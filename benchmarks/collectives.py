@@ -29,9 +29,9 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    from chainermn_tpu.utils import respect_jax_platforms_env
+    from chainermn_tpu.utils import init_compile_cache
 
-    respect_jax_platforms_env()
+    init_compile_cache()
 
     import jax
     import jax.numpy as jnp

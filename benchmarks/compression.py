@@ -90,9 +90,9 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    from chainermn_tpu.utils import respect_jax_platforms_env
+    from chainermn_tpu.utils import init_compile_cache
 
-    respect_jax_platforms_env()
+    init_compile_cache()
     res = measure(args.dim, args.batch_per_chip, args.iters)
     line = json.dumps(res)
     print(line)

@@ -92,9 +92,9 @@ def main():
 
     import jax
 
-    from chainermn_tpu.utils import respect_jax_platforms_env
+    from chainermn_tpu.utils import init_compile_cache
 
-    respect_jax_platforms_env()
+    init_compile_cache()
     # NB: async dispatch stays ON — overlap across steps is the thing being
     # measured.  Single repeated program; the conftest deadlock concerns
     # multiple interleaved compiled programs.

@@ -31,9 +31,9 @@ def main():
 
     import jax
 
-    from chainermn_tpu.utils import respect_jax_platforms_env
+    from chainermn_tpu.utils import init_compile_cache
 
-    respect_jax_platforms_env()
+    init_compile_cache()
     if jax.default_backend() == "cpu":
         jax.config.update("jax_cpu_enable_async_dispatch", False)
     import optax

@@ -9,11 +9,7 @@ training/data/fault-tolerance integration re-built on optax/orbax.
 API facade (reference anchor: ``chainermn/__init__.py``).
 """
 
-from chainermn_tpu import _compat
-
-_compat.install()
-
-from chainermn_tpu.comm import (  # noqa: E402
+from chainermn_tpu.comm import (
     CommunicatorBase,
     DummyCommunicator,
     XlaCommunicator,

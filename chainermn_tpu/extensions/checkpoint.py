@@ -524,10 +524,7 @@ class MultiNodeCheckpointer(Extension):
         if fell_back:
             if jax.process_count() > 1 and hasattr(self.comm, "barrier"):
                 self.comm.barrier()
-            try:
-                self._mngr.reload()
-            except AttributeError:  # pragma: no cover - pre-reload orbax
-                pass
+            self._mngr.reload()
         return doomed
 
     def _known_good_path(self) -> str:

@@ -316,6 +316,13 @@ class PrefetchIterator:
                 self._submit_next()
 
     @property
+    def native(self) -> bool:
+        """Whether batches are assembled by the native threaded loader
+        (``_native/dataloader.cpp``, built with ``g++`` on first use) or by
+        the pure-Python fallback."""
+        return self._h is not None
+
+    @property
     def epoch_detail(self):
         # Consumption-based (the submission cursor runs `depth` batches ahead
         # in native mode and must not leak into schedules keyed on progress).

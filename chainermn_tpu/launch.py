@@ -21,6 +21,14 @@ Multi-host jobs don't launch through this (each host runs one process under
 its own supervisor and passes an explicit coordinator address); the kill-on
 -failure contract there belongs to the cluster scheduler, as it did to the
 multi-host MPI runtime.
+
+One process per chip: an accelerator belongs to the first process that
+initialises a JAX backend on it, so this parent must never do so.  It
+imports the package (and hence ``jax``) for the exit-code constants only;
+nothing on the package's import path calls ``jax.devices()`` or runs a
+computation (pinned by ``tests/test_repo_health.py::
+test_package_import_initialises_no_backend``), so the ranks it spawns find
+their devices free.
 """
 
 from __future__ import annotations

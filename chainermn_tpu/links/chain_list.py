@@ -43,87 +43,16 @@ from jax import lax
 from chainermn_tpu.functions.point_to_point import send_recv
 
 
-#: Last JAX release KNOWN to mis-route ``lax.switch`` cotangents under the
-#: ``check_vma=True`` transpose when the branch index is device-varying
-#: (all closures collapse onto branch 0's operands) — the defect pinned by
-#: ``tests/links_tests/test_hetero_pipeline.py``.  Versions at or below
-#: this skip the probe and run the hetero chain with the checker off.
-_SWITCH_VMA_LAST_KNOWN_BAD = (0, 9, 0)
-
-_switch_vma_probe_cache: dict = {}
-
-
 def switch_vma_safe(mesh) -> bool:
     """Does ``lax.switch`` with a device-varying index differentiate
-    correctly under ``check_vma=True`` on the installed JAX?
-
-    Versions up to :data:`_SWITCH_VMA_LAST_KNOWN_BAD` return ``False``
-    without spending a compile.  NEWER versions run a one-off numeric
-    probe (tiny switch-grad vs oracle, cached per process) so the
-    debug-mode default flips back ON the moment upstream ships the fix
-    (VERDICT r3 item 9) — and stays off if the fix regresses."""
-    from chainermn_tpu import _compat
-
-    if _compat.VMA_SHIMMED:
-        # No vma checker exists on this runtime (shimmed to checker-off):
-        # there is nothing to mis-route, so the switch path is trivially
-        # safe — and the version pin below (which describes the REAL
-        # checker's defect) does not apply.
-        return True
-    ver = tuple(
-        int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-    )
-    if ver <= _SWITCH_VMA_LAST_KNOWN_BAD:
-        return False
-    key = (ver, tuple(d.id for d in mesh.devices.flat))
-    hit = _switch_vma_probe_cache.get(key)
-    if hit is None:
-        hit = _switch_vma_probe_cache[key] = _probe_switch_vma(mesh)
-    return hit
-
-
-def _probe_switch_vma(mesh) -> bool:
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    devices = list(mesh.devices.flat)
-    S = len(devices)
-    if S < 2:
-        return True  # no device-varying index possible: nothing to mis-route
-    rng = np.random.RandomState(0)
-    pm = Mesh(np.array(devices), ("_vmaprobe",))
-    params = tuple(
-        jnp.asarray(rng.normal(size=(4, 4)).astype(np.float32))
-        for _ in range(S)
-    )
-    x = jnp.asarray(rng.normal(size=(2, 4)).astype(np.float32))
-
-    def f(ps, xx):
-        def body(pl, b):
-            idx = lax.axis_index("_vmaprobe")
-            branches = [
-                (lambda bb, s=s: jnp.tanh(bb @ pl[s])) for s in range(S)
-            ]
-            y = lax.switch(idx, branches, b)
-            mask = (idx == S - 1).astype(y.dtype)
-            return jnp.sum(lax.psum(y * mask, "_vmaprobe") ** 2)
-
-        return jax.shard_map(
-            body, mesh=pm, in_specs=(P(), P()), out_specs=P(),
-            check_vma=True,
-        )(ps, xx)
-
-    try:
-        g = jax.jit(jax.grad(f))(params, x)
-    except Exception:
-        return False  # checker rejects the program outright: not safe
-    oracle = jax.grad(
-        lambda ps, xx: jnp.sum(jnp.tanh(xx @ ps[S - 1]) ** 2)
-    )(params, x)
-    return all(
-        bool(np.allclose(np.asarray(g[s]), np.asarray(oracle[s]),
-                         atol=1e-5))
-        for s in range(S)
-    )
+    correctly under ``check_vma=True``?  Not on the installed JAX (0.9.0):
+    the transpose collapses every branch's cotangents onto branch 0's
+    operands, so the hetero chain runs with the checker off.
+    ``tests/links_tests/test_hetero_pipeline.py::
+    test_upstream_switch_vma_defect_still_present`` measures the defect and
+    fails the day a JAX upgrade fixes it — flip this answer then."""
+    del mesh
+    return False
 
 
 def _make_unravel(treedef, shapes):
@@ -381,15 +310,13 @@ class HeteroPipelineChain:
     shape ``(B, *io_shapes[0][0])`` replicated; returns the final stage's
     output ``(B, *io_shapes[-1][1])`` replicated.
 
-    .. warning:: JAX ≤ 0.9.0 mis-routes ``lax.switch`` cotangents under
+    .. warning:: JAX 0.9.0 mis-routes ``lax.switch`` cotangents under
        the ``check_vma=True`` transpose when the branch index is
        device-varying (all closures collapse onto branch 0's operands);
        with the checker off, switch AD is exact — pinned by
        ``tests/links_tests/test_hetero_pipeline.py``.
        :meth:`as_spmd_fn` / :meth:`sharded_spmd_fn` pick the flag via
-       :func:`switch_vma_safe` (version gate + numeric probe), so the
-       debug-mode guarantee returns automatically on a fixed JAX; custom
-       ``comm.spmd`` wrappers should pass
+       :func:`switch_vma_safe`; custom ``comm.spmd`` wrappers should pass
        ``check_vma=switch_vma_safe(comm.mesh)`` the same way.
     """
 

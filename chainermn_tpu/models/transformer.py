@@ -701,8 +701,8 @@ class TransformerLM(nn.Module):
     #: parameter STORAGE dtype.  ``bfloat16`` halves persistent
     #: params(+grads) HBM — with adafactor's factored stats following it,
     #: the T5-style all-bf16 layout sized to fit a 2.6B model's optimizer
-    #: state on the one 15.75 GB chip (capture armed in the watcher; even
-    #: 2.08B with fp32 params OOMs, ``result/lm_2085m_stdout.log``).  The
+    #: state on the one 15.75 GB chip (even 2.08B with fp32 params OOMs,
+    #: ``result/lm_2085m_stdout.log``).  The
     #: MoE router and the LayerNorm/lm_head COMPUTE stay fp32 either way.
     param_dtype: Any = jnp.float32
     #: "flash" (Pallas kernel), "xla" (materialized-scores oracle — the

@@ -107,17 +107,14 @@ def _series_key(rec: dict) -> str:
 
 def load_history(result_dir: str) -> Dict[str, List[dict]]:
     """Headline samples grouped into series.  Non-headline artifacts
-    (traces, logs-as-json, probe records) are skipped by shape; the
-    round-agnostic watcher copy ``bench_tpu_done.json`` is skipped by
-    name (it duplicates whichever round artifact it mirrors — counting
-    it twice would halve the apparent spread)."""
+    (traces, logs-as-json, probe records) are skipped by shape."""
     series: Dict[str, List[dict]] = {}
     try:
         names = sorted(os.listdir(result_dir))
     except OSError:
         return series
     for name in names:
-        if not name.endswith(".json") or name == "bench_tpu_done.json":
+        if not name.endswith(".json"):
             continue
         path = os.path.join(result_dir, name)
         try:

@@ -34,10 +34,9 @@ NEG_INF = -1e30  # finite stand-in: -inf breaks m==NEG_INF rescue on all-masked 
 
 
 def _use_interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    """Pallas interpret mode off-TPU, Mosaic on it — decided from the
+    platform alone; a backend that fails to initialise raises here."""
+    return jax.default_backend() != "tpu"
 
 
 def reference_attention(q, k, v, causal: bool = False,

@@ -228,10 +228,30 @@ class MultiNodeOptimizer:
                 lambda p: jnp.zeros((n,) + p.shape, p.dtype), params
             )
             resid = self.comm.shard_rankwise(resid)
+        step = jnp.zeros((), jnp.int32)
+        opt_state = self.tx.init(params)
+        if isinstance(self.comm, XlaCommunicator):
+            # The step counter and the scalars a transformation creates
+            # itself (adam's ``count``) come up uncommitted on the default
+            # device, while the train step RETURNS them replicated on the
+            # mesh: left alone, the second call sees a new input sharding
+            # and compiles the whole step a second time.  Leaves that
+            # followed the params onto the mesh (mu, nu) stay as they are.
+            mesh = self.comm.mesh
+
+            def settle(x):
+                sh = getattr(x, "sharding", None)
+                if isinstance(sh, NamedSharding) and sh.mesh == mesh:
+                    return x
+                return self.comm.replicate(x)
+
+            step, opt_state = jax.tree_util.tree_map(
+                settle, (step, opt_state)
+            )
         return TrainState(
-            step=jnp.zeros((), jnp.int32),
+            step=step,
             params=params,
-            opt_state=self.tx.init(params),
+            opt_state=opt_state,
             pending_grads=pending,
             model_state=model_state,
             ef_residual=resid,
@@ -595,11 +615,7 @@ def _eager_update(opt, state, batch, loss_fn, has_aux, stateful,
             )
     batch = opt.comm.shard_batch(batch)
     out = step(state, batch)
-    try:
-        on_cpu = jax.devices()[0].platform == "cpu"
-    except Exception:
-        on_cpu = False
-    if on_cpu:
+    if jax.default_backend() == "cpu":
         jax.block_until_ready(out[0])
     return out
 

@@ -183,7 +183,9 @@ class ZeroMultiNodeOptimizer:
                 for spec in self._leafspecs
             ]
         return ZeroTrainState(
-            step=jnp.zeros((), jnp.int32),
+            # replicated like the step's own output, or the second call
+            # compiles again for the new input sharding
+            step=self.comm.replicate(jnp.zeros((), jnp.int32)),
             flat_params=flat,
             opt_state=opt_state,
             model_state=model_state,

@@ -120,8 +120,9 @@ class DecodeEngine:
         unchanged: input shardings are stable across steps, so the jit
         caches never see a second signature.
       device: optional ``jax.Device`` pinning a single-device engine's
-        pools and control uploads (the router's N-replicas-on-N-chips
-        layout without sharding).  Mutually exclusive with ``mesh``.
+        params, pools and control uploads (the router's
+        N-replicas-on-N-chips layout without sharding).  Mutually
+        exclusive with ``mesh``.
         Default ``None`` keeps the classic implicit-default-device fast
         path: no extra transfers anywhere.
     """
@@ -193,10 +194,16 @@ class DecodeEngine:
         elif device is not None:
             placement = (lambda arr: jax.device_put(arr, device))
             self._ctrl = device
+            # The replica's own copy of the weights, beside its pools (a
+            # no-op when they are there already).
+            params, draft_params = jax.device_put(
+                (params, draft_params), device
+            )
         else:
             self._ctrl = None
         self.model = model
         self.params = params
+        self.draft_params = draft_params
         self.capacity = capacity
         self.pool = PagedKVPool(model, num_blocks, block_len,
                                 placement=placement)
@@ -289,13 +296,17 @@ class DecodeEngine:
             samp = jax.random.categorical(key, scaled).astype(jnp.int32)
             return jnp.where(t > 0, samp, greedy)
 
-        # Both programs CLOSE over `params` instead of taking them as an
-        # argument: jit dispatch flattens every call's argument pytree,
-        # and re-flattening hundreds of parameter leaves per generated
-        # token is pure host overhead in the hot loop.  Captured params
-        # are flattened once at trace time; per-step arguments are just
-        # the pools + a handful of small control vectors.
-        def step_impl(pools, tokens, pos, tables, active, rng, temp):
+        # Every program that runs the model takes the params as an
+        # ARGUMENT.  A closed-over array is lowered as a literal: each
+        # program (decode, every prefill ladder size, the speculative
+        # round) would carry its own copy of all the weights inside its
+        # executable — at GPT-2-small width 279 MB of generated code per
+        # program against 260 MB of params, a compile four times as long,
+        # and as much again in every persistent-cache entry (chip
+        # compiler, PR 21) — and a sharded engine's programs would hold
+        # the weights unsharded.
+        def step_impl(params, pools, tokens, pos, tables, active, rng,
+                      temp):
             logits, new_pools = model.apply(
                 {"params": params}, tokens[:, None], cache=pools,
                 decode_pos=pos, block_tables=tables, slot_mask=active,
@@ -311,8 +322,8 @@ class DecodeEngine:
         # token is sampled from that in-chunk position's logits.  A
         # speculative engine's prefill ALSO runs the draft model over the
         # chunk (headless) so the draft cache tracks the target's.
-        def prefill_impl(pools, dpools, tokens, p0, table, last_idx, rng,
-                         temp):
+        def prefill_impl(params, draft_params, pools, dpools, tokens, p0,
+                         table, last_idx, rng, temp):
             h, new_pools = model.apply(
                 {"params": params}, tokens, cache=pools, decode_pos=p0,
                 block_tables=table, return_hidden=True,
@@ -347,8 +358,8 @@ class DecodeEngine:
         # (t > 0) accept zero drafts and sample position-0's logits —
         # which ARE the plain step's logits under the same fold_in key,
         # so sampling semantics are unchanged by speculation.
-        def spec_impl(pools, dpools, tokens, pos, tables, active, rng,
-                      temp):
+        def spec_impl(params, draft_params, pools, dpools, tokens, pos,
+                      tables, active, rng, temp):
             k = spec_k
 
             def dstep(carry, i):
@@ -438,16 +449,16 @@ class DecodeEngine:
 
         _w = _odevice.watch()
         self._step = _w.wrap(
-            jax.jit(step_impl, donate_argnums=(0,)),
+            jax.jit(step_impl, donate_argnums=(1,)),
             program="decode_step", budget=1,
         )
         self._prefill = _w.wrap(
-            jax.jit(prefill_impl, donate_argnums=(0, 1)),
+            jax.jit(prefill_impl, donate_argnums=(2, 3)),
             program="prefill", budget=len(self.prefill_ladder),
         )
         self._spec = (
             _w.wrap(
-                jax.jit(spec_impl, donate_argnums=(0, 1)),
+                jax.jit(spec_impl, donate_argnums=(2, 3)),
                 program="spec_round", budget=1,
             )
             if draft_model is not None else None
@@ -523,6 +534,8 @@ class DecodeEngine:
                 f"{self.prefill_ladder}, got {chunk.shape}"
             )
         self.pools, self.draft_pools, tok = self._prefill(
+            self.params,
+            self.draft_params,
             self.pools,
             self.draft_pools,
             self._up(np.asarray(chunk, np.int32)[None]),
@@ -551,6 +564,7 @@ class DecodeEngine:
         """
         rng, temp = self._rng_temp()
         self.pools, nxt = self._step(
+            self.params,
             self.pools,
             self._up(np.asarray(tokens, np.int32)),
             self._up(np.asarray(pos, np.int32)),
@@ -581,6 +595,8 @@ class DecodeEngine:
             )
         rng, temp = self._rng_temp()
         self.pools, self.draft_pools, toks, n_accept = self._spec(
+            self.params,
+            self.draft_params,
             self.pools,
             self.draft_pools,
             self._up(np.asarray(tokens, np.int32)),

@@ -230,10 +230,11 @@ def pool_placement(mesh):
     from jax.sharding import PartitionSpec as P
 
     def place(arr):
-        if arr.ndim >= 3:
-            spec = P(MODEL_AXIS, *([None] * (arr.ndim - 1)))
-        else:
-            spec = P()
+        # ``P("model")``, not ``P("model", None, ...)``: the engine's
+        # programs hand the pools back under the short spelling, and the
+        # jit cache keys on the spelling — padded with Nones, the first
+        # call of every program would compile a variant no later call uses.
+        spec = P(MODEL_AXIS) if arr.ndim >= 3 else P()
         return jax.device_put(arr, NamedSharding(mesh, spec))
 
     return place

@@ -96,9 +96,6 @@ def main():
 
     import jax
 
-    from chainermn_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     if jax.default_backend() == "cpu":
         jax.config.update("jax_cpu_enable_async_dispatch", False)
     import jax.numpy as jnp

@@ -27,9 +27,6 @@ def main():
     if args.force_cpu:
         jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_cpu_enable_async_dispatch", False)
-        from jax.extend import backend as _backend
-
-        _backend.clear_backends()
 
     import numpy as np
     import optax

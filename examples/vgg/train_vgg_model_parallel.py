@@ -32,9 +32,6 @@ def main():
         jax.config.update("jax_platforms", "cpu")
         # avoid in-process CPU collective rendezvous deadlocks (see tests/conftest.py)
         jax.config.update("jax_cpu_enable_async_dispatch", False)
-        from jax.extend import backend as _backend
-
-        _backend.clear_backends()
 
     import jax.numpy as jnp
     import numpy as np

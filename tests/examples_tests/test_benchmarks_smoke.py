@@ -61,7 +61,7 @@ def test_benchmark_smoke(name, tmp_path):
 
 
 def test_lm_artifact_disposition():
-    """The watcher-wedge contract (round-5): land on any measurement or on
+    """The artifact-landing contract (round-5): land on any measurement or on
     an all-OOM run under --accept-oom; withhold on transients always."""
     import importlib.util
 

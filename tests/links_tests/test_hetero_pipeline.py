@@ -161,21 +161,10 @@ def test_upstream_switch_vma_defect_still_present(devices):
     check_vma=True transpose (closures collapse onto branch 0's operands),
     while the same program with the checker off differentiates exactly.
 
-    Since round 4, :func:`switch_vma_safe` (version gate ≤ 0.9.0 + numeric
-    probe on newer JAX) picks the flag automatically, so a fixed upstream
-    restores the debug guarantee with no code change —
-    ``test_switch_vma_gate_consistent`` below pins that the gate's verdict
-    always matches the measured defect.  WHEN THIS test fails: the
-    installed JAX fixed the defect — verify the gate flipped (the
-    consistency test stays green), then delete THIS test and keep the
-    gate."""
-    from chainermn_tpu import _compat
-
-    if _compat.VMA_SHIMMED:
-        pytest.skip(
-            "vma checker shimmed out on this JAX (_compat): the defect "
-            "under test is a property of the real checker"
-        )
+    :func:`switch_vma_safe` answers ``False`` for that reason
+    (``test_switch_vma_gate_consistent`` below).  WHEN THIS test fails: the
+    installed JAX fixed the defect — flip ``switch_vma_safe`` and that
+    test together, then delete this one."""
     mesh = jax.sharding.Mesh(np.array(devices), ("d",))
     S = len(devices)
     rng = np.random.RandomState(0)
@@ -222,49 +211,19 @@ def test_upstream_switch_vma_defect_still_present(devices):
     )
     assert err > 1e-3, (
         "lax.switch + check_vma=True now differentiates correctly: the "
-        "upstream defect is fixed. switch_vma_safe's gate should flip "
-        "automatically (see test_switch_vma_gate_consistent) — verify it "
-        "does, then delete this test and keep the gate."
+        "upstream defect is fixed: flip switch_vma_safe (and "
+        "test_switch_vma_gate_consistent), then delete this test."
     )
 
 
 def test_switch_vma_gate_consistent(devices):
-    """The auto-restore contract (VERDICT r3 item 9): switch_vma_safe's
-    verdict must MATCH the measured defect on the installed JAX — False
-    while the mis-route exists (the version gate covers ≤ 0.9.0), True the
-    moment a newer JAX differentiates the probe correctly."""
-    import jax as _jax
-
-    from chainermn_tpu.links.chain_list import (
-        _SWITCH_VMA_LAST_KNOWN_BAD,
-        _probe_switch_vma,
-        switch_vma_safe,
-    )
+    """``switch_vma_safe`` must match the defect measured above on the
+    installed JAX: checker off while the mis-route exists, and the chain's
+    own spmd wrappers take their flag from it."""
+    from chainermn_tpu.links.chain_list import switch_vma_safe
 
     mesh = jax.sharding.Mesh(np.array(devices), ("d",))
-    ver = tuple(
-        int(p) for p in _jax.__version__.split(".")[:3] if p.isdigit()
-    )
-    measured_ok = _probe_switch_vma(mesh)
-    from chainermn_tpu import _compat
-
-    if _compat.VMA_SHIMMED:
-        # No real vma checker on this runtime: the gate declares the
-        # switch path trivially safe, and the probe (running checker-off
-        # under the shim) must agree nothing mis-routes.
-        assert switch_vma_safe(mesh) is True
-        assert measured_ok is True
-        return
-    if ver <= _SWITCH_VMA_LAST_KNOWN_BAD:
-        # Pinned-bad version: the gate must short-circuit to False, and
-        # the probe must agree the defect is real (else the pin is stale).
-        assert switch_vma_safe(mesh) is False
-        assert measured_ok is False, (
-            f"JAX {_jax.__version__} no longer shows the switch-vma "
-            "defect: lower/remove _SWITCH_VMA_LAST_KNOWN_BAD"
-        )
-    else:
-        assert switch_vma_safe(mesh) == measured_ok
+    assert switch_vma_safe(mesh) is False
 
 
 def test_hetero_compute_is_distributed_not_replicated(devices):

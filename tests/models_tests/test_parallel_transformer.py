@@ -122,14 +122,7 @@ def test_parallel_loss_and_grads_match_dense(setup, check_vma):
     the vma type), but reduced grads and the reconstructed global loss are
     mode-invariant — this is the exactness pin for the round-4
     check_vma=True default (VERDICT r3 item 9)."""
-    from chainermn_tpu import _compat
     from chainermn_tpu.utils import psum_over_varying
-
-    if check_vma and _compat.VMA_SHIMMED:
-        pytest.skip(
-            "check_vma shimmed to checker-off on this JAX (_compat): the "
-            "vma seeding convention under test does not exist here"
-        )
 
     cfg, mesh, lm, params, tokens, targets = setup
     specs = parallel_lm_specs(cfg)

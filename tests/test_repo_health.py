@@ -273,3 +273,24 @@ def test_every_package_dir_has_init():
     assert not missing, (
         f"package dirs importing as silent namespace packages: {missing}"
     )
+
+
+def test_package_import_initialises_no_backend():
+    """One process per chip: ``chainermn_tpu.launch``'s parent imports the
+    package and then spawns the ranks that need the devices, so importing
+    the package (launcher, serving and model modules included) must leave
+    JAX's backends uninitialised."""
+    import subprocess
+    import sys
+
+    code = (
+        "import chainermn_tpu, chainermn_tpu.launch, chainermn_tpu.serving,"
+        " chainermn_tpu.models, chainermn_tpu.ops, chainermn_tpu.parallel\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, dict(xla_bridge._backends)\n"
+    )
+    r = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True,
+        text=True, timeout=120, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert r.returncode == 0, (r.stdout, r.stderr)

@@ -16,6 +16,32 @@ def test_sync_blocks_on_tree():
     sync(x)  # must not raise; values materialized
 
 
+def test_init_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch,
+                                                             tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and the helper
+    names no other directory.  Unset: ``<checkout>/.jax_cache``, a path that
+    is the same in every run (the directory is part of the cache key)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from chainermn_tpu.utils import init_compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert init_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(repo, ".jax_cache")
+        assert init_compile_cache() == fixed
+        assert init_compile_cache() == fixed  # no pid/timestamp in it
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:  # later tests compile with the cache as they found it
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()
+
+
 def test_benchmark_returns_positive_seconds():
     f = jax.jit(lambda x: (x @ x).sum())
     x = jnp.ones((64, 64))
