@@ -21,6 +21,14 @@ from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
+from chainermn_tpu.observability.tracing import annotate as _annotate
+
+
+def _nbytes(batch: Any) -> int:
+    if isinstance(batch, (tuple, list)):
+        return sum(_nbytes(b) for b in batch)
+    return int(getattr(batch, "nbytes", 0))
+
 
 def _leading_dim(batch: Any) -> int:
     if isinstance(batch, (tuple, list)):
@@ -78,9 +86,12 @@ class DevicePrefetchIterator:
                 return
             # Async: the transfer is in flight the moment shard_batch
             # returns; it completes while earlier batches are consumed.
+            with _annotate("cmn_input_device_put",
+                           bytes=lambda: _nbytes(host)):
+                on_device = self._comm.shard_batch(host)
             self._queue.append(
                 _Entry(
-                    batch=self._comm.shard_batch(host),
+                    batch=on_device,
                     epoch=int(getattr(self._it, "epoch", 0)),
                     is_new_epoch=bool(
                         getattr(self._it, "is_new_epoch", False)
@@ -100,12 +111,13 @@ class DevicePrefetchIterator:
     def __next__(self):
         if not self._queue:
             raise StopIteration
-        e = self._queue.popleft()
-        self.epoch = e.epoch
-        self.is_new_epoch = e.is_new_epoch
-        self.iteration = e.iteration
-        self._epoch_detail = e.epoch_detail
-        self._fill()
+        with _annotate("cmn_input_wait"):
+            e = self._queue.popleft()
+            self.epoch = e.epoch
+            self.is_new_epoch = e.is_new_epoch
+            self.iteration = e.iteration
+            self._epoch_detail = e.epoch_detail
+            self._fill()
         return e.batch
 
     @property

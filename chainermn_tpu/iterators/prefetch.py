@@ -19,6 +19,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from chainermn_tpu import _native
+from chainermn_tpu.observability.tracing import annotate as _annotate
 
 
 class PrefetchIterator:
@@ -170,9 +171,11 @@ class PrefetchIterator:
         return self
 
     def __next__(self):
-        if self._h:
-            return self._next_native()
-        return self._next_sync()
+        with _annotate("cmn_input_host_batch", rows=self.batch_size,
+                       native=int(bool(self._h))):
+            if self._h:
+                return self._next_native()
+            return self._next_sync()
 
     def _next_native(self):
         if not self._pending:

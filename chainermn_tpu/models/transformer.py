@@ -210,22 +210,23 @@ class _DecoderBlock(nn.Module):
                 "'einsum' or 'fused'"
             )
         x = nn.LayerNorm(dtype=self.dtype, param_dtype=self.param_dtype, name="ln1")(h)
-        if KH == H:
-            qkv = nn.DenseGeneral(
-                (3, H, D // H), dtype=self.dtype, param_dtype=self.param_dtype,
-                name="qkv"
-            )(x)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:
-            q = nn.DenseGeneral(
-                (H, D // H), dtype=self.dtype,
-                param_dtype=self.param_dtype, name="q",
-            )(x)
-            kv = nn.DenseGeneral(
-                (2, KH, D // H), dtype=self.dtype, param_dtype=self.param_dtype,
-                name="kv"
-            )(x)
-            k, v = kv[:, :, 0], kv[:, :, 1]
+        with jax.named_scope("attn_qkv"):
+            if KH == H:
+                qkv = nn.DenseGeneral(
+                    (3, H, D // H), dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="qkv"
+                )(x)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            else:
+                q = nn.DenseGeneral(
+                    (H, D // H), dtype=self.dtype,
+                    param_dtype=self.param_dtype, name="q",
+                )(x)
+                kv = nn.DenseGeneral(
+                    (2, KH, D // H), dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="kv"
+                )(x)
+                k, v = kv[:, :, 0], kv[:, :, 1]
         if cache is not None:
             # Incremental: write this chunk's k/v at decode_pos (T=1 per
             # generation step; T=P for the batched prompt prefill), attend
@@ -277,12 +278,13 @@ class _DecoderBlock(nn.Module):
                 # decode_pos[r] .. decode_pos[r] + T - 1 (per-row
                 # speculative verify chunks; ragged prompts at T = 1).
                 q_pos = decode_pos[:, None] + jnp.arange(T)[None]
-            if self.pos_enc == "rope":
-                # Rotate BEFORE the cache write: the cache stores
-                # position-rotated keys, so cached entries never need
-                # re-rotation (RoPE's relative property does the rest).
-                q = apply_rope(q, tables=rope)
-                k = apply_rope(k, tables=rope)
+            with jax.named_scope("attn_qkv"):
+                if self.pos_enc == "rope":
+                    # Rotate BEFORE the cache write: the cache stores
+                    # position-rotated keys, so cached entries never need
+                    # re-rotation (RoPE's relative property does the rest).
+                    q = apply_rope(q, tables=rope)
+                    k = apply_rope(k, tables=rope)
             # int8-quantized cache (``TransformerLM.kv_dtype=jnp.int8``,
             # detected by the scale entries ``init_cache`` adds): each
             # written (token, kv-head) row stores symmetric-absmax int8
@@ -293,27 +295,28 @@ class _DecoderBlock(nn.Module):
             # float cache: the k scale folds into the score einsum's
             # output, the v scale into the probability operand.
             quant = "k_scale" in cache
-            if quant:
-                kf = k.astype(jnp.float32)
-                vf = v.astype(jnp.float32)
-                k_scale = jnp.maximum(
-                    jnp.max(jnp.abs(kf), axis=-1), 1e-6
-                ) / 127.0  # (B, T, KH)
-                v_scale = jnp.maximum(
-                    jnp.max(jnp.abs(vf), axis=-1), 1e-6
-                ) / 127.0
-                k_w = jnp.clip(
-                    jnp.round(kf / k_scale[..., None]), -127, 127
-                ).astype(jnp.int8)
-                v_w = jnp.clip(
-                    jnp.round(vf / v_scale[..., None]), -127, 127
-                ).astype(jnp.int8)
-            else:
-                # Float cache: cast to the cache's storage dtype (kv_dtype
-                # may differ from the compute dtype — e.g. store bf16 under
-                # fp32 compute).
-                k_w = k.astype(cache["k"].dtype)
-                v_w = v.astype(cache["v"].dtype)
+            with jax.named_scope("kv_write"):
+                if quant:
+                    kf = k.astype(jnp.float32)
+                    vf = v.astype(jnp.float32)
+                    k_scale = jnp.maximum(
+                        jnp.max(jnp.abs(kf), axis=-1), 1e-6
+                    ) / 127.0  # (B, T, KH)
+                    v_scale = jnp.maximum(
+                        jnp.max(jnp.abs(vf), axis=-1), 1e-6
+                    ) / 127.0
+                    k_w = jnp.clip(
+                        jnp.round(kf / k_scale[..., None]), -127, 127
+                    ).astype(jnp.int8)
+                    v_w = jnp.clip(
+                        jnp.round(vf / v_scale[..., None]), -127, 127
+                    ).astype(jnp.int8)
+                else:
+                    # Float cache: cast to the cache's storage dtype (kv_dtype
+                    # may differ from the compute dtype — e.g. store bf16 under
+                    # fp32 compute).
+                    k_w = k.astype(cache["k"].dtype)
+                    v_w = v.astype(cache["v"].dtype)
             write_pos = (
                 decode_pos % self.window if rolling else decode_pos
             )
@@ -324,36 +327,37 @@ class _DecoderBlock(nn.Module):
                 # parking block 0 and write back their own current value —
                 # duplicate indices then carry duplicate VALUES, keeping
                 # the scatter deterministic.
-                pool_k, pool_v = cache["k"], cache["v"]
-                BL = pool_k.shape[2]
-                pb = jnp.take_along_axis(
-                    block_tables, q_pos // BL, axis=1
-                )  # (B, T) physical block per written position
-                off = q_pos % BL
-                if slot_mask is not None:
-                    live = slot_mask.astype(bool)[:, None]
-                    pb = jnp.where(live, pb, 0)
-                    off = jnp.where(live, off, 0)
-                k_t = jnp.transpose(k_w, (2, 0, 1, 3))  # (KH, B, T, Dh)
-                v_t = jnp.transpose(v_w, (2, 0, 1, 3))
-                if slot_mask is not None:
-                    lv = live[None, :, :, None]
-                    k_t = jnp.where(lv, k_t, pool_k[:, pb, off])
-                    v_t = jnp.where(lv, v_t, pool_v[:, pb, off])
-                kc = pool_k.at[:, pb, off].set(k_t)
-                vc = pool_v.at[:, pb, off].set(v_t)
-                if quant:
-                    ks_t = jnp.transpose(k_scale, (2, 0, 1))  # (KH, B, T)
-                    vs_t = jnp.transpose(v_scale, (2, 0, 1))
+                with jax.named_scope("kv_write"):
+                    pool_k, pool_v = cache["k"], cache["v"]
+                    BL = pool_k.shape[2]
+                    pb = jnp.take_along_axis(
+                        block_tables, q_pos // BL, axis=1
+                    )  # (B, T) physical block per written position
+                    off = q_pos % BL
                     if slot_mask is not None:
-                        ks_t = jnp.where(
-                            live[None], ks_t, cache["k_scale"][:, pb, off]
-                        )
-                        vs_t = jnp.where(
-                            live[None], vs_t, cache["v_scale"][:, pb, off]
-                        )
-                    ks_c = cache["k_scale"].at[:, pb, off].set(ks_t)
-                    vs_c = cache["v_scale"].at[:, pb, off].set(vs_t)
+                        live = slot_mask.astype(bool)[:, None]
+                        pb = jnp.where(live, pb, 0)
+                        off = jnp.where(live, off, 0)
+                    k_t = jnp.transpose(k_w, (2, 0, 1, 3))  # (KH, B, T, Dh)
+                    v_t = jnp.transpose(v_w, (2, 0, 1, 3))
+                    if slot_mask is not None:
+                        lv = live[None, :, :, None]
+                        k_t = jnp.where(lv, k_t, pool_k[:, pb, off])
+                        v_t = jnp.where(lv, v_t, pool_v[:, pb, off])
+                    kc = pool_k.at[:, pb, off].set(k_t)
+                    vc = pool_v.at[:, pb, off].set(v_t)
+                    if quant:
+                        ks_t = jnp.transpose(k_scale, (2, 0, 1))  # (KH, B, T)
+                        vs_t = jnp.transpose(v_scale, (2, 0, 1))
+                        if slot_mask is not None:
+                            ks_t = jnp.where(
+                                live[None], ks_t, cache["k_scale"][:, pb, off]
+                            )
+                            vs_t = jnp.where(
+                                live[None], vs_t, cache["v_scale"][:, pb, off]
+                            )
+                        ks_c = cache["k_scale"].at[:, pb, off].set(ks_t)
+                        vs_c = cache["v_scale"].at[:, pb, off].set(vs_t)
                 # The kernel's causal bound is the FIRST query position's
                 # (offset t adds t in-kernel); T == 1 reduces to the
                 # classic decode bound.  Idle slots mask to 0.
@@ -369,48 +373,50 @@ class _DecoderBlock(nn.Module):
                 )
                 if (self.decode_attention == "fused" and not self.window
                         and (T == 1 or verify)):
-                    if self.decode_mesh is not None:
-                        # Tensor-parallel engines: the kernel runs per
-                        # shard under shard_map (q cut on heads, pool on
-                        # kv heads — the placement the serving plane
-                        # already installs); bit-identical to the
-                        # unsharded call, no collective added here.
-                        a = sharded_paged_decode_attention(
-                            q[:, 0] if T == 1 else q, kc, vc,
-                            block_tables, valid,
-                            k_scale=ks_c if quant else None,
-                            v_scale=vs_c if quant else None,
-                            mesh=self.decode_mesh,
-                        )
-                    else:
-                        a = paged_decode_attention(
-                            q[:, 0] if T == 1 else q, kc, vc, block_tables,
-                            valid,
-                            k_scale=ks_c if quant else None,
-                            v_scale=vs_c if quant else None,
-                        )
-                    if T == 1:
-                        a = a[:, None]
+                    with jax.named_scope("attn.paged"):
+                        if self.decode_mesh is not None:
+                            # Tensor-parallel engines: the kernel runs per
+                            # shard under shard_map (q cut on heads, pool on
+                            # kv heads — the placement the serving plane
+                            # already installs); bit-identical to the
+                            # unsharded call, no collective added here.
+                            a = sharded_paged_decode_attention(
+                                q[:, 0] if T == 1 else q, kc, vc,
+                                block_tables, valid,
+                                k_scale=ks_c if quant else None,
+                                v_scale=vs_c if quant else None,
+                                mesh=self.decode_mesh,
+                            )
+                        else:
+                            a = paged_decode_attention(
+                                q[:, 0] if T == 1 else q, kc, vc, block_tables,
+                                valid,
+                                k_scale=ks_c if quant else None,
+                                v_scale=vs_c if quant else None,
+                            )
+                        if T == 1:
+                            a = a[:, None]
                 else:
-                    # Gathered fallback (prefill chunks; einsum engines):
-                    # materialize each row's logical kv-head-major view of
-                    # its blocks and run the shared einsum path.
-                    kg = jnp.swapaxes(kc[:, block_tables], 0, 1)
-                    vg = jnp.swapaxes(vc[:, block_tables], 0, 1)
-                    Lg = kg.shape[2] * kg.shape[3]
-                    kg = kg.reshape(B, KH, Lg, D // H)
-                    vg = vg.reshape(B, KH, Lg, D // H)
-                    ksg = vsg = None
-                    if quant:
-                        ksg = jnp.swapaxes(
-                            ks_c[:, block_tables], 0, 1
-                        ).reshape(B, KH, Lg)
-                        vsg = jnp.swapaxes(
-                            vs_c[:, block_tables], 0, 1
-                        ).reshape(B, KH, Lg)
-                    a = _attend_kv_major(
-                        q, kg, vg, q_pos, self.window, ksg, vsg
-                    )
+                    with jax.named_scope("attn.gathered"):
+                        # Gathered fallback (prefill chunks; einsum engines):
+                        # materialize each row's logical kv-head-major view of
+                        # its blocks and run the shared einsum path.
+                        kg = jnp.swapaxes(kc[:, block_tables], 0, 1)
+                        vg = jnp.swapaxes(vc[:, block_tables], 0, 1)
+                        Lg = kg.shape[2] * kg.shape[3]
+                        kg = kg.reshape(B, KH, Lg, D // H)
+                        vg = vg.reshape(B, KH, Lg, D // H)
+                        ksg = vsg = None
+                        if quant:
+                            ksg = jnp.swapaxes(
+                                ks_c[:, block_tables], 0, 1
+                            ).reshape(B, KH, Lg)
+                            vsg = jnp.swapaxes(
+                                vs_c[:, block_tables], 0, 1
+                            ).reshape(B, KH, Lg)
+                        a = _attend_kv_major(
+                            q, kg, vg, q_pos, self.window, ksg, vsg
+                        )
                 new_cache = (
                     {"k": kc, "v": vc, "k_scale": ks_c, "v_scale": vs_c}
                     if quant else {"k": kc, "v": vc}
@@ -420,139 +426,144 @@ class _DecoderBlock(nn.Module):
                 # fused kernel's layout.  Single-token full-attention steps
                 # run the Pallas kernel; prefill chunks, window models and
                 # L > MAX_FUSED_LEN take the layout-matched einsum.
-                k_t = jnp.swapaxes(k_w, 1, 2)  # (B, KH, T, Dh)
-                v_t = jnp.swapaxes(v_w, 1, 2)
-                if jnp.ndim(decode_pos) == 0:
-                    kc = lax.dynamic_update_slice(
-                        cache["k"], k_t, (0, 0, write_pos, 0)
-                    )
-                    vc = lax.dynamic_update_slice(
-                        cache["v"], v_t, (0, 0, write_pos, 0)
-                    )
-                    if quant:
-                        ks_c = lax.dynamic_update_slice(
-                            cache["k_scale"],
-                            jnp.swapaxes(k_scale, 1, 2), (0, 0, write_pos),
+                with jax.named_scope("kv_write"):
+                    k_t = jnp.swapaxes(k_w, 1, 2)  # (B, KH, T, Dh)
+                    v_t = jnp.swapaxes(v_w, 1, 2)
+                    if jnp.ndim(decode_pos) == 0:
+                        kc = lax.dynamic_update_slice(
+                            cache["k"], k_t, (0, 0, write_pos, 0)
                         )
-                        vs_c = lax.dynamic_update_slice(
-                            cache["v_scale"],
-                            jnp.swapaxes(v_scale, 1, 2), (0, 0, write_pos),
+                        vc = lax.dynamic_update_slice(
+                            cache["v"], v_t, (0, 0, write_pos, 0)
                         )
-                else:
-                    rows = jnp.arange(B)[:, None]
-                    cols = write_pos[:, None] + jnp.arange(T)[None]
-                    # Advanced indices (rows, cols) straddling the KH
-                    # slice land the broadcast axes up front: the indexed
-                    # view is (B, T, KH, ...), exactly k_w's layout.
-                    kc = cache["k"].at[rows, :, cols].set(k_w)
-                    vc = cache["v"].at[rows, :, cols].set(v_w)
-                    if quant:
-                        ks_c = cache["k_scale"].at[rows, :, cols].set(
-                            k_scale
-                        )
-                        vs_c = cache["v_scale"].at[rows, :, cols].set(
-                            v_scale
-                        )
+                        if quant:
+                            ks_c = lax.dynamic_update_slice(
+                                cache["k_scale"],
+                                jnp.swapaxes(k_scale, 1, 2), (0, 0, write_pos),
+                            )
+                            vs_c = lax.dynamic_update_slice(
+                                cache["v_scale"],
+                                jnp.swapaxes(v_scale, 1, 2), (0, 0, write_pos),
+                            )
+                    else:
+                        rows = jnp.arange(B)[:, None]
+                        cols = write_pos[:, None] + jnp.arange(T)[None]
+                        # Advanced indices (rows, cols) straddling the KH
+                        # slice land the broadcast axes up front: the indexed
+                        # view is (B, T, KH, ...), exactly k_w's layout.
+                        kc = cache["k"].at[rows, :, cols].set(k_w)
+                        vc = cache["v"].at[rows, :, cols].set(v_w)
+                        if quant:
+                            ks_c = cache["k_scale"].at[rows, :, cols].set(
+                                k_scale
+                            )
+                            vs_c = cache["v_scale"].at[rows, :, cols].set(
+                                v_scale
+                            )
                 if (T == 1 and not self.window
                         and cache["k"].shape[2] <= MAX_FUSED_LEN):
-                    if self.decode_mesh is not None:
-                        a = sharded_fused_decode_attention(
-                            q[:, 0], kc, vc, q_pos[:, 0] + 1,
-                            k_scale=ks_c if quant else None,
-                            v_scale=vs_c if quant else None,
-                            mesh=self.decode_mesh,
-                        )[:, None]
-                    else:
-                        a = fused_decode_attention(
-                            q[:, 0], kc, vc, q_pos[:, 0] + 1,
-                            k_scale=ks_c if quant else None,
-                            v_scale=vs_c if quant else None,
-                        )[:, None]
+                    with jax.named_scope("attn.fused"):
+                        if self.decode_mesh is not None:
+                            a = sharded_fused_decode_attention(
+                                q[:, 0], kc, vc, q_pos[:, 0] + 1,
+                                k_scale=ks_c if quant else None,
+                                v_scale=vs_c if quant else None,
+                                mesh=self.decode_mesh,
+                            )[:, None]
+                        else:
+                            a = fused_decode_attention(
+                                q[:, 0], kc, vc, q_pos[:, 0] + 1,
+                                k_scale=ks_c if quant else None,
+                                v_scale=vs_c if quant else None,
+                            )[:, None]
                 else:
-                    a = _attend_kv_major(
-                        q, kc, vc, q_pos, self.window,
-                        ks_c if quant else None,
-                        vs_c if quant else None,
-                    )
+                    with jax.named_scope("attn.kv_major_einsum"):
+                        a = _attend_kv_major(
+                            q, kc, vc, q_pos, self.window,
+                            ks_c if quant else None,
+                            vs_c if quant else None,
+                        )
                 new_cache = (
                     {"k": kc, "v": vc, "k_scale": ks_c, "v_scale": vs_c}
                     if quant else {"k": kc, "v": vc}
                 )
             else:
-                if jnp.ndim(decode_pos) == 0:
-                    kc = lax.dynamic_update_slice(
-                        cache["k"], k_w, (0, write_pos, 0, 0)
-                    )
-                    vc = lax.dynamic_update_slice(
-                        cache["v"], v_w, (0, write_pos, 0, 0)
-                    )
-                    if quant:
-                        ks_c = lax.dynamic_update_slice(
-                            cache["k_scale"], k_scale, (0, write_pos, 0)
+                with jax.named_scope("kv_write"):
+                    if jnp.ndim(decode_pos) == 0:
+                        kc = lax.dynamic_update_slice(
+                            cache["k"], k_w, (0, write_pos, 0, 0)
                         )
-                        vs_c = lax.dynamic_update_slice(
-                            cache["v_scale"], v_scale, (0, write_pos, 0)
+                        vc = lax.dynamic_update_slice(
+                            cache["v"], v_w, (0, write_pos, 0, 0)
                         )
-                else:
-                    # Per-row chunk scatter: row r writes its T slots
-                    # starting at write_pos[r].
-                    rows = jnp.arange(B)[:, None]
-                    cols = write_pos[:, None] + jnp.arange(T)[None]
-                    kc = cache["k"].at[rows, cols].set(k_w)
-                    vc = cache["v"].at[rows, cols].set(v_w)
+                        if quant:
+                            ks_c = lax.dynamic_update_slice(
+                                cache["k_scale"], k_scale, (0, write_pos, 0)
+                            )
+                            vs_c = lax.dynamic_update_slice(
+                                cache["v_scale"], v_scale, (0, write_pos, 0)
+                            )
+                    else:
+                        # Per-row chunk scatter: row r writes its T slots
+                        # starting at write_pos[r].
+                        rows = jnp.arange(B)[:, None]
+                        cols = write_pos[:, None] + jnp.arange(T)[None]
+                        kc = cache["k"].at[rows, cols].set(k_w)
+                        vc = cache["v"].at[rows, cols].set(v_w)
+                        if quant:
+                            ks_c = cache["k_scale"].at[rows, cols].set(k_scale)
+                            vs_c = cache["v_scale"].at[rows, cols].set(v_scale)
+                with jax.named_scope("attn.einsum"):
+                    # Grouped attention against the (B, L, KH, Dh) cache: query
+                    # head h reads kv head h // (H // KH).  KH == H reduces to
+                    # classic multi-head (group axis of size 1).
+                    G = H // KH
+                    qg = q.reshape(q.shape[0], T, KH, G, D // H)
+                    s = jnp.einsum(
+                        "bqkgd,btkd->bkgqt", qg.astype(jnp.float32),
+                        kc.astype(jnp.float32),
+                    ) / math.sqrt(D // H)
                     if quant:
-                        ks_c = cache["k_scale"].at[rows, cols].set(k_scale)
-                        vs_c = cache["v_scale"].at[rows, cols].set(v_scale)
-                # Grouped attention against the (B, L, KH, Dh) cache: query
-                # head h reads kv head h // (H // KH).  KH == H reduces to
-                # classic multi-head (group axis of size 1).
-                G = H // KH
-                qg = q.reshape(q.shape[0], T, KH, G, D // H)
-                s = jnp.einsum(
-                    "bqkgd,btkd->bkgqt", qg.astype(jnp.float32),
-                    kc.astype(jnp.float32),
-                ) / math.sqrt(D // H)
-                if quant:
-                    # Per-(t, kv-head) k scale commutes out of the head_dim
-                    # contraction: apply it on the (b, k, g, q, t) scores.
-                    s = s * jnp.transpose(
-                        ks_c, (0, 2, 1)
-                    )[:, :, None, None, :]
-                t_idx = jnp.arange(kc.shape[1])
-                if rolling:
-                    # Slot s holds absolute position pos − ((pos − s) mod
-                    # W): the latest position ≡ s that is ≤ pos.  Negative
-                    # ⇒ the slot was never written (early steps) — mask
-                    # it.  Window and causality are automatic: every held
-                    # position lies in (pos − W, pos].
-                    pos_b = q_pos[:, 0]  # (B,), T == 1
-                    p_s = pos_b[:, None] - (
-                        (pos_b[:, None] - t_idx[None, :]) % self.window
-                    )
-                    visible = (p_s >= 0)[:, None, None, None, :]
-                else:
-                    visible = (
-                        t_idx[None, None, None, None, :]
-                        <= q_pos[:, None, None, :, None]
-                    )
-                    if self.window:
-                        # Decode twin of the training-time sliding window:
-                        # only the last `window` positions stay attendable.
-                        visible &= (
+                        # Per-(t, kv-head) k scale commutes out of the head_dim
+                        # contraction: apply it on the (b, k, g, q, t) scores.
+                        s = s * jnp.transpose(
+                            ks_c, (0, 2, 1)
+                        )[:, :, None, None, :]
+                    t_idx = jnp.arange(kc.shape[1])
+                    if rolling:
+                        # Slot s holds absolute position pos − ((pos − s) mod
+                        # W): the latest position ≡ s that is ≤ pos.  Negative
+                        # ⇒ the slot was never written (early steps) — mask
+                        # it.  Window and causality are automatic: every held
+                        # position lies in (pos − W, pos].
+                        pos_b = q_pos[:, 0]  # (B,), T == 1
+                        p_s = pos_b[:, None] - (
+                            (pos_b[:, None] - t_idx[None, :]) % self.window
+                        )
+                        visible = (p_s >= 0)[:, None, None, None, :]
+                    else:
+                        visible = (
                             t_idx[None, None, None, None, :]
-                            > q_pos[:, None, None, :, None] - self.window
+                            <= q_pos[:, None, None, :, None]
                         )
-                s = jnp.where(visible, s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                if quant:
-                    # v scale folds into the probability operand (per t, kv
-                    # head) — the int8 cache feeds the einsum directly.
-                    p = p * jnp.transpose(
-                        vs_c, (0, 2, 1)
-                    )[:, :, None, None, :]
-                a = jnp.einsum(
-                    "bkgqt,btkd->bqkgd", p, vc.astype(jnp.float32)
-                ).reshape(q.shape[0], T, H, D // H).astype(q.dtype)
+                        if self.window:
+                            # Decode twin of the training-time sliding window:
+                            # only the last `window` positions stay attendable.
+                            visible &= (
+                                t_idx[None, None, None, None, :]
+                                > q_pos[:, None, None, :, None] - self.window
+                            )
+                    s = jnp.where(visible, s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1)
+                    if quant:
+                        # v scale folds into the probability operand (per t, kv
+                        # head) — the int8 cache feeds the einsum directly.
+                        p = p * jnp.transpose(
+                            vs_c, (0, 2, 1)
+                        )[:, :, None, None, :]
+                    a = jnp.einsum(
+                        "bkgqt,btkd->bqkgd", p, vc.astype(jnp.float32)
+                    ).reshape(q.shape[0], T, H, D // H).astype(q.dtype)
                 new_cache = (
                     {"k": kc, "v": vc, "k_scale": ks_c, "v_scale": vs_c}
                     if quant else {"k": kc, "v": vc}
@@ -563,42 +574,50 @@ class _DecoderBlock(nn.Module):
                     f"attention={self.attention!r}: expected 'flash', "
                     "'xla' or 'auto'"
                 )
-            if self.pos_enc == "rope":
-                # Shared per-step tables from the parent (packed rows bake
-                # per-document restart positions into them).  Rotation is
-                # elementwise — XLA fuses it into the projection epilogue.
-                q = apply_rope(q, tables=rope)
-                k = apply_rope(k, tables=rope)
+            with jax.named_scope("attn_qkv"):
+                if self.pos_enc == "rope":
+                    # Shared per-step tables from the parent (packed rows bake
+                    # per-document restart positions into them).  Rotation is
+                    # elementwise — XLA fuses it into the projection epilogue.
+                    q = apply_rope(q, tables=rope)
+                    k = apply_rope(k, tables=rope)
             if resolve_attention(self.attention, T) == "flash":
                 # Library-default blocks: largest sweep-winning
                 # power-of-2 divisors of T (flash needs T % block == 0);
                 # natural lengths work without upstream padding.  'auto'
                 # picks flash/xla by the measured on-chip crossover
                 # (ops.FLASH_MIN_SEQ).
-                block = None
-                a = flash_attention(q, k, v, causal=True,
-                                    segment_ids=segment_ids, block_q=block,
-                                    block_k=block,
-                                    window=self.window or None)
+                with jax.named_scope("attn.flash"):
+                    block = None
+                    a = flash_attention(q, k, v, causal=True,
+                                        segment_ids=segment_ids, block_q=block,
+                                        block_k=block,
+                                        window=self.window or None)
             else:
-                a = reference_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids,
-                    window=self.window or None,
-                ).astype(q.dtype)
-        o = nn.DenseGeneral(
-            D, axis=(-2, -1), dtype=self.dtype,
-            param_dtype=self.param_dtype, name="proj",
-        )(a)
-        h = h + o
+                with jax.named_scope("attn.xla"):
+                    a = reference_attention(
+                        q, k, v, causal=True, segment_ids=segment_ids,
+                        window=self.window or None,
+                    ).astype(q.dtype)
+        with jax.named_scope("attn_out"):
+            o = nn.DenseGeneral(
+                D, axis=(-2, -1), dtype=self.dtype,
+                param_dtype=self.param_dtype, name="proj",
+            )(a)
+            h = h + o
         x = nn.LayerNorm(dtype=self.dtype, param_dtype=self.param_dtype, name="ln2")(h)
-        if self.n_experts:
-            y = self._moe_ffn(x)
-        else:
-            y = nn.Dense(self.d_ff, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ff1")(x)
-            y = nn.Dense(D, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="ff2")(nn.gelu(y))
-        h = h + y
+        # ff1 -> activation -> ff2 and the residual add read as ONE layer in
+        # a device trace (a fusion carries its root's scope, and the root of
+        # the ff2 fusion is the residual add).
+        with jax.named_scope("ffn"):
+            if self.n_experts:
+                y = self._moe_ffn(x)
+            else:
+                y = nn.Dense(self.d_ff, dtype=self.dtype,
+                             param_dtype=self.param_dtype, name="ff1")(x)
+                y = nn.Dense(D, dtype=self.dtype, param_dtype=self.param_dtype,
+                             name="ff2")(nn.gelu(y))
+            h = h + y
         return (h, new_cache) if cache is not None else h
 
     def _moe_ffn(self, x):
@@ -647,45 +666,48 @@ class _DecoderBlock(nn.Module):
         b2 = self.param("moe_b2", nn.initializers.zeros, (E, D),
                         self.param_dtype)
 
-        xg = flat.reshape(n_groups, G, D)
-        probs = jax.nn.softmax(
-            (xg.astype(jnp.float32) @ router), axis=-1
-        )  # (g, G, E)
-        dispatch, combine, first = jax.vmap(
-            lambda p: _topk_dispatch(p, C, self.moe_k)
-        )(probs)
-        # Switch load-balance loss, averaged over groups; dropped rate =
-        # routings that lost the capacity race (they fall through on the
-        # residual with weight 0 in `combine`).
-        f_e = jnp.mean(first, axis=1)  # (g, E)
-        p_e = jnp.mean(probs, axis=1)
-        aux = E * jnp.mean(jnp.sum(f_e * p_e, axis=-1))
-        dropped = 1.0 - jnp.sum(dispatch) / (N * self.moe_k)
-        self.sow("intermediates", "moe_aux", aux)
-        self.sow("intermediates", "moe_dropped", dropped)
+        with jax.named_scope("moe.dispatch"):
+            xg = flat.reshape(n_groups, G, D)
+            probs = jax.nn.softmax(
+                (xg.astype(jnp.float32) @ router), axis=-1
+            )  # (g, G, E)
+            dispatch, combine, first = jax.vmap(
+                lambda p: _topk_dispatch(p, C, self.moe_k)
+            )(probs)
+            # Switch load-balance loss, averaged over groups; dropped rate =
+            # routings that lost the capacity race (they fall through on the
+            # residual with weight 0 in `combine`).
+            f_e = jnp.mean(first, axis=1)  # (g, E)
+            p_e = jnp.mean(probs, axis=1)
+            aux = E * jnp.mean(jnp.sum(f_e * p_e, axis=-1))
+            dropped = 1.0 - jnp.sum(dispatch) / (N * self.moe_k)
+            self.sow("intermediates", "moe_aux", aux)
+            self.sow("intermediates", "moe_dropped", dropped)
 
-        # Dispatch einsum in the compute dtype: each (e, c) output slot has
-        # AT MOST ONE nonzero term over n (dispatch is one-hot in (e, c)
-        # per routing), so there is no accumulation to lose — unlike the
-        # EP wire in moe.py, no fp32 pass is needed for exactness.
-        send = jnp.einsum(
-            "gnec,gnd->egcd", dispatch.astype(self.dtype),
-            xg.astype(self.dtype),
-        ).reshape(E, n_groups * C, D)
-        hmid = nn.gelu(
-            jnp.einsum("exd,edf->exf", send, w1.astype(self.dtype))
-            + b1[:, None, :].astype(self.dtype)
-        )
-        out = (
-            jnp.einsum("exf,efd->exd", hmid, w2.astype(self.dtype))
-            + b2[:, None, :].astype(self.dtype)
-        ).reshape(E, n_groups, C, D)
-        # Combine accumulates k expert outputs per token — fp32, as the EP
-        # tier's combine einsum does.
-        y = jnp.einsum(
-            "gnec,egcd->gnd", combine, out.astype(jnp.float32)
-        )
-        return y.reshape(B, T, D).astype(self.dtype)
+            # Dispatch einsum in the compute dtype: each (e, c) output slot has
+            # AT MOST ONE nonzero term over n (dispatch is one-hot in (e, c)
+            # per routing), so there is no accumulation to lose — unlike the
+            # EP wire in moe.py, no fp32 pass is needed for exactness.
+            send = jnp.einsum(
+                "gnec,gnd->egcd", dispatch.astype(self.dtype),
+                xg.astype(self.dtype),
+            ).reshape(E, n_groups * C, D)
+        with jax.named_scope("moe.experts"):
+            hmid = nn.gelu(
+                jnp.einsum("exd,edf->exf", send, w1.astype(self.dtype))
+                + b1[:, None, :].astype(self.dtype)
+            )
+            out = (
+                jnp.einsum("exf,efd->exd", hmid, w2.astype(self.dtype))
+                + b2[:, None, :].astype(self.dtype)
+            ).reshape(E, n_groups, C, D)
+        with jax.named_scope("moe.combine"):
+            # Combine accumulates k expert outputs per token — fp32, as the EP
+            # tier's combine einsum does.
+            y = jnp.einsum(
+                "gnec,egcd->gnd", combine, out.astype(jnp.float32)
+            )
+            return y.reshape(B, T, D).astype(self.dtype)
 
 
 class TransformerLM(nn.Module):
@@ -803,34 +825,35 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"pos_enc={self.pos_enc!r}: expected 'learned' or 'rope'"
             )
-        h = nn.Embed(self.vocab, D, dtype=self.dtype,
-                     param_dtype=self.param_dtype, name="embed")(tokens)
-        positions = None
-        if segment_ids is not None and cache is None:
-            # Per-document position restart (shared helper; both schemes:
-            # the learned table gathers at these positions, RoPE rotates
-            # by them).
-            positions = segment_positions(segment_ids)
-        if self.pos_enc == "learned":
-            pos = self.param(
-                "pos", nn.initializers.normal(0.02), (self.max_len, D),
-                self.param_dtype,
-            )
-            if cache is not None:
-                if jnp.ndim(decode_pos) == 0:
-                    h = h + lax.dynamic_slice(
-                        pos, (decode_pos, 0), (T, D)
-                    )[None].astype(self.dtype)
+        with jax.named_scope("embed"):
+            h = nn.Embed(self.vocab, D, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="embed")(tokens)
+            positions = None
+            if segment_ids is not None and cache is None:
+                # Per-document position restart (shared helper; both schemes:
+                # the learned table gathers at these positions, RoPE rotates
+                # by them).
+                positions = segment_positions(segment_ids)
+            if self.pos_enc == "learned":
+                pos = self.param(
+                    "pos", nn.initializers.normal(0.02), (self.max_len, D),
+                    self.param_dtype,
+                )
+                if cache is not None:
+                    if jnp.ndim(decode_pos) == 0:
+                        h = h + lax.dynamic_slice(
+                            pos, (decode_pos, 0), (T, D)
+                        )[None].astype(self.dtype)
+                    else:
+                        # Per-row positions: row r's chunk occupies
+                        # decode_pos[r] .. decode_pos[r] + T - 1 (ragged-prompt
+                        # decode at T = 1; per-row speculative verify chunks).
+                        gather = decode_pos[:, None] + jnp.arange(T)[None]
+                        h = h + pos[gather].astype(self.dtype)
+                elif positions is None:
+                    h = h + pos[None, :T].astype(self.dtype)
                 else:
-                    # Per-row positions: row r's chunk occupies
-                    # decode_pos[r] .. decode_pos[r] + T - 1 (ragged-prompt
-                    # decode at T = 1; per-row speculative verify chunks).
-                    gather = decode_pos[:, None] + jnp.arange(T)[None]
-                    h = h + pos[gather].astype(self.dtype)
-            elif positions is None:
-                h = h + pos[None, :T].astype(self.dtype)
-            else:
-                h = h + pos[positions].astype(self.dtype)
+                    h = h + pos[positions].astype(self.dtype)
         # RoPE adds nothing to h; compute the cos/sin tables ONCE here and
         # share them across every block (n_layers × 2 rotations reuse one
         # set of transcendentals — also under remat, where blocks would
@@ -882,8 +905,9 @@ class TransformerLM(nn.Module):
                          name="ln_f")(h)
         if return_hidden:
             return (h, new_cache) if cache is not None else h
-        logits = nn.Dense(self.vocab, dtype=jnp.float32,
-                          param_dtype=self.param_dtype, name="lm_head")(h)
+        with jax.named_scope("head"):
+            logits = nn.Dense(self.vocab, dtype=jnp.float32,
+                              param_dtype=self.param_dtype, name="lm_head")(h)
         return (logits, new_cache) if cache is not None else logits
 
     def init_cache(self, batch: int, max_len: int = None):
@@ -1165,10 +1189,11 @@ def lm_loss(model: nn.Module):
             )
         else:
             logits = model.apply({"params": params}, tokens, segment_ids=seg)
-        mask = (targets >= 0).astype(jnp.float32)
-        safe = jnp.maximum(targets, 0)
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, safe)
-        loss = jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("ce"):
+            mask = (targets >= 0).astype(jnp.float32)
+            safe = jnp.maximum(targets, 0)
+            ce = optax.softmax_cross_entropy_with_integer_labels(logits, safe)
+            loss = jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         metrics = {"ppl_log": loss}
         if moe:
             aux, dropped = _moe_stats(mut)
@@ -1205,12 +1230,13 @@ def lm_loss_chunked(model: nn.Module, chunk_size: int = 4096):
         head = params["lm_head"]
         # Match nn.Dense(dtype=fp32): inputs cast to fp32 before the matmul
         # (the chunk einsum accumulates fp32 regardless).
-        ce = chunked_softmax_cross_entropy(
-            hidden.astype(jnp.float32), head["kernel"], targets,
-            bias=head["bias"], chunk_size=chunk_size,
-        )
-        mask = (targets >= 0).astype(jnp.float32)
-        loss = jnp.sum(ce) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("ce"):
+            ce = chunked_softmax_cross_entropy(
+                hidden.astype(jnp.float32), head["kernel"], targets,
+                bias=head["bias"], chunk_size=chunk_size,
+            )
+            mask = (targets >= 0).astype(jnp.float32)
+            loss = jnp.sum(ce) / jnp.maximum(jnp.sum(mask), 1.0)
         metrics = {"ppl_log": loss}
         if moe:
             aux, dropped = _moe_stats(mut)

@@ -127,8 +127,8 @@ from chainermn_tpu.observability.tracing import (  # noqa: E402
     Span,
     SpanRing,
     Tracer,
+    annotate,
     chrome_trace_events,
-    step_annotation,
     tracer,
     write_chrome_trace,
 )
@@ -195,7 +195,7 @@ __all__ = [
     "Tracer",
     "tracer",
     "chrome_trace_events",
-    "step_annotation",
+    "annotate",
     "write_chrome_trace",
     "SLOMonitor",
     "rolling_quantile",

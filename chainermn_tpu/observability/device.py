@@ -65,6 +65,7 @@ from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 from chainermn_tpu.observability import metrics as _metrics
+from chainermn_tpu.observability.tracing import annotate as _annotate
 
 #: Compile-record ring capacity — ``CMN_OBS_COMPILE_RING``.
 DEFAULT_COMPILE_RING = 256
@@ -333,7 +334,8 @@ class WatchedFunction:
     compile()`` in the benches — keep working unchanged.
 
     Steady-state per-call cost: the underlying dispatch plus ONE
-    ``_cache_size()`` read and an int compare.  Everything else
+    ``_cache_size()`` read, an int compare and the profiler's enabled
+    flag (``cmn_dispatch``).  Everything else
     (signature walk, ring append, metrics) happens only on the calls
     that actually compiled — never in the hot loop the budgets guard.
     """
@@ -360,11 +362,21 @@ class WatchedFunction:
     # ------------------------------------------------------------ dispatch
     def __call__(self, *args, **kwargs):
         mark = _mon_state["secs"]
-        out = self._fn(*args, **kwargs)
-        n = int(self._fn._cache_size())
-        if n != self._seen:
-            self._watch._record_compile(self, n, args, kwargs, mark)
-            self._seen = n
+        # On the profiler's clock (free while none runs): the dispatch of
+        # every watched program, whoever calls it, and inside the dispatch
+        # that compiled a ``cmn_compile`` child saying which variant it was
+        # and how long the backend took — a recompile inside a traced
+        # window is a span, not a long dispatch to puzzle over.
+        with _annotate("cmn_dispatch", program=self.program):
+            out = self._fn(*args, **kwargs)
+            n = int(self._fn._cache_size())
+            if n != self._seen:
+                with _annotate(
+                    "cmn_compile", program=self.program, n=n,
+                    backend_ms=lambda: 1e3 * (_mon_state["secs"] - mark),
+                ):
+                    self._watch._record_compile(self, n, args, kwargs, mark)
+                self._seen = n
         return out
 
     # ------------------------------------------------------ transparency

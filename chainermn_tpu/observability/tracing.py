@@ -15,10 +15,11 @@ Two integration layers:
   fault injector uses), the checkpointer, and the health guard.  Each span
   also feeds the metrics registry (``host_op.<op>`` count/bytes/latency),
   so the aggregated feed carries op rates without reading the ring.
-* **Device annotations** — :func:`step_annotation` wraps the train step in
-  a ``jax.profiler.TraceAnnotation`` (and guard-relevant regions in
-  ``jax.named_scope``), so an xprof capture lines device streams up with
-  the host spans by step number.
+* **Device annotations** — :func:`annotate` writes a host phase into the
+  profiler's own trace as a ``jax.profiler.TraceAnnotation`` with its
+  counts (the serving tick's phases, every watched program's dispatch and
+  compile, the input iterators), beside the ``jax.named_scope`` s that
+  name device work by layer; both are free while no profiler runs.
 
 Overhead discipline: a span is one ``perf_counter`` pair, one small object,
 one deque append, and three instrument updates — all gated on
@@ -35,6 +36,9 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+from chainermn_tpu.observability import enabled as _obs_enabled
 from chainermn_tpu.observability import metrics as _metrics
 
 #: Bucket edges for host-op latency histograms (ms) — the registry default.
@@ -461,32 +465,65 @@ def write_chrome_trace(path: str, events, rank: int = 0) -> str:
 
 
 # ------------------------------------------------------- device annotations
-def step_annotation(step: int):
-    """``jax.profiler.TraceAnnotation`` for one train step, so an xprof
-    device timeline carries the host step number; a null context when the
-    profiler API is unavailable (or observability is off — checked by the
-    caller, not here)."""
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation("cmn_train_step", step=int(step))
-    except Exception:  # pragma: no cover - profiler API missing
-        import contextlib
-
-        return contextlib.nullcontext()
+# Everything below is written into the PROFILER's own trace and nowhere
+# else: host phases as ``jax.profiler.TraceAnnotation`` s named ``cmn_*``
+# (this section), device work as ``jax.named_scope`` s at the boundaries
+# that are not modules (models/, ops/, optimizers/, serving/engine.py).
+# Both sit on the profiler's clock beside the device operations; both are
+# free while no profiler session is open.  ``docs/observability.md``,
+# "Profiler-clock spans and scopes", lists the vocabulary.
+def _taken(counts: dict) -> dict:
+    return {k: v() if callable(v) else v for k, v in counts.items()}
 
 
-def named_scope(name: str):
-    """``jax.named_scope`` pass-through (HLO op-name prefix inside traced
-    code — the in-graph counterpart of :func:`step_annotation`)."""
-    try:
-        import jax
+class _Span(_TraceAnnotation):
+    """A ``TraceAnnotation`` whose counts may be callables, called when
+    the span is recorded — that is, never while no profiler runs."""
 
-        return jax.named_scope(name)
-    except Exception:  # pragma: no cover
-        import contextlib
+    def __init__(self, name: str, **counts):
+        super().__init__(name, **_taken(counts))
 
-        return contextlib.nullcontext()
+    def set_metadata(self, **counts) -> None:
+        super().set_metadata(**_taken(counts))
+
+
+class _NoSpan:
+    """What :func:`annotate` hands back while nothing is being profiled."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **counts) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def annotate(name: str, **counts):
+    """A host phase on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation`` called ``name`` (prefix ``cmn_``)
+    whose ``counts`` arrive in the trace as the event's stats.  Spans
+    nest: a span's parent is the span open around it on the same thread;
+    spans of one request carry ``req=``.
+
+    A count given as a callable is called only when the span is really
+    recorded, so one that costs something to take (a sum over the live
+    slots) costs nothing otherwise.  Counts known only at the end of the
+    phase go in through ``span.set_metadata(tokens=...)`` before the
+    ``with`` block closes.
+
+    While no profiler session is open (``TraceAnnotation.is_enabled()``
+    is false) this is one flag read and returns a shared no-op span;
+    with ``CMN_OBS=0`` it records nothing either."""
+    if not _TraceAnnotation.is_enabled() or not _obs_enabled():
+        return _NO_SPAN
+    return _Span(name, **counts)
 
 
 #: Process-wide tracer (lazy singleton, like the metrics registry).

@@ -189,6 +189,7 @@ def fused_decode_attention(
         out_specs=pl.BlockSpec((1, 1, G, Dh), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KH, G, Dh), q.dtype),
         interpret=_use_interpret(),
+        name="fused_decode",
     )(*operands)
     return out.reshape(B, H, Dh)
 
@@ -384,6 +385,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KH, R, Dh), q.dtype),
         interpret=_use_interpret(),
+        name="paged_decode",
     )(tbl, lens, *operands)
     if multi:
         return out.reshape(S, KH, T, G, Dh).transpose(0, 2, 1, 3, 4) \

@@ -418,6 +418,7 @@ def _fwd_chunk(q, k, v, seg_q, seg_kv, segmented, heads, kv_heads, causal,
             jax.ShapeDtypeStruct((BH, T, 1), jnp.float32, vma=vma),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return o, lse[..., 0]
 
@@ -604,6 +605,7 @@ def _bwd(segmented, heads, kv_heads, causal, block_q, block_k, interpret,
                 jax.ShapeDtypeStruct((BH, S, D), out_dtypes[1], vma=vma),
             ],
             interpret=interpret,
+            name="flash_bwd_dkv",
         )(*args)
 
     # Under GQA the per-query-head partials leave the kernel in fp32 (the
@@ -681,6 +683,7 @@ def _bwd(segmented, heads, kv_heads, causal, block_q, block_k, interpret,
             out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((BH, T, D), out_dtype, vma=vma),
             interpret=interpret,
+            name="flash_bwd_dq",
         )(*args)
 
     Ck = _stage_chunk(

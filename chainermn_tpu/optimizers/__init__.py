@@ -394,13 +394,15 @@ class MultiNodeOptimizer:
             if augment is not None:
                 batch = augment(_augment_key(augment_seed, state.step, axes),
                                 batch)
-            loss, aux, new_model_state, grads = _accumulated_grads(
-                grad_one, vparams, state.model_state, batch, accum_steps
-            )
-            if compression is not None:
-                grads, new_resid = self._int8_ef_reduce(
-                    grads, state.ef_residual
+            with jax.named_scope("loss_and_grad"):
+                loss, aux, new_model_state, grads = _accumulated_grads(
+                    grad_one, vparams, state.model_state, batch, accum_steps
                 )
+            if compression is not None:
+                with jax.named_scope("cmn_allreduce_grads"):
+                    grads, new_resid = self._int8_ef_reduce(
+                        grads, state.ef_residual
+                    )
             else:
                 grads = self._allreduce_grads(grads)
                 new_resid = state.ef_residual
@@ -422,8 +424,12 @@ class MultiNodeOptimizer:
             else:
                 apply_grads = grads
                 pending = state.pending_grads
-            updates, opt_state = tx.update(apply_grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = tx.update(
+                    apply_grads, state.opt_state, state.params
+                )
+            with jax.named_scope("apply_updates"):
+                params = optax.apply_updates(state.params, updates)
             if ema_decay is not None:
                 ema = jax.tree_util.tree_map(
                     lambda e, p: e * ema_decay

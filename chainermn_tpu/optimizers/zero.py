@@ -336,19 +336,25 @@ class ZeroMultiNodeOptimizer:
             if augment is not None:
                 batch = augment(_augment_key(augment_seed, state.step, axes),
                                 batch)
-            loss, aux, new_model_state, grads = _accumulated_grads(
-                grad_one, params, state.model_state, batch, accum_steps
-            )
-            if compression is not None:
-                g_local, new_resid = scatter_grads_int8_ef(
-                    grads, state.ef_residual
+            with jax.named_scope("loss_and_grad"):
+                loss, aux, new_model_state, grads = _accumulated_grads(
+                    grad_one, params, state.model_state, batch, accum_steps
                 )
-            else:
-                g_local = scatter_grads(grads)
-                new_resid = state.ef_residual
+            with jax.named_scope("cmn_allreduce_grads"):
+                if compression is not None:
+                    g_local, new_resid = scatter_grads_int8_ef(
+                        grads, state.ef_residual
+                    )
+                else:
+                    g_local = scatter_grads(grads)
+                    new_resid = state.ef_residual
             p_local = state.flat_params
-            updates, opt_state = tx.update(g_local, state.opt_state, p_local)
-            p_local = optax.apply_updates(p_local, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = tx.update(
+                    g_local, state.opt_state, p_local
+                )
+            with jax.named_scope("apply_updates"):
+                p_local = optax.apply_updates(p_local, updates)
             metrics = {"loss": lax.pmean(loss, comm.axis_name)}
             for k_, v_ in aux.items():
                 metrics[k_] = lax.pmean(v_, comm.axis_name)

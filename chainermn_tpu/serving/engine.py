@@ -70,6 +70,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from chainermn_tpu.observability.tracing import annotate as _annotate
 from chainermn_tpu.serving.kv_pool import PagedKVPool
 from chainermn_tpu.serving.prefix_cache import PrefixCache
 
@@ -311,7 +312,8 @@ class DecodeEngine:
                 {"params": params}, tokens[:, None], cache=pools,
                 decode_pos=pos, block_tables=tables, slot_mask=active,
             )
-            nxt = jax.vmap(pick)(logits[:, 0], rng, pos, temp)
+            with jax.named_scope("sample"):
+                nxt = jax.vmap(pick)(logits[:, 0], rng, pos, temp)
             return new_pools, nxt
 
         # Prefill stays a SINGLE-ROW program (one slot's chunk per call):
@@ -338,14 +340,16 @@ class DecodeEngine:
             # rows' logits are never read, and a full (chunk, vocab)
             # head matmul is a third of prefill compute.  Same manual
             # fp32 head application as models.lm_loss_chunked.
-            hx = jax.lax.dynamic_slice_in_dim(h, li, 1, axis=1)
-            head = params["lm_head"]
-            logits = (
-                hx[0].astype(jnp.float32)
-                @ head["kernel"].astype(jnp.float32)
-                + head["bias"].astype(jnp.float32)
-            )
-            nxt = pick(logits[0], rng, p0 + li, temp)
+            with jax.named_scope("head"):
+                hx = jax.lax.dynamic_slice_in_dim(h, li, 1, axis=1)
+                head = params["lm_head"]
+                logits = (
+                    hx[0].astype(jnp.float32)
+                    @ head["kernel"].astype(jnp.float32)
+                    + head["bias"].astype(jnp.float32)
+                )
+            with jax.named_scope("sample"):
+                nxt = pick(logits[0], rng, p0 + li, temp)
             return new_pools, dpools, nxt
 
         # One speculative ROUND, one jitted program: k + 1 sequential
@@ -383,12 +387,13 @@ class DecodeEngine:
                 {"params": params}, chunk, cache=pools, decode_pos=pos,
                 block_tables=tables, slot_mask=active,
             )
-            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S, k+1)
-            agree = (g[:, :k] == chunk[:, 1:]).astype(jnp.int32)
-            n_accept = jnp.cumprod(agree, axis=1).sum(axis=1)
-            tok0 = jax.vmap(pick)(logits[:, 0], rng, pos, temp)
-            g = g.at[:, 0].set(tok0)
-            n_accept = jnp.where(temp > 0.0, 0, n_accept)
+            with jax.named_scope("sample"):
+                g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (S, k+1)
+                agree = (g[:, :k] == chunk[:, 1:]).astype(jnp.int32)
+                n_accept = jnp.cumprod(agree, axis=1).sum(axis=1)
+                tok0 = jax.vmap(pick)(logits[:, 0], rng, pos, temp)
+                g = g.at[:, 0].set(tok0)
+                n_accept = jnp.where(temp > 0.0, 0, n_accept)
             return pools, dpools, g, n_accept
 
         # KV-block migration device half (serving/disagg.py): read ONE
@@ -407,17 +412,22 @@ class DecodeEngine:
                     for n in layer
                 }
 
-            t = [one(p) for p in pools]
-            d = [one(p) for p in dpools] if draft_model is not None else None
+            with jax.named_scope("kv_gather"):
+                t = [one(p) for p in pools]
+                d = (
+                    [one(p) for p in dpools]
+                    if draft_model is not None else None
+                )
             return t, d
 
         def put_impl(pools, dpools, idx, tdata, ddata):
             def one(layer, data):
                 return {n: layer[n].at[:, idx].set(data[n]) for n in layer}
 
-            pools = [one(p, x) for p, x in zip(pools, tdata)]
-            if draft_model is not None:
-                dpools = [one(p, x) for p, x in zip(dpools, ddata)]
+            with jax.named_scope("kv_put"):
+                pools = [one(p, x) for p, x in zip(pools, tdata)]
+                if draft_model is not None:
+                    dpools = [one(p, x) for p, x in zip(dpools, ddata)]
             return pools, dpools
 
         # Copy-on-write: duplicate ONE physical block across every layer
@@ -431,9 +441,10 @@ class DecodeEngine:
                     for n in layer
                 }
 
-            pools = [dup(p) for p in pools]
-            if draft_model is not None:
-                dpools = [dup(p) for p in dpools]
+            with jax.named_scope("cow_copy"):
+                pools = [dup(p) for p in pools]
+                if draft_model is not None:
+                    dpools = [dup(p) for p in dpools]
             return pools, dpools
 
         # Every engine program rides the compile watcher (PR 11): each
@@ -533,21 +544,41 @@ class DecodeEngine:
                 f"chunk must be 1-D with a ladder size "
                 f"{self.prefill_ladder}, got {chunk.shape}"
             )
-        self.pools, self.draft_pools, tok = self._prefill(
-            self.params,
-            self.draft_params,
-            self.pools,
-            self.draft_pools,
-            self._up(np.asarray(chunk, np.int32)[None]),
-            np.int32(p0),
-            self._up(np.asarray(table, np.int32)[None]),
-            np.int32(last_idx),
-            self.rng[slot],
-            np.float32(self.temp[slot]),
-        )
-        return int(tok) if last_idx >= 0 else None
+        with _annotate("cmn_engine_upload"):
+            chunk_d = self._up(np.asarray(chunk, np.int32)[None])
+            table_d = self._up(np.asarray(table, np.int32)[None])
+        with _annotate("cmn_engine_dispatch", program="prefill"):
+            self.pools, self.draft_pools, tok = self._prefill(
+                self.params,
+                self.draft_params,
+                self.pools,
+                self.draft_pools,
+                chunk_d,
+                np.int32(p0),
+                table_d,
+                np.int32(last_idx),
+                self.rng[slot],
+                np.float32(self.temp[slot]),
+            )
+        if last_idx < 0:
+            return None
+        with _annotate("cmn_engine_readback"):
+            return int(tok)
 
     # ------------------------------------------------------------ decode
+    def _upload(self, tokens, pos, tables, active) -> tuple:
+        """The decode step's six device arguments after the weights and
+        pools: the four control vectors uploaded, then rng and temp."""
+        with _annotate("cmn_engine_upload"):
+            rng, temp = self._rng_temp()
+            return (
+                self._up(np.asarray(tokens, np.int32)),
+                self._up(np.asarray(pos, np.int32)),
+                self._up(np.asarray(tables, np.int32)),
+                self._up(np.asarray(active, bool)),
+                rng, temp,
+            )
+
     def step(self, tokens: np.ndarray, pos: np.ndarray,
              tables: np.ndarray, active: np.ndarray) -> np.ndarray:
         """One fixed-capacity decode iteration.
@@ -562,17 +593,12 @@ class DecodeEngine:
         Returns ``(capacity,)`` int32 sampled tokens (garbage at inactive
         slots — callers must mask by ``active``).
         """
-        rng, temp = self._rng_temp()
-        self.pools, nxt = self._step(
-            self.params,
-            self.pools,
-            self._up(np.asarray(tokens, np.int32)),
-            self._up(np.asarray(pos, np.int32)),
-            self._up(np.asarray(tables, np.int32)),
-            self._up(np.asarray(active, bool)),
-            rng, temp,
-        )
-        return np.asarray(nxt)
+        ctrl = self._upload(tokens, pos, tables, active)
+        with _annotate("cmn_engine_dispatch", program="decode_step"):
+            self.pools, nxt = self._step(self.params, self.pools, *ctrl)
+        # The wait for the device: everything dispatched drains here.
+        with _annotate("cmn_engine_readback"):
+            return np.asarray(nxt)
 
     def spec_step(self, tokens: np.ndarray, pos: np.ndarray,
                   tables: np.ndarray, active: np.ndarray
@@ -593,19 +619,14 @@ class DecodeEngine:
                 "spec_step on a non-speculative engine — construct with "
                 "draft_model/draft_params/spec_k"
             )
-        rng, temp = self._rng_temp()
-        self.pools, self.draft_pools, toks, n_accept = self._spec(
-            self.params,
-            self.draft_params,
-            self.pools,
-            self.draft_pools,
-            self._up(np.asarray(tokens, np.int32)),
-            self._up(np.asarray(pos, np.int32)),
-            self._up(np.asarray(tables, np.int32)),
-            self._up(np.asarray(active, bool)),
-            rng, temp,
-        )
-        return np.asarray(toks), np.asarray(n_accept)
+        ctrl = self._upload(tokens, pos, tables, active)
+        with _annotate("cmn_engine_dispatch", program="spec_round"):
+            self.pools, self.draft_pools, toks, n_accept = self._spec(
+                self.params, self.draft_params, self.pools,
+                self.draft_pools, *ctrl,
+            )
+        with _annotate("cmn_engine_readback"):
+            return np.asarray(toks), np.asarray(n_accept)
 
     # ----------------------------------------------------- prefix sharing
     def cow_copy(self, src: int, dst: int) -> None:

@@ -115,6 +115,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from chainermn_tpu.observability.tracing import annotate as _annotate
 from chainermn_tpu.observability.metrics import (
     NoopInstrument as _NoopInstrument,
 )
@@ -318,6 +319,7 @@ class Scheduler:
         self._admit_seq = 0
         self.completions: List[Completion] = []
         self._iterations = 0
+        self._emitted = 0  # tokens ever emitted (cmn_serve_emit counts)
         #: True while non-final prefill chunks dispatched since the last
         #: device readback may still be draining — the next decode step's
         #: wall time would absorb them (the ``serve.mixed_ms`` tag).
@@ -1212,23 +1214,26 @@ class Scheduler:
             self.policy.prefill_budget() if self.policy is not None
             else None
         )
-        spent, first = 0, True
-        for slot in sorted(
-            (s for s in self._slots if s is not None and s.prefilling),
-            key=lambda s: s.admit_seq,
-        ):
-            if self._slots[slot.idx] is not slot:
-                continue  # evicted by an earlier candidate's allocation
-            if budget is not None and not first and spent >= budget:
-                self.policy.note_prefill_capped()
-                break
-            p_before = slot.pos
-            progressed = self._prefill_chunk(slot) or progressed
-            # The slot object survives retirement/eviction, and an
-            # eviction-under-pressure bails before advancing pos — the
-            # delta is exactly the tokens this chunk computed.
-            spent += max(0, slot.pos - p_before)
-            first = False
+        spent, first, chunks = 0, True, 0
+        with _annotate("cmn_serve_prefill_round") as span:
+            for slot in sorted(
+                (s for s in self._slots if s is not None and s.prefilling),
+                key=lambda s: s.admit_seq,
+            ):
+                if self._slots[slot.idx] is not slot:
+                    continue  # evicted by an earlier candidate's allocation
+                if budget is not None and not first and spent >= budget:
+                    self.policy.note_prefill_capped()
+                    break
+                p_before = slot.pos
+                progressed = self._prefill_chunk(slot) or progressed
+                # The slot object survives retirement/eviction, and an
+                # eviction-under-pressure bails before advancing pos — the
+                # delta is exactly the tokens this chunk computed.
+                spent += max(0, slot.pos - p_before)
+                first = False
+                chunks += 1
+            span.set_metadata(chunks=chunks)
         return progressed
 
     def _prefill_chunk(self, slot: _Slot) -> bool:
@@ -1253,10 +1258,13 @@ class Scheduler:
         last = end == len(slot.text)
         tc = self.clock.now()
         t0 = time.perf_counter()
-        tok = eng.prefill(
-            slot.idx, chunk, p0, slot.table,
-            last_idx=(end - p0 - 1) if last else -1,
-        )
+        with _annotate("cmn_serve_prefill", req=slot.entry.req.id,
+                       slot=slot.idx, p0=p0, tokens=end - p0, padded=size,
+                       final=int(last)):
+            tok = eng.prefill(
+                slot.idx, chunk, p0, slot.table,
+                last_idx=(end - p0 - 1) if last else -1,
+            )
         dur_ms = (time.perf_counter() - t0) * 1e3
         self._m_prefill.observe(dur_ms)
         if self.ledger is not None:
@@ -1313,147 +1321,178 @@ class Scheduler:
         ]
         if not live:
             return False
-        S = self.engine.capacity
-        k = self.engine.spec_k
-        tokens = np.zeros((S,), np.int32)
-        pos = np.zeros((S,), np.int32)
-        tables = np.zeros((S, self.engine.max_blocks), np.int32)
-        active = np.zeros((S,), bool)
-        for s in live:
-            # The step writes position `pos` (a speculative round writes
-            # through `pos + spec_k`) — make sure those blocks exist.
-            self._alloc_for(
-                s, blocks_for(s.pos + 1 + k, self.engine.block_len)
-            )
-        live = [
-            s for s in self._slots if s is not None and not s.prefilling
-        ]
-        if not live:
-            return True  # everything evicted itself; still progress
-        for s in live:
-            tokens[s.idx] = s.last_token
-            pos[s.idx] = s.pos
-            tables[s.idx] = s.table
-            active[s.idx] = True
-        mixed = self._unsynced_prefill
-        self._iterations += 1
-        tc = self.clock.now()
-        t0 = time.perf_counter()
-        if self._fault is not None:
-            # ``skew@serve_step:N:ms`` — inside the timed window, so an
-            # injected stretch lands in this iteration's histogram
-            # exactly like a real slowdown would.
-            self._fault.hook("serve_step", count=self._iterations)
-        if k:
-            out, n_accept = self.engine.spec_step(
-                tokens, pos, tables, active
-            )
-        else:
-            out = self.engine.step(tokens, pos, tables, active)
-        dur_ms = (time.perf_counter() - t0) * 1e3
-        # The token readback above drained the dispatch queue: any
-        # prefill work queued before this step has now been absorbed
-        # into dur_ms — book the contaminated iteration separately so
-        # serve.decode_ms (and the SLO token stream) stay clean.
-        self._unsynced_prefill = False
-        if mixed:
-            self._m_mixed.observe(dur_ms)
-        else:
-            self._m_decode.observe(dur_ms)
-            if self.slo is not None:
-                self.slo.observe("token", dur_ms)
-            if self._dev_enabled:
-                self._dev_ms_sum += dur_ms
-                self._dev_ms_n += 1
-        if self.timeline is not None:
-            self.timeline.record(
-                "decode", t=tc, dur_ms=dur_ms,
-                info={"reqs": [(s.idx, s.entry.req.id) for s in live],
-                      "mixed": mixed},
-            )
-        if self.slo is not None and \
-                self._iterations % self.slo.check_every == 0:
-            self.slo.check()
-            if self.policy is not None:
-                # Feed the fresh verdict into the drift latch on the
-                # check cadence — hysteresis counts CHECKS, not
-                # iterations, mirroring the autoscaler's streaks.
-                self.policy.on_slo_check(self.slo.last_report)
-        if self.incidents is not None and \
-                self._iterations % self._mem_every == 0:
-            # Watch-rule evaluation on the SLO-check cadence, AFTER the
-            # check refreshed the drift gauge: a breach captures its
-            # bundle while the registry still shows the breach.
-            self.incidents.evaluate()
-        if self.memory is not None and \
-                self._iterations % self._mem_every == 0:
-            self.memory.sample(kv=self._kv_sample())
-        if self._dev_enabled and \
-                self._iterations % self._mem_every == 0:
-            # capture=False: live requests are between decode steps
-            # right here — the one-time cost capture is a synchronous
-            # backend compile and belongs at drain, never mid-traffic.
-            self._publish_device(capture=False)
-        for s in live:
-            if self.ledger is not None:
-                # Booked AFTER the step completed: a replica crash at
-                # serve_step raised before reaching here, so a harvested
-                # request is never billed for an iteration that produced
-                # nothing (the harvest books the eviction instead).
-                self.ledger.book(
-                    s.entry.req.id, "decode_iterations", 1
-                )
-            if self.policy is not None:
-                self.policy.charge(
-                    s.entry.req.tenant, "decode_iterations", 1
-                )
-            if k:
-                # One speculative round: emit the accepted drafts plus
-                # the target's correction/bonus, token by token — EOS or
-                # the budget can retire the slot mid-round, and the
-                # over-accepted tail is simply dropped (its K/V is
-                # causally masked and rewritten by later steps: rollback
-                # is the position not advancing, nothing is copied).
-                na = int(n_accept[s.idx])
-                emitted = 0
-                for j in range(na + 1):
-                    s.pos += 1
-                    self._emit(s, int(out[s.idx, j]))
-                    emitted += 1
-                    if self._slots[s.idx] is not s:
-                        break  # retired mid-round (EOS / budget)
-                if s.entry.req.temperature <= 0:
-                    # Acceptance capped at what was EMITTED: a mid-run
-                    # retirement leaves the tail drafts unused — neither
-                    # accepted nor rejected — while a full emission
-                    # (correction/bonus included) adjudicated all k.
-                    acc = min(emitted, na)
-                    prop = acc if emitted <= na else k
-                    entry = s.entry
-                    entry.spec_proposed += prop
-                    entry.spec_accepted += acc
-                    self.spec_proposed += prop
-                    self.spec_accepted += acc
-                    self._m_spec_prop.inc(prop)
-                    self._m_spec_acc.inc(acc)
-                    if self.ledger is not None:
-                        self.ledger.book(
-                            entry.req.id, "spec_proposed", prop
-                        )
-                        self.ledger.book(
-                            entry.req.id, "spec_accepted", acc
-                        )
-                    self._m_spec_rate.set(
-                        self.spec_accepted / max(self.spec_proposed, 1)
+        # Phases on the profiler's clock (free while none runs): build =
+        # block allocation + the four control vectors; the engine's own
+        # upload/dispatch/readback spans; publish = histograms, timeline,
+        # SLO / incident / memory / device cadence; emit = ledger, policy,
+        # token accounting, retirement.  The counts say how much of the
+        # paged kernel's grid holds a token: it visits capacity x
+        # max_blocks table entries whatever the contexts are.
+        with _annotate("cmn_serve_decode") as span:
+            with _annotate("cmn_serve_build"):
+                S = self.engine.capacity
+                k = self.engine.spec_k
+                tokens = np.zeros((S,), np.int32)
+                pos = np.zeros((S,), np.int32)
+                tables = np.zeros((S, self.engine.max_blocks), np.int32)
+                active = np.zeros((S,), bool)
+                for s in live:
+                    # The step writes position `pos` (a speculative round
+                    # writes through `pos + spec_k`) — make sure those
+                    # blocks exist.
+                    self._alloc_for(
+                        s, blocks_for(s.pos + 1 + k, self.engine.block_len)
                     )
+                live = [
+                    s for s in self._slots
+                    if s is not None and not s.prefilling
+                ]
+                if not live:
+                    return True  # everything evicted itself; still progress
+                for s in live:
+                    tokens[s.idx] = s.last_token
+                    pos[s.idx] = s.pos
+                    tables[s.idx] = s.table
+                    active[s.idx] = True
+            span.set_metadata(
+                live=len(live),
+                kv_blocks_resident=lambda: sum(
+                    blocks_for(s.pos + 1, self.engine.block_len)
+                    for s in live
+                ),
+                kv_blocks_grid=S * self.engine.max_blocks,
+                table_width=self.engine.max_blocks,
+            )
+            mixed = self._unsynced_prefill
+            self._iterations += 1
+            tc = self.clock.now()
+            t0 = time.perf_counter()
+            if self._fault is not None:
+                # ``skew@serve_step:N:ms`` — inside the timed window, so an
+                # injected stretch lands in this iteration's histogram
+                # exactly like a real slowdown would.
+                self._fault.hook("serve_step", count=self._iterations)
+            if k:
+                out, n_accept = self.engine.spec_step(
+                    tokens, pos, tables, active
+                )
             else:
-                s.pos += 1
-                self._emit(s, int(out[s.idx]))
+                out = self.engine.step(tokens, pos, tables, active)
+            dur_ms = (time.perf_counter() - t0) * 1e3
+            with _annotate("cmn_serve_publish"):
+                # The token readback above drained the dispatch queue: any
+                # prefill work queued before this step has now been absorbed
+                # into dur_ms — book the contaminated iteration separately so
+                # serve.decode_ms (and the SLO token stream) stay clean.
+                self._unsynced_prefill = False
+                if mixed:
+                    self._m_mixed.observe(dur_ms)
+                else:
+                    self._m_decode.observe(dur_ms)
+                    if self.slo is not None:
+                        self.slo.observe("token", dur_ms)
+                    if self._dev_enabled:
+                        self._dev_ms_sum += dur_ms
+                        self._dev_ms_n += 1
+                if self.timeline is not None:
+                    self.timeline.record(
+                        "decode", t=tc, dur_ms=dur_ms,
+                        info={"reqs": [(s.idx, s.entry.req.id) for s in live],
+                              "mixed": mixed},
+                    )
+                if self.slo is not None and \
+                        self._iterations % self.slo.check_every == 0:
+                    self.slo.check()
+                    if self.policy is not None:
+                        # Feed the fresh verdict into the drift latch on the
+                        # check cadence — hysteresis counts CHECKS, not
+                        # iterations, mirroring the autoscaler's streaks.
+                        self.policy.on_slo_check(self.slo.last_report)
+                if self.incidents is not None and \
+                        self._iterations % self._mem_every == 0:
+                    # Watch-rule evaluation on the SLO-check cadence, AFTER the
+                    # check refreshed the drift gauge: a breach captures its
+                    # bundle while the registry still shows the breach.
+                    self.incidents.evaluate()
+                if self.memory is not None and \
+                        self._iterations % self._mem_every == 0:
+                    self.memory.sample(kv=self._kv_sample())
+                if self._dev_enabled and \
+                        self._iterations % self._mem_every == 0:
+                    # capture=False: live requests are between decode steps
+                    # right here — the one-time cost capture is a synchronous
+                    # backend compile and belongs at drain, never mid-traffic.
+                    self._publish_device(capture=False)
+            done0, emitted0 = len(self.completions), self._emitted
+            with _annotate("cmn_serve_emit") as emit_span:
+                for s in live:
+                    if self.ledger is not None:
+                        # Booked AFTER the step completed: a replica crash
+                        # at serve_step raised before reaching here, so a
+                        # harvested request is never billed for an iteration
+                        # that produced nothing (the harvest books the
+                        # eviction instead).
+                        self.ledger.book(
+                            s.entry.req.id, "decode_iterations", 1
+                        )
+                    if self.policy is not None:
+                        self.policy.charge(
+                            s.entry.req.tenant, "decode_iterations", 1
+                        )
+                    if k:
+                        # One speculative round: emit the accepted drafts
+                        # plus the target's correction/bonus, token by token
+                        # — EOS or the budget can retire the slot mid-round,
+                        # and the over-accepted tail is simply dropped (its
+                        # K/V is causally masked and rewritten by later
+                        # steps: rollback is the position not advancing,
+                        # nothing is copied).
+                        na = int(n_accept[s.idx])
+                        emitted = 0
+                        for j in range(na + 1):
+                            s.pos += 1
+                            self._emit(s, int(out[s.idx, j]))
+                            emitted += 1
+                            if self._slots[s.idx] is not s:
+                                break  # retired mid-round (EOS / budget)
+                        if s.entry.req.temperature <= 0:
+                            # Acceptance capped at what was EMITTED: a
+                            # mid-run retirement leaves the tail drafts
+                            # unused — neither accepted nor rejected — while
+                            # a full emission (correction/bonus included)
+                            # adjudicated all k.
+                            acc = min(emitted, na)
+                            prop = acc if emitted <= na else k
+                            entry = s.entry
+                            entry.spec_proposed += prop
+                            entry.spec_accepted += acc
+                            self.spec_proposed += prop
+                            self.spec_accepted += acc
+                            self._m_spec_prop.inc(prop)
+                            self._m_spec_acc.inc(acc)
+                            if self.ledger is not None:
+                                self.ledger.book(
+                                    entry.req.id, "spec_proposed", prop
+                                )
+                                self.ledger.book(
+                                    entry.req.id, "spec_accepted", acc
+                                )
+                            self._m_spec_rate.set(
+                                self.spec_accepted / max(self.spec_proposed, 1)
+                            )
+                    else:
+                        s.pos += 1
+                        self._emit(s, int(out[s.idx]))
+                emit_span.set_metadata(
+                    tokens=self._emitted - emitted0,
+                    retired=len(self.completions) - done0,
+                )
         return True
 
     def _emit(self, slot: _Slot, tok: int) -> None:
         """Account one generated token; retire the slot when done."""
         self._m_tokens.inc()
+        self._emitted += 1
         slot.generated.append(tok)
         slot.last_token = tok
         req = slot.entry.req
@@ -1521,25 +1560,42 @@ class Scheduler:
         work at all).  :meth:`run` is a tick loop over one scheduler;
         the :class:`~chainermn_tpu.serving.router.Router` interleaves
         ticks across replicas on a shared clock."""
+        # The tick's phases are on the profiler's clock as nested
+        # ``cmn_serve_*`` spans with their counts (free while no profiler
+        # runs; vocabulary in docs/observability.md).
         progressed = False
-        if self._cancel_deadlines():
-            progressed = True
-        while self._try_admit():
-            progressed = True
-        if self._prefill_round():
-            progressed = True
-        if self._decode_step():
-            progressed = True
-        self._m_queue.set(len(self._queue))
-        self._m_occ.set(self.slot_occupancy)
-        if self.policy is not None and not self.policy.fleet:
-            # Standalone scheduler: its queue IS the fleet view.  Under
-            # a router (policy.fleet) the router publishes the
-            # fleet-wide census instead — per-replica publishes would
-            # thrash the shared gauges.
-            self.policy.publish_queue(
-                [e.req.tenant for e in self._queue]
-            )
+        with _annotate("cmn_serve_tick", iter=self._iterations):
+            with _annotate("cmn_serve_deadlines"):
+                if self._cancel_deadlines():
+                    progressed = True
+            with _annotate("cmn_serve_admit",
+                           queue=lambda: len(self._queue)) as span:
+                seq0 = self._admit_seq
+                while self._try_admit():
+                    progressed = True
+                if self._admit_seq != seq0:
+                    span.set_metadata(
+                        admitted=self._admit_seq - seq0,
+                        req=lambda: ",".join(
+                            str(s.entry.req.id) for s in self._slots
+                            if s is not None and s.admit_seq >= seq0
+                        ),
+                    )
+            if self._prefill_round():
+                progressed = True
+            if self._decode_step():
+                progressed = True
+            with _annotate("cmn_serve_publish"):
+                self._m_queue.set(len(self._queue))
+                self._m_occ.set(self.slot_occupancy)
+                if self.policy is not None and not self.policy.fleet:
+                    # Standalone scheduler: its queue IS the fleet view.
+                    # Under a router (policy.fleet) the router publishes
+                    # the fleet-wide census instead — per-replica
+                    # publishes would thrash the shared gauges.
+                    self.policy.publish_queue(
+                        [e.req.tenant for e in self._queue]
+                    )
         return progressed
 
     def run(self, requests: Optional[Sequence[Request]] = None

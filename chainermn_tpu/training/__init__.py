@@ -11,7 +11,6 @@ every reference example).
 
 from __future__ import annotations
 
-import contextlib
 import json
 import math
 import os
@@ -26,7 +25,6 @@ from chainermn_tpu import observability as _obs
 from chainermn_tpu.observability import aggregate as _oagg
 from chainermn_tpu.observability import flight as _oflight
 from chainermn_tpu.observability import metrics as _omet
-from chainermn_tpu.observability import tracing as _otrace
 from chainermn_tpu.resilience import faults as _faults
 
 
@@ -544,15 +542,13 @@ class Trainer:
                 # poison THIS iteration's batch (counted 1-based like the
                 # iter site).
                 batch = _faults.poison_batch(inj, batch, self.iteration + 1)
-            # Host-side profiler annotation around the step dispatch: an
-            # xprof capture lines its device stream up with these step
-            # numbers (and with the host spans in the ring).
-            with (_otrace.step_annotation(self.iteration + 1)
-                  if self._obs_on else contextlib.nullcontext()):
-                self.state, metrics = self.optimizer.update(
-                    self.state, batch, self.loss_fn, has_aux=self.has_aux,
-                    stateful=self.stateful, **self.step_kwargs,
-                )
+            # The step is a watched program: its dispatch is on the
+            # profiler's clock as ``cmn_dispatch(program=train_step)``
+            # whoever calls it (observability/device.py).
+            self.state, metrics = self.optimizer.update(
+                self.state, batch, self.loss_fn, has_aux=self.has_aux,
+                stateful=self.stateful, **self.step_kwargs,
+            )
             self.iteration += 1
             if inj is not None:
                 # Fail-silent injection, post-step: flip@param corrupts the

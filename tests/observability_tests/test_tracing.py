@@ -120,8 +120,3 @@ def test_span_publishes_op_metrics(monkeypatch):
     assert snap["host_op.send_obj.ms"]["count"] == 2
 
 
-def test_step_annotation_is_usable_context():
-    with otrace.step_annotation(7):
-        pass
-    with otrace.named_scope("cmn_region"):
-        pass

@@ -101,21 +101,24 @@ def _flash(backward):
 
 
 #: name -> ((function, [(shape, dtype), ...]), Mosaic kernel launches
-#: expected in the compiled text)
+#: expected in the compiled text, the stable names those kernels carry —
+#: a trace reduction finds a kernel by ``%<name>``, not by its result dtype)
 _CASES = {
-    "paged_bf16": (_paged((S, H, DH), jnp.bfloat16), 1),
-    "paged_int8": (_paged((S, H, DH), jnp.int8), 1),
-    "paged_verify_t4": (_paged((S, 4, H, DH), jnp.bfloat16), 1),
-    "fused_l1024": (_fused(), 1),
-    "flash_fwd": (_flash(False), 1),
+    "paged_bf16": (_paged((S, H, DH), jnp.bfloat16), 1, ["paged_decode"]),
+    "paged_int8": (_paged((S, H, DH), jnp.int8), 1, ["paged_decode"]),
+    "paged_verify_t4": (_paged((S, 4, H, DH), jnp.bfloat16), 1,
+                        ["paged_decode"]),
+    "fused_l1024": (_fused(), 1, ["fused_decode"]),
+    "flash_fwd": (_flash(False), 1, ["flash_fwd"]),
     # forward (for the residuals) + the dq and dk/dv kernels
-    "flash_bwd": (_flash(True), 3),
+    "flash_bwd": (_flash(True), 3,
+                  ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
 }
 
 
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_kernel_compiles_for_v5e(name, chip):
-    (fn, shapes), launches = _CASES[name]
+    (fn, shapes), launches, kernels = _CASES[name]
     on_chip = SingleDeviceSharding(chip)
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
             for shape, dtype in shapes]
@@ -123,3 +126,11 @@ def test_kernel_compiles_for_v5e(name, chip):
     assert text.count("tpu_custom_call") == launches, (
         name, text.count("tpu_custom_call")
     )
+    import re
+
+    called = set(re.findall(r"%([a-z_]+)(?:\.\d+)? = [^\n]*tpu_custom_call",
+                            text))
+    # (an enclosing transformation wraps the name when no named scope
+    # encloses the call: ``transpose_jvp_flash_bwd_dq__``)
+    assert len(called) == len(kernels) and all(
+        any(k in c for c in called) for k in kernels), (name, called)

@@ -332,17 +332,35 @@ def test_skew_fault_files_one_deduped_incident(obs_run, prompts,
 
 def test_unfaulted_twin_files_zero_incidents(obs_run, prompts, tmp_path):
     """The quiet control for the incident plane: the identical workload
-    without the fault breaches nothing and files nothing."""
+    without the fault breaches nothing and files nothing.
+
+    "Without the fault" is what this twin controls, so that is what it
+    asserts on: the latencies its monitor sees come from a steady clock of
+    the test's own (every observation reads 2 ms), not from wall-clock
+    decode times — under six xdist workers on a loaded CPU those jitter by
+    more than the 50% tolerance all by themselves and the twin filed a
+    drift incident about the host it ran on.  Everything downstream of the
+    observation is the real thing: the scheduler's check cadence, the
+    baseline/window arithmetic, the incident manager's rule evaluation."""
     from chainermn_tpu.observability.incident import IncidentManager
+
+    class SteadyClockSLO(SLOMonitor):
+        seen = 0
+
+        def observe(self, stream, value_ms):
+            self.seen += 1
+            super().observe(stream, 2.0)
 
     eng = obs_run[0]
     reg = MetricsRegistry()
     inc_dir = tmp_path / "incidents"
     mgr = IncidentManager(registry=reg, directory=str(inc_dir))
-    slo = SLOMonitor(registry=reg, window=32, min_samples=8,
-                     tolerance=0.5, check_every=4)
+    slo = SteadyClockSLO(registry=reg, window=32, min_samples=8,
+                         tolerance=0.5, check_every=4)
     sched = Scheduler(eng, registry=reg, slo=slo, incidents=mgr)
     sched.run([Request(id=1, prompt=prompts[0], max_new_tokens=32)])
+    # the monitor was fed by the real loop and judged on its cadence
+    assert slo.seen >= 32 and slo.last_report["token"]["breached"] is False
     assert mgr.count == 0 and mgr.dropped == 0
     assert not inc_dir.is_dir() or not any(inc_dir.iterdir())
     snap = reg.snapshot()
