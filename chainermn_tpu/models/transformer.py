@@ -170,10 +170,11 @@ class _DecoderBlock(nn.Module):
         one set of weights serves training and generation.
 
         ``block_tables`` (``(B, max_blocks)`` int32) switches the decode
-        path to the PAGED cache: the cache entries are physical block
-        pools ``(KH, num_blocks, block_len, Dh)`` shared by all rows, and
-        each row's positions are mapped through its block table
-        (``chainermn_tpu/serving``).  ``slot_mask`` (``(B,)`` bool) marks
+        path to the PAGED cache: the cache entry is one physical block
+        pool ``{"kv": (num_blocks, block_len, KH * 2 * Dh)}`` shared by all
+        rows (token-major, each head's ``[k | v]`` side by side —
+        :mod:`chainermn_tpu.serving.kv_pool`), and each row's positions
+        are mapped through its block table.  ``slot_mask`` (``(B,)`` bool) marks
         live decode slots — masked rows write nothing (their scatter is
         redirected to the reserved parking block with their own current
         value, keeping duplicate-index writes deterministic)."""
@@ -183,6 +184,7 @@ class _DecoderBlock(nn.Module):
             flash_attention,
             fused_decode_attention,
             paged_decode_attention,
+            paged_kernel_takes,
             reference_attention,
             resolve_attention,
             sharded_fused_decode_attention,
@@ -294,7 +296,7 @@ class _DecoderBlock(nn.Module):
             # the context/batch fits.  Dequantization never materializes a
             # float cache: the k scale folds into the score einsum's
             # output, the v scale into the probability operand.
-            quant = "k_scale" in cache
+            quant = ("kv_scale" if paged else "k_scale") in cache
             with jax.named_scope("kv_write"):
                 if quant:
                     kf = k.astype(jnp.float32)
@@ -315,49 +317,52 @@ class _DecoderBlock(nn.Module):
                     # Float cache: cast to the cache's storage dtype (kv_dtype
                     # may differ from the compute dtype — e.g. store bf16 under
                     # fp32 compute).
-                    k_w = k.astype(cache["k"].dtype)
-                    v_w = v.astype(cache["v"].dtype)
+                    kvd = cache["kv" if paged else "k"].dtype
+                    k_w = k.astype(kvd)
+                    v_w = v.astype(kvd)
             write_pos = (
                 decode_pos % self.window if rolling else decode_pos
             )
             if paged:
                 # Paged pool write: each row's positions map through its
-                # block table to physical pool blocks; one scatter per
-                # pool.  Masked (idle) slots redirect to the reserved
-                # parking block 0 and write back their own current value —
+                # block table to physical pool blocks; ONE scatter of whole
+                # token rows ``[k_0|v_0|k_1|v_1|...]`` into the token-major
+                # pool (serving/kv_pool.py says why that layout) — the same
+                # statement for decode (T = 1), verify and prefill chunks.
+                # Masked (idle) slots redirect to the reserved parking
+                # block 0 and write back their own current value —
                 # duplicate indices then carry duplicate VALUES, keeping
                 # the scatter deterministic.
+                Dh = D // H
                 with jax.named_scope("kv_write"):
-                    pool_k, pool_v = cache["k"], cache["v"]
-                    BL = pool_k.shape[2]
+                    pool = cache["kv"]
+                    BL = pool.shape[1]
                     pb = jnp.take_along_axis(
                         block_tables, q_pos // BL, axis=1
                     )  # (B, T) physical block per written position
                     off = q_pos % BL
+                    row = jnp.concatenate([k_w, v_w], axis=-1).reshape(
+                        B, T, KH * 2 * Dh
+                    )
+                    if quant:
+                        # (B, T, KH, 2): a head's k and v scale, as the
+                        # kernel's (2, block_len) scale panel pairs them.
+                        srow = jnp.stack([k_scale, v_scale], axis=-1)
                     if slot_mask is not None:
                         live = slot_mask.astype(bool)[:, None]
                         pb = jnp.where(live, pb, 0)
                         off = jnp.where(live, off, 0)
-                    k_t = jnp.transpose(k_w, (2, 0, 1, 3))  # (KH, B, T, Dh)
-                    v_t = jnp.transpose(v_w, (2, 0, 1, 3))
-                    if slot_mask is not None:
-                        lv = live[None, :, :, None]
-                        k_t = jnp.where(lv, k_t, pool_k[:, pb, off])
-                        v_t = jnp.where(lv, v_t, pool_v[:, pb, off])
-                    kc = pool_k.at[:, pb, off].set(k_t)
-                    vc = pool_v.at[:, pb, off].set(v_t)
-                    if quant:
-                        ks_t = jnp.transpose(k_scale, (2, 0, 1))  # (KH, B, T)
-                        vs_t = jnp.transpose(v_scale, (2, 0, 1))
-                        if slot_mask is not None:
-                            ks_t = jnp.where(
-                                live[None], ks_t, cache["k_scale"][:, pb, off]
+                        row = jnp.where(live[..., None], row, pool[pb, off])
+                        if quant:
+                            srow = jnp.where(
+                                live[..., None, None], srow,
+                                cache["kv_scale"][pb, :, :, off],
                             )
-                            vs_t = jnp.where(
-                                live[None], vs_t, cache["v_scale"][:, pb, off]
-                            )
-                        ks_c = cache["k_scale"].at[:, pb, off].set(ks_t)
-                        vs_c = cache["v_scale"].at[:, pb, off].set(vs_t)
+                    kvc = pool.at[pb, off].set(row)
+                    sc_c = (
+                        cache["kv_scale"].at[pb, :, :, off].set(srow)
+                        if quant else None
+                    )
                 # The kernel's causal bound is the FIRST query position's
                 # (offset t adds t in-kernel); T == 1 reduces to the
                 # classic decode bound.  Idle slots mask to 0.
@@ -367,12 +372,13 @@ class _DecoderBlock(nn.Module):
                 # Verify chunks (per-row decode_pos, small static T — the
                 # speculative path) keep the Pallas kernel; prefill
                 # chunks (scalar decode_pos, large T) stay on the
-                # gathered einsum.
+                # gathered einsum, and so does a head width whose
+                # [k|v] panel the chip's kernel cannot tile.
                 verify = (
                     jnp.ndim(decode_pos) == 1 and 1 < T <= MAX_VERIFY_T
                 )
                 if (self.decode_attention == "fused" and not self.window
-                        and (T == 1 or verify)):
+                        and (T == 1 or verify) and paged_kernel_takes(Dh)):
                     with jax.named_scope("attn.paged"):
                         if self.decode_mesh is not None:
                             # Tensor-parallel engines: the kernel runs per
@@ -381,45 +387,41 @@ class _DecoderBlock(nn.Module):
                             # already installs); bit-identical to the
                             # unsharded call, no collective added here.
                             a = sharded_paged_decode_attention(
-                                q[:, 0] if T == 1 else q, kc, vc,
-                                block_tables, valid,
-                                k_scale=ks_c if quant else None,
-                                v_scale=vs_c if quant else None,
+                                q[:, 0] if T == 1 else q, kvc,
+                                block_tables, valid, sc_c,
                                 mesh=self.decode_mesh,
                             )
                         else:
                             a = paged_decode_attention(
-                                q[:, 0] if T == 1 else q, kc, vc, block_tables,
-                                valid,
-                                k_scale=ks_c if quant else None,
-                                v_scale=vs_c if quant else None,
+                                q[:, 0] if T == 1 else q, kvc,
+                                block_tables, valid, sc_c,
                             )
                         if T == 1:
                             a = a[:, None]
                 else:
                     with jax.named_scope("attn.gathered"):
                         # Gathered fallback (prefill chunks; einsum engines):
-                        # materialize each row's logical kv-head-major view of
-                        # its blocks and run the shared einsum path.
-                        kg = jnp.swapaxes(kc[:, block_tables], 0, 1)
-                        vg = jnp.swapaxes(vc[:, block_tables], 0, 1)
-                        Lg = kg.shape[2] * kg.shape[3]
-                        kg = kg.reshape(B, KH, Lg, D // H)
-                        vg = vg.reshape(B, KH, Lg, D // H)
+                        # gather each row's blocks and view THEM kv-head
+                        # major — a transpose of one slot's context, never
+                        # of a pool — for the shared einsum path.
+                        MB = block_tables.shape[1]
+                        g = kvc[block_tables].reshape(
+                            B, MB * BL, KH, 2, Dh
+                        )
+                        kg = jnp.transpose(g[:, :, :, 0], (0, 2, 1, 3))
+                        vg = jnp.transpose(g[:, :, :, 1], (0, 2, 1, 3))
                         ksg = vsg = None
                         if quant:
-                            ksg = jnp.swapaxes(
-                                ks_c[:, block_tables], 0, 1
-                            ).reshape(B, KH, Lg)
-                            vsg = jnp.swapaxes(
-                                vs_c[:, block_tables], 0, 1
-                            ).reshape(B, KH, Lg)
+                            # (B, MB, KH, 2, BL) -> (B, KH, 2, MB * BL)
+                            sg = jnp.transpose(
+                                sc_c[block_tables], (0, 2, 3, 1, 4)
+                            ).reshape(B, KH, 2, MB * BL)
+                            ksg, vsg = sg[:, :, 0], sg[:, :, 1]
                         a = _attend_kv_major(
                             q, kg, vg, q_pos, self.window, ksg, vsg
                         )
                 new_cache = (
-                    {"k": kc, "v": vc, "k_scale": ks_c, "v_scale": vs_c}
-                    if quant else {"k": kc, "v": vc}
+                    {"kv": kvc, "kv_scale": sc_c} if quant else {"kv": kvc}
                 )
             elif kv_major:
                 # kv-head-major contiguous cache (B, KH, L, Dh) — the

@@ -13,6 +13,7 @@ from chainermn_tpu.ops.decode_attention import (
     MAX_VERIFY_T,
     fused_decode_attention,
     paged_decode_attention,
+    paged_kernel_takes,
     sharded_fused_decode_attention,
     sharded_paged_decode_attention,
 )
@@ -42,6 +43,7 @@ __all__ = [
     "max_pool_fused",
     "fused_decode_attention",
     "paged_decode_attention",
+    "paged_kernel_takes",
     "sharded_fused_decode_attention",
     "sharded_paged_decode_attention",
     "MAX_FUSED_LEN",

@@ -30,15 +30,19 @@ upstream.
 
 :func:`paged_decode_attention` is the continuous-batching twin
 (``chainermn_tpu/serving``): the cache lives in a fixed device-resident
-**block pool** ``(KH, num_blocks, block_len, Dh)`` and each slot owns a
+**block pool**, one array per layer, token-major and lane-dense —
+``(num_blocks, block_len, KH * 2 * Dh)`` with each head's key and value
+side by side in one lane group ``[k_h | v_h]`` (why that layout and no
+other: :mod:`chainermn_tpu.serving.kv_pool`) — and each slot owns a
 block table mapping logical cache blocks to physical pool blocks
 (vLLM/PagedAttention, Kwon et al. 2023).  Grid ``(S, KH, MB)`` with the
-block tables scalar-prefetched so each program's K/V DMA is indexed
-``pool[kh, table[s, m]]`` — the kernel walks the table directly, no
+block tables scalar-prefetched so each program's ONE K/V DMA is the
+``(block_len, 2 * Dh)`` panel ``pool[table[s, m], :, kh]``, split into
+``k`` and ``v`` in VMEM — the kernel walks the table directly, no
 gathered contiguous copy is ever materialized.  Blocks accumulate
 through the online-softmax recurrence (running max / normalizer /
 fp32 accumulator in VMEM scratch), so there is no ``MAX_FUSED_LEN``
-cap: VMEM holds one ``(block_len, Dh)`` panel at a time.  Blocks
+cap: VMEM holds one panel at a time.  Blocks
 entirely past ``valid_len`` are skipped (``@pl.when``), so a
 short sequence in a long-capacity slot pays for the blocks it
 actually fills.  A 4-D query ``(S, T, H, Dh)`` is the **multi-query
@@ -47,7 +51,9 @@ positions ride as extra rows of each ``(slot, kv-head)`` program, and
 query offset ``t`` attends positions ``< valid_len + t`` — per-position
 causality inside the verify chunk, one kernel launch for all ``k + 1``
 positions (``T <= MAX_VERIFY_T``; ``T == 1`` is bit-identical to the
-3-D call).
+3-D call).  On a chip the panel's lane width ``2 * Dh`` has to be a
+multiple of 128 (:func:`paged_kernel_takes`); any other head width
+takes the model's gathered einsum path.
 
 No reference counterpart (the reference has no incremental-decode stack;
 SURVEY §2.9's examples are training-side) — this extends the repo's
@@ -62,10 +68,11 @@ the partitioner propagate through ``pallas_call``.
 :func:`sharded_paged_decode_attention` and
 :func:`sharded_fused_decode_attention` close the gap by running the
 kernel **per shard** under ``jax.shard_map`` over a 1-D mesh: queries
-shard on the query-head axis, caches/pools on the KV-head axis (the
-serving plane's kv-head-major pool layout was chosen in PR 4 with
-exactly this cut in mind), block tables / lengths ride replicated, and
-each shard runs the unmodified kernel over its local ``KH / n`` heads.
+shard on the query-head axis, contiguous caches on their KV-head axis,
+paged pools on their LAST axis (whole ``[k_h | v_h]`` lane groups, so a
+cut on KV heads is a plain block cut), block tables / lengths ride
+replicated, and each shard runs the unmodified kernel over its local
+``KH / n`` heads.
 Attention is embarrassingly parallel across KV heads, so the sharded
 output is bit-identical to the unsharded kernel's — no collective is
 introduced; the row-parallel output projection's existing ``psum``
@@ -194,11 +201,23 @@ def fused_decode_attention(
     return out.reshape(B, H, Dh)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+def paged_kernel_takes(head_dim: int) -> bool:
+    """Whether :func:`paged_decode_attention` can read a pool of this
+    head width where it runs: Mosaic wants the ``(block_len, 2 * Dh)``
+    panel's lane width a multiple of 128 (Dh 64, 128, 192, 256 ...); the
+    interpreter (every non-TPU backend) takes any width.  The shape
+    decides — callers with another width use the gathered einsum path."""
+    return (2 * head_dim) % 128 == 0 or _use_interpret()
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, kv_ref, *rest,
                   scale, block_len, quant, n_q, group):
     """One (slot, kv head, logical block): online-softmax accumulation of
     this block's contribution into the VMEM scratch; the last block
     normalizes and writes the (n_q·G, Dh) output.
+
+    ``kv_ref`` is the ``(1, block_len, 2·Dh)`` panel ``[k | v]`` of this
+    head in this physical block — one DMA, split here.
 
     ``n_q`` query positions ride as extra rows (row ``r`` is query offset
     ``r // group``): offset ``t`` attends positions ``< valid + t`` —
@@ -206,7 +225,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     the classic decode bound at ``n_q == 1``.
     """
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc = rest
+        sc_ref, o_ref, m_scr, l_scr, acc = rest
     else:
         o_ref, m_scr, l_scr, acc = rest
     s_idx = pl.program_id(0)
@@ -228,16 +247,18 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     def _():
         # Blocks wholly past the LAST query's bound are skipped: a short
         # sequence in a long-capacity slot reads only its filled blocks.
-        R = q_ref.shape[2]  # n_q * group rows
+        R, Dh = q_ref.shape[2], q_ref.shape[3]  # n_q * group rows
         q = q_ref[0, 0].astype(jnp.float32) * scale   # (R, Dh)
-        k = k_ref[0, 0].astype(jnp.float32)           # (BL, Dh)
-        v = v_ref[0, 0].astype(jnp.float32)
+        kv = kv_ref[0].astype(jnp.float32)            # (BL, 2·Dh)
+        k, v = kv[:, :Dh], kv[:, Dh:]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (R, BL)
         if quant:
-            s = s * ks_ref[0, 0, :, 0][None, :]
+            # Per-position k scale commutes out of the Dh contraction; v
+            # scale folds into the probability operand below.
+            s = s * sc_ref[0, 0, 0:1, :]
         pos = base + jax.lax.broadcasted_iota(
             jnp.int32, (R, k.shape[0]), 1
         )
@@ -255,7 +276,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         p = jnp.exp(s - m_new[:, None]) * mask.astype(jnp.float32)
         l_scr[:, 0] = alpha * l_scr[:, 0] + jnp.sum(p, axis=1)
         if quant:
-            p = p * vs_ref[0, 0, :, 0][None, :]
+            p = p * sc_ref[0, 0, 1:2, :]
         acc[:] = alpha[:, None] * acc[:] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -271,22 +292,21 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_decode_attention(
     q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    kv_pool: jax.Array,
     block_tables: jax.Array,
     valid_len: jax.Array,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
+    kv_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Single-position attention against a block-pooled (paged) KV cache.
 
     The serving engine's hot op (``chainermn_tpu/serving/engine.py``): S
     decode slots each read their own logical sequence out of one shared
     physical pool through a per-slot block table.  The kernel walks the
-    table via scalar prefetch — block ``m`` of slot ``s`` DMAs
-    ``pool[kh, block_tables[s, m]]`` straight into VMEM — and folds blocks
-    through the online-softmax recurrence, so no contiguous per-slot cache
-    copy is ever materialized and there is no ``MAX_FUSED_LEN`` cap.
+    table via scalar prefetch — block ``m`` of slot ``s`` DMAs head
+    ``kh``'s ``[k | v]`` panel of ``pool[block_tables[s, m]]`` straight
+    into VMEM — and folds blocks through the online-softmax recurrence, so
+    no contiguous per-slot cache copy is ever materialized and there is no
+    ``MAX_FUSED_LEN`` cap.
 
     Args:
       q: ``(S, H, Dh)`` — each slot's current query position — or
@@ -295,8 +315,10 @@ def paged_decode_attention(
         ``< valid_len[s] + t`` (per-position causality inside the chunk;
         the chunk's K/V must already be written to the pool).  ``T`` is
         static and small (``<= MAX_VERIFY_T`` by the model's dispatch).
-      k_pool/v_pool: ``(KH, num_blocks, block_len, Dh)`` physical pools
-        (float, or int8 with scales).
+      kv_pool: ``(num_blocks, block_len, KH * 2 * Dh)`` — the physical
+        pool (float, or int8 with ``kv_scale``); lanes
+        ``[h*2*Dh, h*2*Dh + Dh)`` of a row are head ``h``'s key, the next
+        ``Dh`` its value (:mod:`chainermn_tpu.serving.kv_pool`).
       block_tables: ``(S, max_blocks)`` int32 — logical→physical block map
         per slot.  Entries past a slot's filled length may point anywhere
         valid (they are masked, conventionally 0 — the serving pool
@@ -308,8 +330,9 @@ def paged_decode_attention(
         masked — zeros-over-guard, discarded by the engine; later
         offsets attend only the chunk's own parked writes, equally
         discarded).
-      k_scale/v_scale: ``(KH, num_blocks, block_len)`` fp32 — required iff
-        the pool is int8 (same symmetric-absmax convention as
+      kv_scale: ``(num_blocks, KH, 2, block_len)`` fp32 — required iff the
+        pool is int8: row 0 of a head's pair is the per-position key
+        scale, row 1 the value scale (same symmetric-absmax convention as
         :func:`fused_decode_attention`).
 
     Returns ``(S, H, Dh)`` or ``(S, T, H, Dh)`` (matching ``q``) in
@@ -321,7 +344,13 @@ def paged_decode_attention(
     else:
         S, H, Dh = q.shape
         T = 1
-    KH, NB, BL, _ = k_pool.shape
+    if kv_pool.ndim != 3 or kv_pool.shape[2] % (2 * Dh):
+        raise ValueError(
+            f"kv_pool must be (num_blocks, block_len, KH * 2 * Dh) with "
+            f"Dh = {Dh}, got {kv_pool.shape}"
+        )
+    _, BL, lanes = kv_pool.shape
+    KH = lanes // (2 * Dh)
     if H % KH:
         raise ValueError(f"H ({H}) must be a multiple of KH ({KH})")
     if block_tables.ndim != 2 or block_tables.shape[0] != S:
@@ -331,9 +360,9 @@ def paged_decode_attention(
         )
     G = H // KH
     MB = block_tables.shape[1]
-    quant = k_pool.dtype == jnp.int8
-    if quant and (k_scale is None or v_scale is None):
-        raise ValueError("int8 pool needs k_scale and v_scale")
+    quant = kv_pool.dtype == jnp.int8
+    if quant and kv_scale is None:
+        raise ValueError("int8 pool needs kv_scale")
     if multi:
         # Query offsets ride as extra ROWS of each (slot, kv-head)
         # program: (S, T, KH, G, Dh) -> (S, KH, T*G, Dh), offset t of
@@ -347,23 +376,18 @@ def paged_decode_attention(
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(valid_len, jnp.int32).reshape(S)
 
-    q_spec = pl.BlockSpec(
-        (1, 1, R, Dh), lambda s, h, m, tbl, ln: (s, h, 0, 0)
-    )
-    kv_spec = pl.BlockSpec(
-        (1, 1, BL, Dh), lambda s, h, m, tbl, ln: (h, tbl[s, m], 0, 0)
-    )
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qg, k_pool, v_pool]
+    in_specs = [
+        pl.BlockSpec((1, 1, R, Dh), lambda s, h, m, tbl, ln: (s, h, 0, 0)),
+        pl.BlockSpec(
+            (1, BL, 2 * Dh), lambda s, h, m, tbl, ln: (tbl[s, m], 0, h)
+        ),
+    ]
+    operands = [qg, kv_pool]
     if quant:
-        sc_spec = pl.BlockSpec(
-            (1, 1, BL, 1), lambda s, h, m, tbl, ln: (h, tbl[s, m], 0, 0)
-        )
-        in_specs += [sc_spec, sc_spec]
-        operands += [
-            k_scale.reshape(KH, NB, BL, 1),
-            v_scale.reshape(KH, NB, BL, 1),
-        ]
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 2, BL), lambda s, h, m, tbl, ln: (tbl[s, m], h, 0, 0)
+        ))
+        operands.append(kv_scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, KH, MB),
@@ -420,25 +444,25 @@ def _mesh_axis(mesh, axis: Optional[str]) -> str:
 
 def sharded_paged_decode_attention(
     q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    kv_pool: jax.Array,
     block_tables: jax.Array,
     valid_len: jax.Array,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
+    kv_scale: Optional[jax.Array] = None,
     *,
     mesh,
     axis: Optional[str] = None,
 ) -> jax.Array:
     """:func:`paged_decode_attention` under ``shard_map`` on a 1-D mesh.
 
-    Queries shard on the query-head axis, pools (and int8 scales) on the
-    KV-head axis 0 — the layout :func:`serving.sharding.pool_placement`
-    already produces — block tables and lengths ride replicated.  Each
-    shard runs the Pallas kernel over its ``KH / n`` local heads, so the
-    output (sharded like ``q``) is bit-identical to the unsharded call:
-    softmax never crosses KV heads.  Supports the 4-D multi-query verify
-    form and the int8 pool exactly like the unsharded entry.
+    Queries shard on the query-head axis, the pool on its LAST axis (a
+    head's ``[k | v]`` lanes are contiguous, so ``KH / n`` heads are one
+    plain block of it) and the int8 scales on their KV-head axis 1 — the
+    layout :func:`serving.sharding.pool_placement` already produces —
+    block tables and lengths ride replicated.  Each shard runs the Pallas
+    kernel over its ``KH / n`` local heads, so the output (sharded like
+    ``q``) is bit-identical to the unsharded call: softmax never crosses
+    KV heads.  Supports the 4-D multi-query verify form and the int8 pool
+    exactly like the unsharded entry.
 
     ``mesh`` is the serving :class:`jax.sharding.Mesh`; ``axis`` defaults
     to the mesh's only axis name.  A mesh of size 1 falls through to the
@@ -450,55 +474,30 @@ def sharded_paged_decode_attention(
     n = int(mesh.shape[axis])
     if n == 1:
         return paged_decode_attention(
-            q, k_pool, v_pool, block_tables, valid_len, k_scale, v_scale
+            q, kv_pool, block_tables, valid_len, kv_scale
         )
-    KH = k_pool.shape[0]
+    KH = kv_pool.shape[2] // (2 * q.shape[-1])
     if KH % n:
         raise ValueError(
-            f"KV heads ({KH}, pool axis 0) are not divisible by mesh "
-            f"axis '{axis}' ({n}); the per-shard paged kernel needs a "
-            f"whole number of local KV heads"
+            f"KV heads ({KH}, the pool's lane groups) are not divisible "
+            f"by mesh axis '{axis}' ({n}); the per-shard paged kernel "
+            f"needs a whole number of local KV heads"
         )
-    multi = q.ndim == 4
-    q_spec = (
-        jax.sharding.PartitionSpec(None, None, axis, None)
-        if multi
-        else jax.sharding.PartitionSpec(None, axis, None)
-    )
-    pool_spec = jax.sharding.PartitionSpec(axis, None, None, None)
-    scale_spec = jax.sharding.PartitionSpec(axis, None, None)
-    rep2 = jax.sharding.PartitionSpec(None, None)
-    rep1 = jax.sharding.PartitionSpec(None)
-    quant = k_pool.dtype == jnp.int8
-    if quant:
-        if k_scale is None or v_scale is None:
-            raise ValueError("int8 pool needs k_scale and v_scale")
-
-        def body(q, kp, vp, tbl, lens, ks, vs):
-            return paged_decode_attention(q, kp, vp, tbl, lens, ks, vs)
-
-        sm = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(q_spec, pool_spec, pool_spec, rep2, rep1,
-                      scale_spec, scale_spec),
-            out_specs=q_spec,
-            check_vma=False,
-        )
-        return sm(q, k_pool, v_pool, block_tables, valid_len,
-                  k_scale, v_scale)
-
-    def body(q, kp, vp, tbl, lens):
-        return paged_decode_attention(q, kp, vp, tbl, lens)
-
+    P = jax.sharding.PartitionSpec
+    q_spec = P(None, None, axis, None) if q.ndim == 4 else P(None, axis, None)
+    operands = [q, kv_pool, block_tables, valid_len]
+    in_specs = [q_spec, P(None, None, axis), P(None, None), P(None)]
+    if kv_scale is not None:
+        operands.append(kv_scale)
+        in_specs.append(P(None, axis, None, None))
     sm = jax.shard_map(
-        body,
+        paged_decode_attention,
         mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, rep2, rep1),
+        in_specs=tuple(in_specs),
         out_specs=q_spec,
         check_vma=False,
     )
-    return sm(q, k_pool, v_pool, block_tables, valid_len)
+    return sm(*operands)
 
 
 def sharded_fused_decode_attention(

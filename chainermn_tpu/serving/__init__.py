@@ -34,7 +34,7 @@ Six layers:
 * :mod:`~chainermn_tpu.serving.sharding` — the pod-scale GSPMD plan: one
   engine tensor-parallel over a 1-D ``Mesh(("model",))`` — params on the
   Megatron cut, the paged KV pools (target and draft) sharded
-  kv-head-major on the layout's purpose-built ``(KH, ...)`` axis, all
+  on KV heads (whole ``[k | v]`` lane groups of their last axis), all
   host-side bookkeeping untouched (``DecodeEngine(mesh=...)``).
 * :mod:`~chainermn_tpu.serving.router` — N engines × M chips behind
   least-loaded dispatch off each replica's live gauges, per-replica

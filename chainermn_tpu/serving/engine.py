@@ -108,7 +108,7 @@ class DecodeEngine:
         (:func:`~chainermn_tpu.serving.sharding.serving_mesh`): the
         engine becomes TENSOR-PARALLEL over it — params sharded per
         :func:`~chainermn_tpu.serving.sharding.param_spec`, the paged KV
-        pools (target AND draft) sharded kv-head-major on axis 0, block
+        pools (target AND draft) sharded on KV heads (their last axis), block
         tables / allocator / prefix trie untouched (pure host
         bookkeeping over block ids), control vectors uploaded
         replicated.  Both decode paths work under a mesh:
@@ -407,7 +407,7 @@ class DecodeEngine:
             def one(layer):
                 return {
                     n: jax.lax.dynamic_index_in_dim(
-                        layer[n], idx, axis=1, keepdims=False
+                        layer[n], idx, axis=0, keepdims=False
                     )
                     for n in layer
                 }
@@ -422,7 +422,7 @@ class DecodeEngine:
 
         def put_impl(pools, dpools, idx, tdata, ddata):
             def one(layer, data):
-                return {n: layer[n].at[:, idx].set(data[n]) for n in layer}
+                return {n: layer[n].at[idx].set(data[n]) for n in layer}
 
             with jax.named_scope("kv_put"):
                 pools = [one(p, x) for p, x in zip(pools, tdata)]
@@ -437,8 +437,7 @@ class DecodeEngine:
         def cow_impl(pools, dpools, src, dst):
             def dup(layer):
                 return {
-                    n: layer[n].at[:, dst].set(layer[n][:, src])
-                    for n in layer
+                    n: layer[n].at[dst].set(layer[n][src]) for n in layer
                 }
 
             with jax.named_scope("cow_copy"):
@@ -646,8 +645,9 @@ class DecodeEngine:
     # ------------------------------------------------------- kv migration
     def read_block(self, block: int) -> dict:
         """One physical block's live KV contents as HOST numpy arrays:
-        ``{"target": [per-layer {name: (KH, block_len, Dh)}...],
-        "draft": same or None}`` — the serializable unit
+        ``{"target": [per-layer {"kv": (block_len, KH * 2 * Dh)}...],
+        "draft": same or None}`` (int8 pools add ``"kv_scale":
+        (KH, 2, block_len)``) — the serializable unit
         :mod:`~chainermn_tpu.serving.disagg` ships over the hostcomm p2p
         plane.  Pure read: the pools stay live for the next step."""
         import jax

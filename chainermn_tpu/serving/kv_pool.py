@@ -4,11 +4,41 @@ The static-batch decode path (:func:`~chainermn_tpu.models.lm_generate`)
 sizes one contiguous ``(B, L, ...)`` cache to the LONGEST request and holds
 it for the whole batch — memory proportional to ``B · max_len`` even when
 most rows finished long ago.  The serving engine instead draws from one
-physical **block pool** per layer, laid out kv-head major exactly as the
-fused/paged decode kernels want it:
+physical **block pool** per layer, token-major and lane-dense:
 
-    ``{"k", "v"}``:  ``(KH, num_blocks, block_len, Dh)``
-    ``{"k_scale", "v_scale"}`` (int8 pools): ``(KH, num_blocks, block_len)``
+    ``{"kv"}``:  ``(num_blocks, block_len, KH * 2 * Dh)``
+    ``{"kv_scale"}`` (int8 pools): ``(num_blocks, KH, 2, block_len)`` fp32
+
+A token's row holds every KV head's key and value side by side,
+``[k_0 | v_0 | k_1 | v_1 | ...]``: lanes ``[h*2*Dh, h*2*Dh + Dh)`` are head
+``h``'s key, the next ``Dh`` its value.  (The int8 scale plane pairs a
+head's key and value scale the same way, positions minor-most: the
+``(2, block_len)`` panel the kernel multiplies into its scores.)
+
+**Why this layout** (chip compiler, PR 25; ``tests/ops_tests/
+test_tpu_compile.py`` keeps the proof).  Three parties touch a pool — the
+program's argument and result (what the runtime keeps at rest), XLA's
+scatter that writes the step's tokens, and the Mosaic paged-attention
+kernel that reads it — and each picks a physical layout for the shape it
+is handed.  For the kv-head-major pool (KV heads outermost, ``Dh`` minor)
+this engine had before, at ``Dh = 64`` they picked three different ones:
+
+    ===========================  ==========================================
+    argument / result            ``{1,3,2,0}``: ``num_blocks`` minor-most,
+                                 padded 2049 → 2176
+    scatter (and a DUS loop)     ``{3,0,2,1}``
+    Mosaic kernel                ``{3,2,1,0}``, ``Dh`` 64 padded to 128 lanes
+    ===========================  ==========================================
+
+so every decode tick and every prefill call converted each pool of each
+layer three times — whole-pool copies, a third of the device's time (ledger,
+PR 24) — to write 0.2 MB.  With ``(num_blocks, block_len, KH * 2 * Dh)``
+the minor axis is a multiple of 128 lanes whenever ``2 * Dh`` is, all three
+agree on plain row-major ``{2,1,0}``, the scatter updates the donated
+argument in place, and nothing is padded.  Fusing ``k`` and ``v`` is what
+makes ``Dh = 64`` lane-dense; it also halves the kernel's DMAs (one
+``(block_len, 2 * Dh)`` panel a grid step).  A cut on KV heads is still a
+plain block cut — of the last axis (:mod:`~chainermn_tpu.serving.sharding`).
 
 A decode slot owns an ordered list of physical blocks (its *block table*);
 logical position ``p`` lives at ``(table[p // block_len], p % block_len)``.
@@ -129,8 +159,9 @@ def blocks_for(tokens: int, block_len: int) -> int:
 
 
 class PagedKVPool:
-    """The device-resident pools (one ``{"k","v"[,scales]}`` dict per
-    layer) plus their :class:`BlockAllocator`.
+    """The device-resident pools (one ``{"kv"[, "kv_scale"]}`` dict per
+    layer, laid out as the module docstring draws it) plus their
+    :class:`BlockAllocator`.
 
     Built from the model's own geometry so the pool entries are exactly
     what :meth:`TransformerLM.__call__`'s paged decode branch expects.
@@ -143,7 +174,7 @@ class PagedKVPool:
     ``jax.devices()[0]``): a callable applied to every freshly-built
     pool array.  Pass
     :func:`~chainermn_tpu.serving.sharding.pool_placement` for a
-    kv-head-major mesh shard, ``lambda a: jax.device_put(a, dev)`` to
+    mesh shard on KV heads, ``lambda a: jax.device_put(a, dev)`` to
     pin a specific device, or ``None`` (the default-constructed
     single-device fast path — no extra transfer, unchanged behavior).
     """
@@ -157,16 +188,15 @@ class PagedKVPool:
         kvh = model.n_kv_heads or model.n_heads
         dh = model.d_model // model.n_heads
         kvd = model.kv_dtype if model.kv_dtype is not None else model.dtype
-        shape = (kvh, num_blocks, block_len, dh)
+        shape = (num_blocks, block_len, kvh * 2 * dh)
         self.block_len = block_len
         self.num_blocks = num_blocks
         self.allocator = BlockAllocator(num_blocks)
         if jnp.dtype(kvd) == jnp.int8:
             self.pools: List[Dict] = [
-                {"k": jnp.zeros(shape, jnp.int8),
-                 "v": jnp.zeros(shape, jnp.int8),
-                 "k_scale": jnp.zeros(shape[:3], jnp.float32),
-                 "v_scale": jnp.zeros(shape[:3], jnp.float32)}
+                {"kv": jnp.zeros(shape, jnp.int8),
+                 "kv_scale": jnp.zeros(
+                     (num_blocks, kvh, 2, block_len), jnp.float32)}
                 for _ in range(model.n_layers)
             ]
             per_layer = 2 * kvh * block_len * (dh + 4)  # k+v int8 + scales
@@ -176,8 +206,7 @@ class PagedKVPool:
                     f"kv_dtype must be a float dtype or jnp.int8, got {kvd}"
                 )
             self.pools = [
-                {"k": jnp.zeros(shape, kvd), "v": jnp.zeros(shape, kvd)}
-                for _ in range(model.n_layers)
+                {"kv": jnp.zeros(shape, kvd)} for _ in range(model.n_layers)
             ]
             per_layer = 2 * kvh * block_len * dh * jnp.dtype(kvd).itemsize
         if placement is not None:

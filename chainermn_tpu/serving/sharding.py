@@ -20,12 +20,13 @@ The plan, axis by axis:
   EVERY expert, so routing stays host-invisible.
 * **LM head** is vocab-sharded (column-parallel); greedy argmax over the
   sharded vocab is a cheap per-shard argmax + cross-chip max.
-* **The paged KV pool** is sharded **kv-head-major**: the pool layout
-  ``(KH, num_blocks, block_len, Dh)`` was chosen in PR 4 with exactly
-  this cut in mind — axis 0 is the natural shard axis, so each chip owns
-  ``KH / n`` heads of EVERY physical block.  Block ids mean the same
-  thing on every chip, which is what keeps the host-side bookkeeping
-  replicated-trivially:
+* **The paged KV pool** is sharded on **KV heads**: the pool is
+  token-major, ``(num_blocks, block_len, KH * 2 * Dh)`` with each head's
+  ``[k | v]`` lanes contiguous (:mod:`~chainermn_tpu.serving.kv_pool`
+  says why), so a cut of its LAST axis is a plain block cut that hands
+  each chip ``KH / n`` whole heads of EVERY physical block.  Block ids
+  mean the same thing on every chip, which is what keeps the host-side
+  bookkeeping replicated-trivially:
 * **Block tables, the refcounted allocator and the prefix-cache trie
   stay host-side and replicated** — they are pure Python accounting over
   physical block *ids* (never touching pool bytes), so sharding the
@@ -45,7 +46,7 @@ rules, so GSPMD alone cannot propagate through ``pallas_call`` — instead
 a sharded ``decode_attention="fused"`` engine runs the kernels **per
 shard under** ``shard_map`` (:func:`~chainermn_tpu.ops.
 sharded_paged_decode_attention`): queries cut on the head axis, pools on
-the KV-head axis 0 (the placement above), block tables replicated.
+KV heads (the placement above), block tables replicated.
 Attention never crosses KV heads, so the per-shard outputs are
 bit-identical to the unsharded kernel's and no new collective lands on
 the decode hot path — the row-parallel ``proj`` psum that already exists
@@ -109,7 +110,7 @@ def validate_geometry(model, mesh) -> None:
     """Fail fast when ``model``'s geometry cannot split ``n`` ways.
 
     Only the KV-head axis is MANDATORY: :func:`pool_placement` shards
-    every pool on axis 0 and the per-shard Pallas kernels
+    every pool on its KV heads and the per-shard Pallas kernels
     (``decode_attention="fused"``) need a whole number of local KV
     heads, so ``KH % n`` must hold (and with GQA, ``H = KH * groups``,
     so the query heads divide whenever KH does).  Any OTHER indivisible
@@ -125,9 +126,9 @@ def validate_geometry(model, mesh) -> None:
     kvh = model.n_kv_heads or model.n_heads
     if kvh % n:
         raise ValueError(
-            f"model kv heads ({kvh}, the pools' shard axis 0) are not "
+            f"model kv heads ({kvh}, the pools' shard axis) are not "
             f"divisible by the mesh's '{MODEL_AXIS}' axis ({n}) — the "
-            "paged pools shard kv-head-major and the per-shard decode "
+            "paged pools shard on KV heads and the per-shard decode "
             "kernels need whole local KV heads, so KH is the one axis "
             "that must split"
         )
@@ -222,19 +223,23 @@ def shard_params(params, mesh):
 
 def pool_placement(mesh):
     """Placement callable for :class:`~chainermn_tpu.serving.kv_pool.
-    PagedKVPool`: pool entries (rank >= 3 — ``(KH, num_blocks,
-    block_len[, Dh])``) shard kv-head-major on axis 0; anything smaller
-    replicates."""
+    PagedKVPool`: both pool entries shard on KV heads — the values
+    ``(num_blocks, block_len, KH * 2 * Dh)`` on their last axis (whole
+    ``[k | v]`` lane groups), the int8 scales ``(num_blocks, KH, 2,
+    block_len)`` on axis 1; anything smaller replicates."""
     import jax
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     def place(arr):
-        # ``P("model")``, not ``P("model", None, ...)``: the engine's
-        # programs hand the pools back under the short spelling, and the
-        # jit cache keys on the spelling — padded with Nones, the first
-        # call of every program would compile a variant no later call uses.
-        spec = P(MODEL_AXIS) if arr.ndim >= 3 else P()
+        # No trailing Nones (``P(None, "model")``, not ``P(None, "model",
+        # None, None)``): the engine's programs hand the pools back under
+        # the short spelling, and the jit cache keys on the spelling —
+        # padded, the first call of every program would compile a variant
+        # no later call uses.
+        spec = {3: P(None, None, MODEL_AXIS), 4: P(None, MODEL_AXIS)}.get(
+            arr.ndim, P()
+        )
         return jax.device_put(arr, NamedSharding(mesh, spec))
 
     return place

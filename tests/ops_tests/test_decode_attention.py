@@ -139,6 +139,22 @@ def test_validation_errors():
 
 
 # ---------------------------------------------------------------- paged
+def _fuse(kp, vp):
+    """Per-head keys and values ``(KH, NB, BL, Dh)`` -> the engine's ONE pool
+    ``(NB, BL, KH * 2 * Dh)``: token-major, each head's ``[k | v]`` side by
+    side (``serving/kv_pool.py``).  The oracles below keep reading the
+    per-head arrays, so they also check the layout."""
+    kv = np.concatenate([np.asarray(kp), np.asarray(vp)], axis=-1)
+    KH, NB, BL, W = kv.shape
+    return jnp.asarray(kv.transpose(1, 2, 0, 3).reshape(NB, BL, KH * W))
+
+
+def _fuse_scale(ks, vs):
+    """``(KH, NB, BL)`` k and v scales -> ``(NB, KH, 2, BL)``."""
+    sc = np.stack([np.asarray(ks), np.asarray(vs)], axis=2)
+    return jnp.asarray(sc.transpose(1, 0, 2, 3))
+
+
 def _paged_oracle(q, kp, vp, tbl, valid):
     """fp32 reference for the paged kernel's multi-query (verify) mode:
     gather each slot's logical cache through its block table, mask per
@@ -177,7 +193,7 @@ def test_paged_multi_query_verify_matches_oracle():
     valid = np.asarray([9, 1, 17], np.int32)
     from chainermn_tpu.ops import paged_decode_attention
 
-    out = paged_decode_attention(q, kp, vp, jnp.asarray(tbl),
+    out = paged_decode_attention(q, _fuse(kp, vp), jnp.asarray(tbl),
                                  jnp.asarray(valid))
     assert out.shape == (S, T, H, Dh)
     ref = _paged_oracle(q, kp, vp, tbl, valid)
@@ -196,8 +212,9 @@ def test_paged_single_query_is_multi_query_t1():
     valid = jnp.asarray([6, 11], jnp.int32)
     from chainermn_tpu.ops import paged_decode_attention
 
-    a = paged_decode_attention(q, kp, vp, tbl, valid)
-    b = paged_decode_attention(q[:, None], kp, vp, tbl, valid)[:, 0]
+    pool = _fuse(kp, vp)
+    a = paged_decode_attention(q, pool, tbl, valid)
+    b = paged_decode_attention(q[:, None], pool, tbl, valid)[:, 0]
     assert (np.asarray(a) == np.asarray(b)).all()
 
 
@@ -214,9 +231,86 @@ def test_paged_idle_slot_zero_valid_is_defined():
     valid = jnp.zeros((S,), jnp.int32)
     from chainermn_tpu.ops import paged_decode_attention
 
-    out = np.asarray(paged_decode_attention(q, kp, vp, tbl, valid))
+    out = np.asarray(paged_decode_attention(q, _fuse(kp, vp), tbl, valid))
     assert np.isfinite(out).all()
     assert (out[:, 0] == 0).all()  # offset 0: fully masked
+
+
+@pytest.mark.parametrize("T", [1, 3], ids=["decode", "verify_t3"])
+def test_paged_pool_matches_reference_attention_on_gathered(T):
+    """The kernel on the engine's pool against the repo's ONE attention
+    oracle (``reference_attention``) on each slot's gathered keys/values:
+    ragged ``valid_len``, an idle slot, GQA, and (T = 3) a verify chunk —
+    query offset t of a slot holding ``valid - 1 + T`` positions is the
+    causal attention's row ``valid - 1 + t``."""
+    from chainermn_tpu.ops import paged_decode_attention, reference_attention
+
+    rng = np.random.RandomState(11)
+    S, H, KH, Dh, NB, BL, MB = 4, 6, 2, 16, 16, 4, 5
+    q = rng.randn(S, T, H, Dh).astype(np.float32)
+    kp = rng.randn(KH, NB, BL, Dh).astype(np.float32)
+    vp = rng.randn(KH, NB, BL, Dh).astype(np.float32)
+    tbl = np.stack([rng.permutation(np.arange(1, NB))[:MB] for _ in range(S)])
+    tbl[2] = 0  # the idle slot's table is parked
+    valid = np.asarray([13, 1, 0, 18 - (T - 1)], np.int32)
+    got = paged_decode_attention(
+        jnp.asarray(q if T > 1 else q[:, 0]), _fuse(kp, vp),
+        jnp.asarray(tbl, jnp.int32), jnp.asarray(valid),
+    )
+    got = np.asarray(got).reshape(S, T, H, Dh)
+    assert (got[2, 0] == 0).all() and np.isfinite(got).all()  # idle slot
+    for s in (0, 1, 3):
+        L = int(valid[s]) - 1 + T
+        # this slot's context, gathered: (1, L, KH, Dh)
+        kg = kp[:, tbl[s]].reshape(KH, MB * BL, Dh)[:, :L].transpose(1, 0, 2)
+        vg = vp[:, tbl[s]].reshape(KH, MB * BL, Dh)[:, :L].transpose(1, 0, 2)
+        qf = np.zeros((1, L, H, Dh), np.float32)
+        qf[0, L - T:] = q[s]
+        want = reference_attention(
+            jnp.asarray(qf), jnp.asarray(kg[None]), jnp.asarray(vg[None]),
+            causal=True,
+        )[0, L - T:]
+        np.testing.assert_allclose(got[s], np.asarray(want), atol=2e-5)
+
+
+def test_paged_int8_pool_matches_dequantized_oracle():
+    """int8 pool + the (NB, KH, 2, BL) scale plane: the kernel folds the k
+    scale into the scores and the v scale into the probabilities — the same
+    numbers as the float oracle on the dequantized pools."""
+    from chainermn_tpu.ops import paged_decode_attention
+
+    rng = np.random.RandomState(12)
+    S, T, H, KH, Dh, NB, BL, MB = 3, 2, 4, 2, 8, 10, 4, 4
+    q = jnp.asarray(rng.randn(S, T, H, Dh), jnp.float32)
+    k8 = rng.randint(-127, 128, size=(KH, NB, BL, Dh)).astype(np.int8)
+    v8 = rng.randint(-127, 128, size=(KH, NB, BL, Dh)).astype(np.int8)
+    ks = (rng.rand(KH, NB, BL) * 0.02 + 0.001).astype(np.float32)
+    vs = (rng.rand(KH, NB, BL) * 0.02 + 0.001).astype(np.float32)
+    tbl = rng.randint(1, NB, size=(S, MB)).astype(np.int32)
+    valid = np.asarray([7, 1, 12], np.int32)
+    out = paged_decode_attention(q, _fuse(k8, v8), jnp.asarray(tbl),
+                                 jnp.asarray(valid), _fuse_scale(ks, vs))
+    ref = _paged_oracle(q, k8 * ks[..., None], v8 * vs[..., None], tbl, valid)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4, rtol=1e-4)
+
+
+def test_paged_pool_shape_is_checked():
+    """A pool that is not (num_blocks, block_len, KH * 2 * Dh) for the
+    query's head width is refused by name, as is an int8 pool without its
+    scale plane."""
+    from chainermn_tpu.ops import paged_decode_attention
+
+    q = jnp.zeros((2, 4, 8), jnp.float32)
+    tbl = jnp.zeros((2, 3), jnp.int32)
+    valid = jnp.ones((2,), jnp.int32)
+    with pytest.raises(ValueError, match=r"KH \* 2 \* Dh"):
+        paged_decode_attention(q, jnp.zeros((2, 6, 4, 8)), tbl, valid)
+    with pytest.raises(ValueError, match=r"KH \* 2 \* Dh"):
+        paged_decode_attention(q, jnp.zeros((6, 4, 24)), tbl, valid)
+    with pytest.raises(ValueError, match="multiple of KH"):
+        paged_decode_attention(q, jnp.zeros((6, 4, 3 * 16)), tbl, valid)
+    with pytest.raises(ValueError, match="int8 pool needs kv_scale"):
+        paged_decode_attention(q, jnp.zeros((6, 4, 32), jnp.int8), tbl, valid)
 
 
 # ------------------------------------------------- sharded (shard_map)
@@ -248,16 +342,17 @@ def test_sharded_paged_bit_identical_to_unsharded():
     vp = jnp.asarray(rng.randn(KH, NB, BL, Dh), jnp.float32)
     tbl = jnp.asarray(rng.randint(1, NB, size=(S, MB)), jnp.int32)
     valid = jnp.asarray([5, 14], jnp.int32)
+    pool = _fuse(kp, vp)
     for q in (q3, q4):
-        ref = paged_decode_attention(q, kp, vp, tbl, valid)
-        out = sharded_paged_decode_attention(q, kp, vp, tbl, valid,
-                                             mesh=mesh)
+        ref = paged_decode_attention(q, pool, tbl, valid)
+        out = sharded_paged_decode_attention(q, pool, tbl, valid, mesh=mesh)
         assert (np.asarray(out) == np.asarray(ref)).all()
     ks = jnp.asarray(np.abs(rng.rand(KH, NB, BL)) + 0.1, jnp.float32)
     vs = jnp.asarray(np.abs(rng.rand(KH, NB, BL)) + 0.1, jnp.float32)
-    kp8, vp8 = (kp * 5).astype(jnp.int8), (vp * 5).astype(jnp.int8)
-    ref = paged_decode_attention(q3, kp8, vp8, tbl, valid, ks, vs)
-    out = sharded_paged_decode_attention(q3, kp8, vp8, tbl, valid, ks, vs,
+    pool8 = _fuse((kp * 5).astype(jnp.int8), (vp * 5).astype(jnp.int8))
+    sc = _fuse_scale(ks, vs)
+    ref = paged_decode_attention(q3, pool8, tbl, valid, sc)
+    out = sharded_paged_decode_attention(q3, pool8, tbl, valid, sc,
                                          mesh=mesh)
     assert (np.asarray(out) == np.asarray(ref)).all()
 
@@ -276,8 +371,9 @@ def test_sharded_paged_single_query_is_multi_query_t1():
     vp = jnp.asarray(rng.randn(KH, NB, BL, Dh), jnp.float32)
     tbl = jnp.asarray(rng.randint(1, NB, size=(S, MB)), jnp.int32)
     valid = jnp.asarray([6, 11], jnp.int32)
-    a = sharded_paged_decode_attention(q, kp, vp, tbl, valid, mesh=mesh)
-    b = sharded_paged_decode_attention(q[:, None], kp, vp, tbl, valid,
+    pool = _fuse(kp, vp)
+    a = sharded_paged_decode_attention(q, pool, tbl, valid, mesh=mesh)
+    b = sharded_paged_decode_attention(q[:, None], pool, tbl, valid,
                                        mesh=mesh)[:, 0]
     assert (np.asarray(a) == np.asarray(b)).all()
 
@@ -319,10 +415,11 @@ def test_sharded_wrapper_validation():
     vp = jnp.asarray(rng.randn(KH, NB, BL, Dh), jnp.float32)
     tbl = jnp.asarray(rng.randint(1, NB, size=(S, MB)), jnp.int32)
     valid = jnp.asarray([6, 11], jnp.int32)
+    pool = _fuse(kp, vp)
     with pytest.raises(ValueError, match=r"KV heads \(2.*'model' \(4\)"):
-        sharded_paged_decode_attention(q, kp, vp, tbl, valid,
+        sharded_paged_decode_attention(q, pool, tbl, valid,
                                        mesh=serving_mesh(4))
-    ref = paged_decode_attention(q, kp, vp, tbl, valid)
-    out = sharded_paged_decode_attention(q, kp, vp, tbl, valid,
+    ref = paged_decode_attention(q, pool, tbl, valid)
+    out = sharded_paged_decode_attention(q, pool, tbl, valid,
                                          mesh=serving_mesh(1))
     assert (np.asarray(out) == np.asarray(ref)).all()

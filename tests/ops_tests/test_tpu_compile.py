@@ -13,6 +13,7 @@ the CPU here, so the module steers that choice itself for its duration.
 """
 
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
@@ -72,12 +73,14 @@ def chip():
     _cc.reset_cache()
 
 
-def _paged(q_shape, kv_dtype):
-    pool = ((H, NB, BL, DH), kv_dtype)
-    args = [(q_shape, jnp.bfloat16), pool, pool,
+def _paged(q_shape, kv_dtype, kv_heads=H, nb=NB):
+    """The kernel on the serving pool (``serving/kv_pool.py``): token-major,
+    each head's ``[k | v]`` in one lane group."""
+    dh = q_shape[-1]
+    args = [(q_shape, jnp.bfloat16), ((nb, BL, kv_heads * 2 * dh), kv_dtype),
             ((S, MB), jnp.int32), ((S,), jnp.int32)]
     if kv_dtype == jnp.int8:
-        args += [((H, NB, BL), jnp.float32)] * 2
+        args.append(((nb, kv_heads, 2, BL), jnp.float32))
     return paged_decode_attention, args
 
 
@@ -116,21 +119,114 @@ _CASES = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(_CASES))
-def test_kernel_compiles_for_v5e(name, chip):
-    (fn, shapes), launches, kernels = _CASES[name]
-    on_chip = SingleDeviceSharding(chip)
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
-            for shape, dtype in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+def _assert_mosaic_took(text, launches, kernels, name):
     assert text.count("tpu_custom_call") == launches, (
         name, text.count("tpu_custom_call")
     )
-    import re
-
     called = set(re.findall(r"%([a-z_]+)(?:\.\d+)? = [^\n]*tpu_custom_call",
                             text))
     # (an enclosing transformation wraps the name when no named scope
     # encloses the call: ``transpose_jvp_flash_bwd_dq__``)
     assert len(called) == len(kernels) and all(
         any(k in c for c in called) for k in kernels), (name, called)
+
+
+def _on(chip, tree):
+    """The abstract arguments ``tree`` (arrays, ``ShapeDtypeStruct``s or
+    ``(shape, dtype)`` pairs), placed on the described chip."""
+    on_chip = SingleDeviceSharding(chip)
+
+    def pair(a):
+        return isinstance(a, tuple) and len(a) == 2 and isinstance(a[0], tuple)
+
+    def place(a):
+        shape, dtype = a if pair(a) else (a.shape, a.dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    return jax.tree_util.tree_map(place, tree, is_leaf=pair)
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_kernel_compiles_for_v5e(name, chip):
+    (fn, shapes), launches, kernels = _CASES[name]
+    text = jax.jit(fn).lower(*_on(chip, shapes)).compile().as_text()
+    _assert_mosaic_took(text, launches, kernels, name)
+
+
+#: the serving cells' pool: GPT-2 XL heads (25 of 64) and StarCoder2's
+#: (24 query / 2 KV heads of 128), 32 slots x 1024 positions in blocks of 16
+_XL_NB = 2049
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("heads, kv_heads, dh", [(25, 25, 64), (24, 2, 128)],
+                         ids=["dh64", "dh128"])
+def test_mosaic_accepts_the_kv_panel(heads, kv_heads, dh, kv_dtype, chip):
+    """The pool's ``(1, block_len, 2 * Dh)`` panel ``[k | v]`` is a block
+    Mosaic tiles, at both head widths the configurations have, int8 (with
+    its ``(1, 1, 2, block_len)`` scale panel) included."""
+    fn, shapes = _paged((S, heads, dh), kv_dtype, kv_heads, _XL_NB)
+    text = jax.jit(fn).lower(*_on(chip, shapes)).compile().as_text()
+    _assert_mosaic_took(text, 1, ["paged_decode"], (dh, kv_dtype))
+
+
+def _xl_layer_step(tokens_per_slot, per_slot_pos):
+    """One GPT-2 XL-wide decoder layer over the backlog cell's pool, as the
+    engine's programs call it: the pools donated, (decode, verify) every
+    slot at its own position behind a live mask, (prefill) one slot's chunk
+    at a scalar position."""
+    from chainermn_tpu.models import TransformerLM
+    from chainermn_tpu.serving.kv_pool import PagedKVPool
+
+    model = TransformerLM(
+        vocab=256, n_layers=1, d_model=1600, n_heads=25, d_ff=256,
+        max_len=1024, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        decode_attention="fused")
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    pools = jax.eval_shape(lambda: PagedKVPool(model, _XL_NB, BL).pools)
+    slots = S if per_slot_pos else 1
+
+    def step(params, pools, tokens, pos, tables, active):
+        return model.apply(
+            {"params": params}, tokens, cache=pools,
+            decode_pos=pos if per_slot_pos else pos[0], block_tables=tables,
+            slot_mask=active if per_slot_pos else None, return_hidden=True)
+
+    args = (params, pools,
+            jax.ShapeDtypeStruct((slots, tokens_per_slot), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32),
+            jax.ShapeDtypeStruct((slots, MB), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    return jax.jit(step, donate_argnums=(1,)), args, pools[0]["kv"]
+
+
+@pytest.mark.parametrize("tokens, per_slot, launches", [
+    (1, True, 1), (4, True, 1), (32, False, 0),
+], ids=["decode", "verify_t4", "prefill_c32"])
+def test_pool_write_and_kernel_share_one_layout(tokens, per_slot, launches,
+                                                chip):
+    """The guard that keeps a whole-pool copy from coming back without a
+    chip run (``serving/kv_pool.py`` has the story): at the backlog cell's
+    geometry — 25 heads of 64, 2,049 blocks of 16, 32 slots, a table 64
+    wide, the pool donated — the model's own write and the kernel (or, for
+    a prefill chunk, the gather) compile to a program in which argument,
+    scatter and Mosaic agree on the pool's layout: no ``copy`` of the
+    pool's shape, no temporary as large as a pool, and the pool at rest
+    row-major (so nothing is padded: 2049 x 16 x 3200 x 2 bytes)."""
+    fn, args, pool = _xl_layer_step(tokens, per_slot)
+    compiled = fn.lower(*_on(chip, args)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == launches
+    shape = "[%s]" % ",".join(str(d) for d in pool.shape)  # [2049,16,3200]
+    pool_bytes = pool.size * pool.dtype.itemsize
+    copies = [c for c in re.findall(r"= (\S+) copy\(", text) if shape in c]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= pool_bytes  # updated in place
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    at_rest = [a for a in entry.split(", ") if shape in a]
+    assert len(at_rest) == 1 and at_rest[0].startswith(
+        "bf16" + shape + "{2,1,0"), at_rest

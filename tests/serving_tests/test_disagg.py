@@ -554,7 +554,7 @@ def test_torn_frame_checksum_detected(make_model, tiny_params, prompts):
     q = comm.queues[(0, 1)]
     frame = pickle.loads(q.popleft())
     layer = frame["body"]["blocks"][slots[0].blocks[0]]["target"][0]
-    arr = layer["k"]
+    arr = layer["kv"]
     flat = arr.reshape(-1).view(np.uint8)
     flat[0] ^= 0xFF
     q.append(pickle.dumps(frame))

@@ -97,6 +97,51 @@ def test_einsum_engine_same_tokens(make_model, tiny_params, prompts, oracle):
     assert eng.decode_compiles == 1
 
 
+def test_head_width_decides_kernel_or_gathered(make_model, tiny_params,
+                                               monkeypatch):
+    """The shape decides, not a knob: where the kernel is compiled and not
+    interpreted (a chip), a fused model whose ``[k | v]`` panel is not a
+    whole number of 128-lane groups (Dh 16: 32 lanes) takes the gathered
+    einsum, with no Pallas call in its program; interpreted (this CPU) the
+    same model keeps the kernel, as does every width with ``2 * Dh`` a
+    multiple of 128 on a chip."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops import paged_kernel_takes
+    from chainermn_tpu.serving.kv_pool import PagedKVPool
+
+    model = make_model(decode_attention="fused")  # Dh = 64 / 4 = 16
+    pools = PagedKVPool(model, num_blocks=8, block_len=8).pools
+
+    def lowered():
+        # a new function each time: the trace cache keys on the function,
+        # not on what ``_use_interpret`` says
+        def step(params, pools, tokens, pos, tables, active):
+            return model.apply(
+                {"params": params}, tokens[:, None], cache=pools,
+                decode_pos=pos, block_tables=tables, slot_mask=active)
+
+        return jax.jit(step).lower(
+            tiny_params, pools, jnp.zeros((2,), jnp.int32),
+            jnp.ones((2,), jnp.int32), jnp.zeros((2, 4), jnp.int32),
+            jnp.ones((2,), bool)).as_text(debug_info=True)
+
+    text = lowered()
+    assert "attn.paged" in text and "attn.gathered" not in text
+    assert paged_kernel_takes(16)
+
+    mod = sys.modules["chainermn_tpu.ops.decode_attention"]
+    monkeypatch.setattr(mod, "_use_interpret", lambda: False)
+    assert [d for d in (16, 32, 64, 80, 96, 128, 192, 256)
+            if paged_kernel_takes(d)] == [64, 128, 192, 256]
+    text = lowered()
+    assert "attn.gathered" in text and "attn.paged" not in text
+    assert "pallas" not in text and "custom_call" not in text
+
+
 @pytest.mark.slow  # tier-1 wall budget: the fp and einsum oracle
 # twins above stay tier-1; the int8 pool planes are pinned fast by
 # the kv_pool battery

@@ -94,26 +94,30 @@ def test_blocks_for():
 
 
 # ------------------------------------------------------------------ pool
-def test_pool_geometry_kv_head_major(make_model, model_kw):
+def test_pool_geometry_token_major_lane_dense(make_model, model_kw):
+    """One array a layer: (num_blocks, block_len, KH * 2 * Dh) — a token's
+    row holds every head's [k | v] side by side (kv_pool.py says why)."""
     pool = PagedKVPool(make_model(), num_blocks=6, block_len=8)
     kvh = model_kw["n_kv_heads"]
     dh = model_kw["d_model"] // model_kw["n_heads"]
     assert len(pool.pools) == model_kw["n_layers"]
     for entry in pool.pools:
-        assert set(entry) == {"k", "v"}
-        assert entry["k"].shape == (kvh, 6, 8, dh)
-        assert entry["k"].dtype == jnp.float32
+        assert set(entry) == {"kv"}
+        assert entry["kv"].shape == (6, 8, kvh * 2 * dh)
+        assert entry["kv"].dtype == jnp.float32
 
 
-def test_pool_int8_variant_has_scale_planes(make_model):
+def test_pool_int8_variant_has_scale_planes(make_model, model_kw):
     pool = PagedKVPool(
         make_model(kv_dtype=jnp.int8), num_blocks=6, block_len=8
     )
     entry = pool.pools[0]
-    assert set(entry) == {"k", "v", "k_scale", "v_scale"}
-    assert entry["k"].dtype == jnp.int8
-    assert entry["k_scale"].shape == entry["k"].shape[:3]
-    assert entry["k_scale"].dtype == jnp.float32
+    kvh = model_kw["n_kv_heads"]
+    assert set(entry) == {"kv", "kv_scale"}
+    assert entry["kv"].dtype == jnp.int8
+    # a head's k and v scale paired, positions minor-most
+    assert entry["kv_scale"].shape == (6, kvh, 2, 8)
+    assert entry["kv_scale"].dtype == jnp.float32
 
 
 def test_pool_bytes_per_block_accounting(make_model, model_kw):

@@ -21,7 +21,7 @@ top-level conftest env hook), so every assertion here exercises REAL
    and CompileWatch budgets intact through ``shard_map``, at mesh sizes
    2 AND 4 (4 needs ``n_kv_heads=4`` — one local head per shard).
 4. **Layout** — params land on the Megatron cut (:mod:`sharding`'s spec
-   table), KV pools shard kv-head-major on axis 0, and the host-side
+   table), KV pools shard on KV heads (their last axis), and the host-side
    bookkeeping (allocator, trie, block tables) is untouched by sharding.
 5. **The rig itself** — a pristine subprocess proves the env hook alone
    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) builds the
@@ -243,8 +243,8 @@ def test_sharded_kernel_mesh4(make_model, pod_devices):
 
 def test_param_and_pool_layout(make_model, tiny_params, model_mesh):
     """The Megatron cut lands where the spec table says: q heads, kv
-    heads, ffn hidden and vocab sharded; the pool kv-head-major on axis
-    0; host bookkeeping untouched."""
+    heads, ffn hidden and vocab sharded; the pool on KV heads (its last
+    axis); host bookkeeping untouched."""
     from jax.sharding import PartitionSpec as P
 
     eng = DecodeEngine(
@@ -265,12 +265,15 @@ def test_param_and_pool_layout(make_model, tiny_params, model_mesh):
     # Small/replicated things stay replicated.
     assert spec[("embed", "embedding")] == P()
     assert spec[("block_0", "ln1", "scale")] == P()
-    # KV pools: kv-head-major shard — axis 0 split across the mesh.
-    pool = eng.pools[0]["k"]
-    # the short spelling — what the engine's programs hand back, so the
+    # KV pools: cut on KV heads — the LAST axis of the token-major pool
+    # (a head's [k | v] lanes are contiguous), split across the mesh.
+    pool = eng.pools[0]["kv"]
+    # no trailing Nones — what the engine's programs hand back, so the
     # jit cache sees one input sharding from the first call on
-    assert pool.sharding.spec == P(M)
+    assert pool.sharding.spec == P(None, None, M)
     assert len(pool.sharding.device_set) == 2
+    shard = pool.addressable_shards[0].data
+    assert shard.shape == pool.shape[:2] + (pool.shape[2] // 2,)
     # Host bookkeeping is plain Python, untouched by placement.
     assert eng.pool.allocator.free_blocks == eng.pool.num_blocks - 1
     assert eng.prefix is not None
@@ -283,7 +286,7 @@ def test_geometry_validation_fails_fast(make_model, tiny_params,
     # 3 does not divide n_kv_heads=2 — construction must name the
     # failing axis, not surface a partitioner (or per-shard kernel)
     # error mid-step.  Same check for BOTH decode paths: the pools
-    # shard kv-head-major either way.
+    # shard on KV heads either way.
     mesh3 = serving_mesh(3, devices=pod_devices[:3])
     for attn in ("einsum", "fused"):
         with pytest.raises(ValueError, match="divisible by the mesh"):
@@ -320,14 +323,14 @@ def test_explicit_device_placement(make_model, tiny_params, prompts,
         make_model(), tiny_params, capacity=1, num_blocks=16,
         block_len=8, prefill_chunk=8, device=dev,
     )
-    assert list(eng.pools[0]["k"].devices()) == [dev]
+    assert list(eng.pools[0]["kv"].devices()) == [dev]
     comps = Scheduler(eng).run(
         [Request(id=0, prompt=prompts[0], max_new_tokens=5)]
     )
     assert comps[0].tokens == oracle(
         eng.model, tiny_params, prompts[0], 5
     )
-    assert list(eng.pools[0]["k"].devices()) == [dev]
+    assert list(eng.pools[0]["kv"].devices()) == [dev]
 
 
 def test_rig_env_hook_in_pristine_subprocess():
