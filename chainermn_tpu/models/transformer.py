@@ -373,7 +373,7 @@ class _DecoderBlock(nn.Module):
                 # speculative path) keep the Pallas kernel; prefill
                 # chunks (scalar decode_pos, large T) stay on the
                 # gathered einsum, and so does a head width whose
-                # [k|v] panel the chip's kernel cannot tile.
+                # [k|v] lane group the chip's kernel cannot slice.
                 verify = (
                     jnp.ndim(decode_pos) == 1 and 1 < T <= MAX_VERIFY_T
                 )

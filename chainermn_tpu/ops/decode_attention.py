@@ -35,25 +35,33 @@ upstream.
 side by side in one lane group ``[k_h | v_h]`` (why that layout and no
 other: :mod:`chainermn_tpu.serving.kv_pool`) — and each slot owns a
 block table mapping logical cache blocks to physical pool blocks
-(vLLM/PagedAttention, Kwon et al. 2023).  Grid ``(S, KH, MB)`` with the
-block tables scalar-prefetched so each program's ONE K/V DMA is the
-``(block_len, 2 * Dh)`` panel ``pool[table[s, m], :, kh]``, split into
-``k`` and ``v`` in VMEM — the kernel walks the table directly, no
-gathered contiguous copy is ever materialized.  Blocks accumulate
-through the online-softmax recurrence (running max / normalizer /
-fp32 accumulator in VMEM scratch), so there is no ``MAX_FUSED_LEN``
-cap: VMEM holds one panel at a time.  Blocks
-entirely past ``valid_len`` are skipped (``@pl.when``), so a
-short sequence in a long-capacity slot pays for the blocks it
-actually fills.  A 4-D query ``(S, T, H, Dh)`` is the **multi-query
-verify mode** (the serving engine's speculative decode): ``T`` chunk
-positions ride as extra rows of each ``(slot, kv-head)`` program, and
-query offset ``t`` attends positions ``< valid_len + t`` — per-position
-causality inside the verify chunk, one kernel launch for all ``k + 1``
-positions (``T <= MAX_VERIFY_T``; ``T == 1`` is bit-identical to the
-3-D call).  On a chip the panel's lane width ``2 * Dh`` has to be a
-multiple of 128 (:func:`paged_kernel_takes`); any other head width
-takes the model's gathered einsum path.
+(vLLM/PagedAttention, Kwon et al. 2023).  Grid ``(S,)``, one step a
+slot, with the block tables and each slot's count of resident blocks
+scalar-prefetched: the pool stays in HBM and the step loops over the
+slot's OWN blocks, DMAing the whole contiguous row
+``pool[table[s, i]]`` — every KV head of the block, 102 KB at GPT-2 XL's
+25 heads of 64 in bf16 — into a double buffer while the row before it
+is consumed.  The kernel walks the table directly, no gathered
+contiguous copy is ever materialized, and a table entry past a slot's
+length costs neither a DMA nor a loop iteration, an idle slot nothing
+but its zeros.  Blocks accumulate through the online-softmax recurrence
+(running max / normalizer / fp32 accumulator in VMEM scratch), so there
+is no ``MAX_FUSED_LEN`` cap: VMEM holds two rows at a time.  How a
+row's heads are handled follows from the static shapes: one query head
+a KV head (MHA) as lane-dense VPU arithmetic over the whole row — the
+slot's queries laid along the row's own lanes, one cross-lane sum a
+head — because 25 matmuls of ``(1 x 64) . (64 x 16)`` a block leave the
+MXU idle behind its own latency; grouped queries (and the int8 pool,
+whose scale panels are per head) as a static loop of MXU matmuls over
+the KV heads, where ``G * T`` query rows a head make the matmul worth
+its push.  A 4-D query ``(S, T, H, Dh)`` is the **multi-query verify
+mode** (the serving engine's speculative decode): query offset ``t``
+attends positions ``< valid_len + t`` — per-position causality inside
+the verify chunk, one kernel launch for all ``k + 1`` positions
+(``T <= MAX_VERIFY_T``; ``T == 1`` is bit-identical to the 3-D call).
+On a chip a head's lane group ``2 * Dh`` has to be a multiple of 128
+(:func:`paged_kernel_takes`); any other head width takes the model's
+gathered einsum path.
 
 No reference counterpart (the reference has no incremental-decode stack;
 SURVEY §2.9's examples are training-side) — this extends the repo's
@@ -203,21 +211,146 @@ def fused_decode_attention(
 
 def paged_kernel_takes(head_dim: int) -> bool:
     """Whether :func:`paged_decode_attention` can read a pool of this
-    head width where it runs: Mosaic wants the ``(block_len, 2 * Dh)``
-    panel's lane width a multiple of 128 (Dh 64, 128, 192, 256 ...); the
-    interpreter (every non-TPU backend) takes any width.  The shape
-    decides — callers with another width use the gathered einsum path."""
+    head width where it runs: the kernel DMAs a pool block's whole
+    ``(block_len, KH * 2 * Dh)`` row and slices it at the heads'
+    ``[k | v]`` lane groups, so Mosaic wants a group's width ``2 * Dh`` a
+    multiple of 128 lanes (Dh 64, 128, 192, 256 ...); the interpreter
+    (every non-TPU backend) takes any width.  The shape decides — callers
+    with another width use the gathered einsum path."""
     return (2 * head_dim) % 128 == 0 or _use_interpret()
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, kv_ref, *rest,
-                  scale, block_len, quant, n_q, group):
-    """One (slot, kv head, logical block): online-softmax accumulation of
-    this block's contribution into the VMEM scratch; the last block
-    normalizes and writes the (n_q·G, Dh) output.
+def _group_sums(x, width):
+    """Sum each ``width``-lane group of ``x``'s last axis, the sum left in
+    every lane of its group (the groups are the heads' ``[k | v]`` lanes;
+    a slice at a multiple of ``width`` is whole vregs on the chip, and the
+    sum over it one cross-lane reduce a vreg)."""
+    rows, lanes = x.shape
+    return jnp.concatenate([
+        jnp.broadcast_to(
+            jnp.sum(x[:, h * width:(h + 1) * width], axis=1, keepdims=True),
+            (rows, width))
+        for h in range(lanes // width)
+    ], axis=1)
 
-    ``kv_ref`` is the ``(1, block_len, 2·Dh)`` panel ``[k | v]`` of this
-    head in this physical block — one DMA, split here.
+
+#: rows of the pool in VMEM at once in :func:`paged_decode_attention`: one
+#: in use and three in flight.  On a v5e (my chip runs, PR 28) two buffers
+#: left every block waiting on its row — 0.40 us a block at 102 KB rows and
+#: at 16 KB rows alike, so the DMA's latency and not its bytes — three
+#: 0.29, four 0.27 (the arithmetic's own time at GPT-2 XL's row), six and
+#: eight no better.
+_ROWS_IN_VMEM = 4
+
+
+def _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold):
+    """This slot's resident blocks, in table order: ``fold(i, b)`` runs on
+    block ``i`` once its row sits in ``kv_buf[b]``, while the rows of the
+    blocks after it are in flight into the other buffers.  The loop's trip
+    count is the slot's own block count: a table entry past it costs
+    nothing.  ``init()`` runs behind the first rows' DMAs."""
+    s_idx = pl.program_id(0)
+    n = nblk_ref[s_idx]
+    n_buf = kv_buf.shape[0]
+
+    def row(i):
+        b = i % n_buf
+        return pltpu.make_async_copy(
+            kv_hbm.at[tbl_ref[s_idx, i]], kv_buf.at[b], sem.at[b])
+
+    for i in range(n_buf - 1):
+        pl.when(i < n)(row(i).start)
+    init()
+
+    def body(i, carry):
+        ahead = i + (n_buf - 1)
+        pl.when(ahead < n)(lambda: row(ahead).start())
+        row(i).wait()
+        fold(i, i % n_buf)
+        return carry
+
+    jax.lax.fori_loop(0, n, body, None)
+
+
+def _paged_row_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, o_ref,
+                      kv_buf, sem, m_scr, l_scr, acc, *,
+                      block_len, n_q, width, streams):
+    """One slot at one query head a KV head (``G == 1``): every head of a
+    block at once, as lane-dense arithmetic on the ``(block_len, KH * 2 *
+    Dh)`` row.
+
+    ``q_ref`` is ``(1, n_q, 1, L)``: query offset ``t``'s heads laid along
+    the row's own lanes, scaled, zeros under the value lanes — so
+    ``row * q`` holds every head's ``q . k`` products in that head's key
+    lanes and :func:`_group_sums` leaves each head's score in all of its
+    lanes, the value lanes among them, which is where the probabilities
+    multiply the values.  Rows ``r, r + streams, ...`` of a block feed
+    running statistics of their own (``(streams, L)`` a query offset: whole
+    vregs, no reduction over sublanes inside the loop); the end merges the
+    streams — the same online-softmax recurrence, its order of summation
+    over positions changed.  The key lanes of ``acc`` and of the output
+    carry nothing; the caller keeps the value lanes.
+    """
+    n_chunks = block_len // streams
+    valid = len_ref[pl.program_id(0)]
+
+    def init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc[:] = jnp.zeros_like(acc)
+
+    def over_queries(one):
+        if n_q == 1:
+            one(0, None)
+        else:
+            jax.lax.fori_loop(0, n_q, one, None)
+
+    def fold(i, b):
+        kv = kv_buf[b].astype(jnp.float32)            # (BL, L)
+        rows = [kv[c * streams:(c + 1) * streams] for c in range(n_chunks)]
+        pos = i * block_len + jax.lax.broadcasted_iota(
+            jnp.int32, rows[0].shape, 0
+        )
+
+        def one(t, carry):
+            q = q_ref[0, t]                           # (1, L)
+            masks = [pos + c * streams < valid + t for c in range(n_chunks)]
+            s = [jnp.where(mk, _group_sums(r * q, width), NEG_INF)
+                 for r, mk in zip(rows, masks)]
+            m_prev = m_scr[t]
+            m_new = functools.reduce(jnp.maximum, s, m_prev)
+            alpha = jnp.exp(m_prev - m_new)
+            # Explicit p mask: see the per-head kernel below.
+            p = [jnp.where(mk, jnp.exp(x - m_new), 0.0)
+                 for x, mk in zip(s, masks)]
+            l_scr[t] = alpha * l_scr[t] + sum(p)
+            acc[t] = alpha * acc[t] + sum(x * r for x, r in zip(p, rows))
+            m_scr[t] = m_new
+            return carry
+
+        over_queries(one)
+
+    _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold)
+
+    def last(t, carry):
+        m = m_scr[t]
+        w = jnp.exp(m - jnp.max(m, axis=0, keepdims=True))
+        l = jnp.sum(l_scr[t] * w, axis=0, keepdims=True)
+        a = jnp.sum(acc[t] * w, axis=0, keepdims=True)
+        o_ref[0, t] = (a / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return carry
+
+    over_queries(last)
+
+
+def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, *rest,
+                       scale, block_len, quant, n_q, group):
+    """One slot, a static loop over a block's KV heads: per head the
+    ``(n_q * G, Dh) x (Dh, block_len)`` score and ``(n_q * G, block_len) x
+    (block_len, Dh)`` value matmuls on the head's ``[k | v]`` lane group of
+    the row, accumulated through the online-softmax recurrence into that
+    head's VMEM scratch; the end normalizes and writes the ``(KH, n_q * G,
+    Dh)`` output.
 
     ``n_q`` query positions ride as extra rows (row ``r`` is query offset
     ``r // group``): offset ``t`` attends positions ``< valid + t`` —
@@ -225,71 +358,59 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, kv_ref, *rest,
     the classic decode bound at ``n_q == 1``.
     """
     if quant:
-        sc_ref, o_ref, m_scr, l_scr, acc = rest
-    else:
-        o_ref, m_scr, l_scr, acc = rest
-    s_idx = pl.program_id(0)
-    m_idx = pl.program_id(2)
-    n_blocks = pl.num_programs(2)
+        sc_ref, *rest = rest
+    o_ref, kv_buf, sem, m_scr, l_scr, acc = rest
+    KH, R, Dh = q_ref.shape[1:]
 
-    @pl.when(m_idx == 0)
-    def _():
-        # Scratch persists across grid steps (the block axis is innermost
-        # and sequential on TPU) — every slot/head pair must re-init it.
+    def init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
-    valid = len_ref[s_idx]
-    base = m_idx * block_len
+    # Row r is query offset r // group; it may attend one position more
+    # than the row before it (the verify chunk's causality).
+    bound = len_ref[pl.program_id(0)] + jax.lax.broadcasted_iota(
+        jnp.int32, (R, block_len), 0) // group
 
-    @pl.when(base < valid + (n_q - 1))
-    def _():
-        # Blocks wholly past the LAST query's bound are skipped: a short
-        # sequence in a long-capacity slot reads only its filled blocks.
-        R, Dh = q_ref.shape[2], q_ref.shape[3]  # n_q * group rows
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # (R, Dh)
-        kv = kv_ref[0].astype(jnp.float32)            # (BL, 2·Dh)
-        k, v = kv[:, :Dh], kv[:, Dh:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (R, BL)
-        if quant:
-            # Per-position k scale commutes out of the Dh contraction; v
-            # scale folds into the probability operand below.
-            s = s * sc_ref[0, 0, 0:1, :]
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (R, k.shape[0]), 1
-        )
-        # Row r is query offset r // group; it may attend one position
-        # more than the row before it (the verify chunk's causality).
-        toff = jax.lax.broadcasted_iota(jnp.int32, (R, k.shape[0]), 0) \
-            // group
-        mask = pos < valid + toff
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        # Explicit p mask: with the finite NEG_INF stand-in, a fully-masked
-        # row would otherwise see exp(NEG_INF - NEG_INF) = 1 per position.
-        p = jnp.exp(s - m_new[:, None]) * mask.astype(jnp.float32)
-        l_scr[:, 0] = alpha * l_scr[:, 0] + jnp.sum(p, axis=1)
-        if quant:
-            p = p * sc_ref[0, 0, 1:2, :]
-        acc[:] = alpha[:, None] * acc[:] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:, 0] = m_new
+    def fold(i, b):
+        mask = i * block_len + jax.lax.broadcasted_iota(
+            jnp.int32, (R, block_len), 1
+        ) < bound
+        for h in range(KH):
+            q = q_ref[0, h].astype(jnp.float32) * scale   # (R, Dh)
+            kv = kv_buf[b, :, h * 2 * Dh:(h + 1) * 2 * Dh] \
+                .astype(jnp.float32)                      # (BL, 2·Dh)
+            k, v = kv[:, :Dh], kv[:, Dh:]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (R, BL)
+            if quant:
+                # Per-position k scale commutes out of the Dh contraction;
+                # v scale folds into the probability operand below.
+                s = s * sc_ref[0, i, h, 0:1, :]
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[h, :, 0]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            alpha = jnp.exp(m_prev - m_new)
+            # Explicit p mask: with the finite NEG_INF stand-in, a
+            # fully-masked row would otherwise see exp(NEG_INF - NEG_INF)
+            # = 1 per position.
+            p = jnp.exp(s - m_new[:, None]) * mask.astype(jnp.float32)
+            l_scr[h, :, 0] = alpha * l_scr[h, :, 0] + jnp.sum(p, axis=1)
+            if quant:
+                p = p * sc_ref[0, i, h, 1:2, :]
+            acc[h] = alpha[:, None] * acc[h] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h, :, 0] = m_new
 
-    @pl.when(m_idx == n_blocks - 1)
-    def _():
-        o_ref[0, 0] = (
-            acc[:] / jnp.maximum(l_scr[:, 0], 1e-30)[:, None]
-        ).astype(o_ref.dtype)
+    _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold)
+    o_ref[0] = (acc[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
+@jax.jit  # a model's layers call it alike: traced once, not once a layer
 def paged_decode_attention(
     q: jax.Array,
     kv_pool: jax.Array,
@@ -301,12 +422,22 @@ def paged_decode_attention(
 
     The serving engine's hot op (``chainermn_tpu/serving/engine.py``): S
     decode slots each read their own logical sequence out of one shared
-    physical pool through a per-slot block table.  The kernel walks the
-    table via scalar prefetch — block ``m`` of slot ``s`` DMAs head
-    ``kh``'s ``[k | v]`` panel of ``pool[block_tables[s, m]]`` straight
-    into VMEM — and folds blocks through the online-softmax recurrence, so
-    no contiguous per-slot cache copy is ever materialized and there is no
-    ``MAX_FUSED_LEN`` cap.
+    physical pool through a per-slot block table.  Grid ``(S,)``, one step
+    a slot, the tables and each slot's count of resident blocks
+    scalar-prefetched: the pool stays in HBM and the step loops over the
+    slot's own blocks (:func:`_walk_blocks`), DMAing the whole row
+    ``pool[block_tables[s, i]]`` — every KV head of the block in one
+    contiguous read — ``_ROWS_IN_VMEM - 1`` blocks ahead of the one it
+    folds through the online-softmax recurrence.  No contiguous per-slot
+    cache copy is ever materialized, there is no ``MAX_FUSED_LEN`` cap, and
+    a table entry past the slot's last resident block costs neither a DMA
+    nor a loop iteration.
+
+    How the heads of a row are handled follows from the static shapes
+    alone: one query head a KV head (``G == 1``, float pool) as lane-dense
+    arithmetic over the whole row (:func:`_paged_row_kernel`); grouped
+    queries, and the int8 pool with its per-head scale panel, as a static
+    loop of MXU matmuls over the KV heads (:func:`_paged_head_kernel`).
 
     Args:
       q: ``(S, H, Dh)`` — each slot's current query position — or
@@ -321,7 +452,7 @@ def paged_decode_attention(
         ``Dh`` its value (:mod:`chainermn_tpu.serving.kv_pool`).
       block_tables: ``(S, max_blocks)`` int32 — logical→physical block map
         per slot.  Entries past a slot's filled length may point anywhere
-        valid (they are masked, conventionally 0 — the serving pool
+        (they are never read, conventionally 0 — the serving pool
         reserves physical block 0 as the parking block).
       valid_len: ``(S,)`` int32 — the FIRST query position's causal bound:
         positions ``< valid_len[s] + t`` attendable for query offset
@@ -338,8 +469,7 @@ def paged_decode_attention(
     Returns ``(S, H, Dh)`` or ``(S, T, H, Dh)`` (matching ``q``) in
     ``q``'s dtype.
     """
-    multi = q.ndim == 4
-    if multi:
+    if q.ndim == 4:
         S, T, H, Dh = q.shape
     else:
         S, H, Dh = q.shape
@@ -349,8 +479,8 @@ def paged_decode_attention(
             f"kv_pool must be (num_blocks, block_len, KH * 2 * Dh) with "
             f"Dh = {Dh}, got {kv_pool.shape}"
         )
-    _, BL, lanes = kv_pool.shape
-    KH = lanes // (2 * Dh)
+    _, BL, L = kv_pool.shape
+    KH = L // (2 * Dh)
     if H % KH:
         raise ValueError(f"H ({H}) must be a multiple of KH ({KH})")
     if block_tables.ndim != 2 or block_tables.shape[0] != S:
@@ -363,67 +493,95 @@ def paged_decode_attention(
     quant = kv_pool.dtype == jnp.int8
     if quant and kv_scale is None:
         raise ValueError("int8 pool needs kv_scale")
-    if multi:
-        # Query offsets ride as extra ROWS of each (slot, kv-head)
-        # program: (S, T, KH, G, Dh) -> (S, KH, T*G, Dh), offset t of
-        # group row g at row t*G + g (the kernel recovers t as
-        # row // G for its per-offset causal bound).
-        qg = q.reshape(S, T, KH, G, Dh).transpose(0, 2, 1, 3, 4) \
-            .reshape(S, KH, T * G, Dh)
-    else:
-        qg = q.reshape(S, KH, G, Dh)
-    R = T * G
+    scale = 1.0 / math.sqrt(Dh)
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(valid_len, jnp.int32).reshape(S)
+    # Blocks some query offset of the slot may attend: the kernel's trip
+    # count.  A table entry past it is never read.
+    nblk = jnp.minimum((lens + (T - 1) + (BL - 1)) // BL, MB)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, R, Dh), lambda s, h, m, tbl, ln: (s, h, 0, 0)),
-        pl.BlockSpec(
-            (1, BL, 2 * Dh), lambda s, h, m, tbl, ln: (tbl[s, m], 0, h)
-        ),
-    ]
-    operands = [qg, kv_pool]
-    if quant:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, 2, BL), lambda s, h, m, tbl, ln: (tbl[s, m], h, 0, 0)
-        ))
-        operands.append(kv_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, KH, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, R, Dh), lambda s, h, m, tbl, ln: (s, h, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),   # running max
-            pltpu.VMEM((R, 1), jnp.float32),   # normalizer
-            pltpu.VMEM((R, Dh), jnp.float32),  # output accumulator
-        ],
-    )
+    def slot(s, *prefetched):
+        return (s, 0, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    q4 = q.reshape(S, T, KH, G, Dh)
+    by_row = G == 1 and not quant
+    if by_row:
+        # Query offset t's heads along the row's own lanes: scaled, zeros
+        # under the value lanes.
+        qrow = q4.reshape(S, T, KH, Dh).astype(jnp.float32) * scale
+        qrow = jnp.concatenate([qrow, jnp.zeros_like(qrow)], axis=-1) \
+            .reshape(S, T, 1, L)
+        streams = math.gcd(BL, 8)
+        kernel = functools.partial(
+            _paged_row_kernel, block_len=BL, n_q=T, width=2 * Dh,
+            streams=streams,
+        )
+        operands = [qrow, kv_pool]
+        in_specs = [pl.BlockSpec((1, T, 1, L), slot), hbm]
+        out_spec = pl.BlockSpec((1, T, 1, L), slot)
+        out_shape = jax.ShapeDtypeStruct((S, T, 1, L), q.dtype)
+        scratch = [pltpu.VMEM((T, streams, L), jnp.float32)] * 3
+    else:
+        # Query offsets ride as extra ROWS of each KV head: offset t of
+        # group row g at row t*G + g (the kernel recovers t as row // G
+        # for its per-offset causal bound).
+        R = T * G
+        qg = q4.transpose(0, 2, 1, 3, 4).reshape(S, KH, R, Dh)
+        kernel = functools.partial(
+            _paged_head_kernel, scale=scale, block_len=BL, quant=quant,
+            n_q=T, group=G,
+        )
+        operands = [qg, kv_pool]
+        in_specs = [pl.BlockSpec((1, KH, R, Dh), slot), hbm]
+        if quant:
+            # The scale plane's 16-wide minor axis is no row a DMA issued
+            # by hand can slice (Mosaic wants 128 lanes): the slot's scale
+            # panels ride gathered, a per-slot block like the queries.
+            operands.append(kv_scale[tbl])
+            in_specs.append(pl.BlockSpec(
+                (1, MB, KH, 2, BL), lambda s, *prefetched: slot(s) + (0,)))
+        out_spec = pl.BlockSpec((1, KH, R, Dh), slot)
+        out_shape = jax.ShapeDtypeStruct((S, KH, R, Dh), q.dtype)
+        scratch = [
+            pltpu.VMEM((KH, R, 1), jnp.float32),   # running max
+            pltpu.VMEM((KH, R, 1), jnp.float32),   # normalizer
+            pltpu.VMEM((KH, R, Dh), jnp.float32),  # output accumulator
+        ]
     out = pl.pallas_call(
-        functools.partial(
-            _paged_kernel, scale=1.0 / math.sqrt(Dh), block_len=BL,
-            quant=quant, n_q=T, group=G,
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=[
+                pltpu.VMEM((_ROWS_IN_VMEM, BL, L), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((_ROWS_IN_VMEM,)),
+            ] + scratch,
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KH, R, Dh), q.dtype),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=_use_interpret(),
         name="paged_decode",
-    )(tbl, lens, *operands)
-    if multi:
-        return out.reshape(S, KH, T, G, Dh).transpose(0, 2, 1, 3, 4) \
-            .reshape(S, T, H, Dh)
-    return out.reshape(S, H, Dh)
+    )(tbl, lens, nblk, *operands)
+    if by_row:
+        out = out.reshape(S, T, KH, 2, Dh)[:, :, :, 1]
+    else:
+        out = out.reshape(S, KH, T, G, Dh).transpose(0, 2, 1, 3, 4)
+    return out.reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------
 # Tensor-parallel (shard_map) entry points
 # ---------------------------------------------------------------------------
 #
-# Both kernels are embarrassingly parallel across KV heads: program
-# (.., kh, ..) touches only kv head ``kh`` of the cache/pool and query
-# group ``kh`` of q.  A 1-D mesh cut on the KV-head axis therefore needs
+# Both kernels are embarrassingly parallel across KV heads: what they
+# compute for kv head ``kh`` touches only that head's panel (the fused
+# kernel) or lane group of a pool row (the paged one) and query group
+# ``kh`` of q.  A 1-D mesh cut on the KV-head axis therefore needs
 # NO collective — each shard runs the unmodified kernel over its
 # ``KH / n`` local heads and the per-shard outputs concatenate on the
 # (query-)head axis, which is exactly the Megatron column cut the

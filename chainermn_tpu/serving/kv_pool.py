@@ -13,7 +13,10 @@ A token's row holds every KV head's key and value side by side,
 ``[k_0 | v_0 | k_1 | v_1 | ...]``: lanes ``[h*2*Dh, h*2*Dh + Dh)`` are head
 ``h``'s key, the next ``Dh`` its value.  (The int8 scale plane pairs a
 head's key and value scale the same way, positions minor-most: the
-``(2, block_len)`` panel the kernel multiplies into its scores.)
+``(2, block_len)`` panel the kernel multiplies into its scores.  Its
+16-wide minor axis is no row a hand-issued DMA can slice, so the kernel's
+caller gathers a slot's panels through its table — ``kv_scale[table]`` —
+and hands them over as a per-slot block.)
 
 **Why this layout** (chip compiler, PR 25; ``tests/ops_tests/
 test_tpu_compile.py`` keeps the proof).  Three parties touch a pool — the
@@ -36,9 +39,14 @@ PR 24) — to write 0.2 MB.  With ``(num_blocks, block_len, KH * 2 * Dh)``
 the minor axis is a multiple of 128 lanes whenever ``2 * Dh`` is, all three
 agree on plain row-major ``{2,1,0}``, the scatter updates the donated
 argument in place, and nothing is padded.  Fusing ``k`` and ``v`` is what
-makes ``Dh = 64`` lane-dense; it also halves the kernel's DMAs (one
-``(block_len, 2 * Dh)`` panel a grid step).  A cut on KV heads is still a
-plain block cut — of the last axis (:mod:`~chainermn_tpu.serving.sharding`).
+makes ``Dh = 64`` lane-dense, and a block's row — every KV head's key and
+value for ``block_len`` tokens — is one contiguous read: the kernel
+(:func:`~chainermn_tpu.ops.paged_decode_attention`) leaves the pool in HBM
+and DMAs ``pool[table[s, i]]`` whole, 102 KB at 25 heads of 64 in bf16, for
+each block a slot holds and for no other table entry.  A cut on KV heads is
+still a plain block cut — of the last axis
+(:mod:`~chainermn_tpu.serving.sharding`) — and the local row is then the
+shard's DMA.
 
 A decode slot owns an ordered list of physical blocks (its *block table*);
 logical position ``p`` lives at ``(table[p // block_len], p % block_len)``.

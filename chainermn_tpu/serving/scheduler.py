@@ -1326,8 +1326,9 @@ class Scheduler:
         # upload/dispatch/readback spans; publish = histograms, timeline,
         # SLO / incident / memory / device cadence; emit = ledger, policy,
         # token accounting, retirement.  The counts say how much of the
-        # paged kernel's grid holds a token: it visits capacity x
-        # max_blocks table entries whatever the contexts are.
+        # block tables holds a token: capacity x max_blocks entries are
+        # handed to the step whatever the contexts are (the paged kernel
+        # walks only the resident ones; the gathered fallback reads all).
         with _annotate("cmn_serve_decode") as span:
             with _annotate("cmn_serve_build"):
                 S = self.engine.capacity

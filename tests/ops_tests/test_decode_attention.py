@@ -313,6 +313,77 @@ def test_paged_pool_shape_is_checked():
         paged_decode_attention(q, jnp.zeros((6, 4, 32), jnp.int8), tbl, valid)
 
 
+#: slot lengths of one call (block_len 16, a table 4 wide), by name
+_BL, _MB = 16, 4
+_LENGTHS = {
+    "idle": [0] * 6,
+    "one": [1] * 6,
+    "bl_minus_1": [_BL - 1] * 6,
+    "bl": [_BL] * 6,
+    "bl_plus_1": [_BL + 1] * 6,
+    "full_table": [_BL * _MB] * 6,
+    "ragged": [0, 1, _BL + 3, 3 * _BL - 1, _BL * _MB, 2 * _BL],
+}
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("T", [1, 4], ids=["decode", "verify_t4"])
+@pytest.mark.parametrize("lengths", sorted(_LENGTHS))
+@pytest.mark.parametrize("H, KH, Dh", [(25, 25, 64), (24, 2, 128), (4, 2, 64)],
+                         ids=["xl_mha", "sc2_gqa", "gqa_small"])
+def test_paged_whole_row_matches_oracle(H, KH, Dh, lengths, T, kind):
+    """One grid step serves every head of a block: the kernel against the
+    float32 oracle at the serving geometries' head shapes (GPT-2 XL's 25
+    heads of 64 handled along the row's lanes, StarCoder2's 24 / 2 of 128
+    and a small GQA as a loop over KV heads), at the slot lengths where a
+    block fills, for decode and a verify chunk, float and int8 pools.
+
+    Table entries past what a slot can attend point at a block of NaN (in
+    an int8 pool, at NaN scales): a step past the slot's last resident
+    block must neither compute on the row it names nor, by the clamped
+    index map, name another than the last resident one — any read of it
+    poisons the output.  (Six slots in every case: the lengths of a
+    (heads, T, pool dtype) share one compiled kernel.)"""
+    from chainermn_tpu.ops import paged_decode_attention
+
+    valid = np.asarray(_LENGTHS[lengths], np.int32)
+    # the last query of a verify chunk attends through valid + T - 2
+    valid = np.minimum(valid, _BL * _MB - (T - 1))
+    S = len(valid)
+    rng = np.random.RandomState(len(lengths) * 100 + T)
+    NB = S * _MB + 2
+    q = jnp.asarray(rng.randn(S, T, H, Dh), jnp.float32)
+    if kind == "int8":
+        kp = rng.randint(-127, 128, size=(KH, NB, _BL, Dh)).astype(np.int8)
+        vp = rng.randint(-127, 128, size=(KH, NB, _BL, Dh)).astype(np.int8)
+        ks = (rng.rand(KH, NB, _BL) * 0.02 + 0.001).astype(np.float32)
+        vs = (rng.rand(KH, NB, _BL) * 0.02 + 0.001).astype(np.float32)
+        ks[:, NB - 1] = vs[:, NB - 1] = np.nan
+        scale = _fuse_scale(ks, vs)
+        kf, vf = kp * ks[..., None], vp * vs[..., None]
+    else:
+        kp = jnp.asarray(rng.randn(KH, NB, _BL, Dh), jnp.bfloat16)
+        vp = jnp.asarray(rng.randn(KH, NB, _BL, Dh), jnp.bfloat16)
+        kp = kp.at[:, NB - 1].set(jnp.nan)
+        vp = vp.at[:, NB - 1].set(jnp.nan)
+        scale = None
+        kf, vf = np.asarray(kp, np.float32), np.asarray(vp, np.float32)
+    tbl = np.full((S, _MB), NB - 1, np.int32)  # the poisoned block
+    for s in range(S):
+        n = -(-(int(valid[s]) + T - 1) // _BL)
+        tbl[s, :n] = 1 + s * _MB + np.arange(n)
+    out = paged_decode_attention(
+        q if T > 1 else q[:, 0], _fuse(kp, vp), jnp.asarray(tbl),
+        jnp.asarray(valid), scale)
+    out = np.asarray(out).reshape(S, T, H, Dh)
+    assert np.isfinite(out).all()
+    ref = _paged_oracle(q, kf, vf, tbl, valid)
+    live = valid > 0
+    tol = dict(atol=1e-4, rtol=1e-4) if kind == "int8" else dict(atol=2e-5)
+    np.testing.assert_allclose(out[live], ref[live], **tol)
+    assert (out[~live, 0] == 0).all()  # idle: offset 0 fully masked
+
+
 # ------------------------------------------------- sharded (shard_map)
 def _mesh2():
     """A 2-way serving mesh over the forced CPU pod (the tests/conftest

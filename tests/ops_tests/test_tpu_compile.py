@@ -75,7 +75,7 @@ def chip():
 
 def _paged(q_shape, kv_dtype, kv_heads=H, nb=NB):
     """The kernel on the serving pool (``serving/kv_pool.py``): token-major,
-    each head's ``[k | v]`` in one lane group."""
+    each head's ``[k | v]`` in one lane group, a block's row one DMA."""
     dh = q_shape[-1]
     args = [(q_shape, jnp.bfloat16), ((nb, BL, kv_heads * 2 * dh), kv_dtype),
             ((S, MB), jnp.int32), ((S,), jnp.int32)]
@@ -160,15 +160,23 @@ _XL_NB = 2049
 
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
-@pytest.mark.parametrize("heads, kv_heads, dh", [(25, 25, 64), (24, 2, 128)],
-                         ids=["dh64", "dh128"])
-def test_mosaic_accepts_the_kv_panel(heads, kv_heads, dh, kv_dtype, chip):
-    """The pool's ``(1, block_len, 2 * Dh)`` panel ``[k | v]`` is a block
-    Mosaic tiles, at both head widths the configurations have, int8 (with
-    its ``(1, 1, 2, block_len)`` scale panel) included."""
-    fn, shapes = _paged((S, heads, dh), kv_dtype, kv_heads, _XL_NB)
+@pytest.mark.parametrize("heads, kv_heads, dh, t", [
+    (25, 25, 64, 1), (24, 2, 128, 1), (25, 25, 64, 4),
+], ids=["dh64", "dh128", "dh64_verify_t4"])
+def test_mosaic_accepts_the_kv_panel(heads, kv_heads, dh, t, kv_dtype, chip):
+    """A pool block's whole ``(block_len, KH * 2 * Dh)`` row — every KV
+    head's ``[k | v]`` — is what the kernel DMAs (by hand, from the pool
+    left in HBM, into its double buffer) and slices at the heads' lane
+    groups: Mosaic takes it at both head widths the configurations have,
+    as lane-dense arithmetic over the row (25 heads of 64, decode and a
+    verify chunk) and as a loop of matmuls over KV heads (24 / 2 of 128),
+    int8 (the slot's ``(max_blocks, KH, 2, block_len)`` scale panels a
+    per-slot block) included — one ``tpu_custom_call`` named
+    ``paged_decode``."""
+    q_shape = (S, heads, dh) if t == 1 else (S, t, heads, dh)
+    fn, shapes = _paged(q_shape, kv_dtype, kv_heads, _XL_NB)
     text = jax.jit(fn).lower(*_on(chip, shapes)).compile().as_text()
-    _assert_mosaic_took(text, 1, ["paged_decode"], (dh, kv_dtype))
+    _assert_mosaic_took(text, 1, ["paged_decode"], (dh, t, kv_dtype))
 
 
 def _xl_layer_step(tokens_per_slot, per_slot_pos):
