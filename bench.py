@@ -132,10 +132,9 @@ def _decode_headline() -> dict | None:
     ``decode_streaming*``).
 
     Only OUTPUT-EQUIVALENT arms compete for the headline — plain,
-    ``kv_int8``, ``speculative``, and the ``decode_attention_arm``
-    (fused-kernel decode) all produce (modulo documented bf16 argmax
-    tie-flips) the target model's greedy generation, so their tokens/sec
-    answer the same question.  ``rolling`` decodes through an
+    ``kv_int8`` and ``speculative`` all produce (modulo documented bf16
+    argmax tie-flips) the target model's greedy generation, so their
+    tokens/sec answer the same question.  ``rolling`` decodes through an
     O(window) ring cache — a *different function* (bounded attention
     context) whose higher tokens/sec must not beat the full-attention
     arms at their own metric; its best capture is reported separately
@@ -148,12 +147,6 @@ def _decode_headline() -> dict | None:
         for arm in ("kv_int8", "speculative"):
             if isinstance(rec.get(arm), dict):
                 arms.append((rec[arm].get("tokens_per_sec"), arm))
-        if isinstance(rec.get("decode_attention_arm"), dict):
-            fa = rec["decode_attention_arm"]
-            arms.append((
-                fa.get("tokens_per_sec"),
-                f"decode_attention={fa.get('impl')}",
-            ))
         for tps, arm in arms:
             yield tps, {
                 "metric": "lm_decode_tokens_per_sec",

@@ -65,16 +65,6 @@ def main():
                     help="grouped-query attention: kv heads (0 = classic "
                          "MHA) — shrinks the KV cache, decode's dominant "
                          "bandwidth term, by n_heads/kv_heads")
-    ap.add_argument("--decode-attention", default=None,
-                    choices=("einsum", "fused"),
-                    help="also time the chosen decode-attention impl as "
-                         "an arm against the model default: 'fused' runs "
-                         "every generation step through the Pallas "
-                         "decode kernel over the kv-head-major cache "
-                         "(ops.fused_decode_attention), 'einsum' the "
-                         "classic XLA path.  Output-equivalent (greedy "
-                         "agreement reported with divergence structure), "
-                         "so the arm competes for the decode headline")
     ap.add_argument("--kv-int8", action="store_true",
                     help="also time an int8-quantized KV cache arm "
                          "(kv_dtype=jnp.int8: same params, half the "
@@ -229,39 +219,6 @@ def main():
         payload["window"] = args.window
     if args.rope:
         payload["pos_enc"] = "rope"
-    if args.decode_attention and args.decode_attention == model.decode_attention:
-        # Not silently dropped: the requested impl IS the baseline, so a
-        # separate arm would time the identical program twice.
-        payload["decode_attention_arm"] = {
-            "skipped": f"requested impl '{args.decode_attention}' is "
-                       "already the model default — no separate arm to "
-                       "time",
-            "impl": args.decode_attention,
-            "baseline_impl": model.decode_attention,
-        }
-    elif args.decode_attention:
-        # BEFORE the speculative block (same reason as --kv-int8 below:
-        # --draft-mode distilled mutates `params` in place).  Same params,
-        # same prompt — only the decode-step attention impl and its cache
-        # layout change, so the ratio isolates the fused kernel's single
-        # VMEM pass over the cache vs the einsum's fp32 materializations.
-        # Greedy-token agreement vs the default path is reported with the
-        # divergence structure: exact in fp32, bf16 near-argmax ties can
-        # flip between the two kernels.
-        fa_model = model.clone(decode_attention=args.decode_attention)
-        fa_dt, fa_toks = timed(False, m=fa_model)
-        payload["decode_attention_arm"] = {
-            "impl": args.decode_attention,
-            "baseline_impl": model.decode_attention,
-            "tokens_per_sec": round(
-                args.batch * args.new * args.iters / fa_dt, 1
-            ),
-            "ms_per_gen_step": round(
-                fa_dt / args.iters / steps * 1000.0, 3
-            ),
-            "speedup_vs_default": round(dt / fa_dt, 3),
-            "greedy_agreement": _divergence_stats(fa_toks, plain_toks),
-        }
     if args.kv_int8:
         # BEFORE the speculative block: --draft-mode distilled mutates
         # `params` in place (zeroing tail-block write-backs), so an int8

@@ -54,42 +54,37 @@ def segment_positions(segment_ids: jax.Array) -> jax.Array:
     return idx - starts  # (B, T)
 
 
-def _attend_kv_major(q, kc, vc, q_pos, window, ks_c=None, vs_c=None):
-    """Grouped-query attention of a ``(B, T, H, Dh)`` query chunk against a
-    kv-head-major ``(B, KH, L, Dh)`` cache — the einsum fallback for the
-    fused-kernel cache layout (prefill chunks, sliding-window models,
-    ``L > MAX_FUSED_LEN``) and for the gathered paged-pool view.
-
-    Mask semantics mirror the legacy ``(B, L, KH, Dh)`` einsum path exactly
-    (causal length bound per row; optional sliding window); only the cache
-    axis order differs.  ``ks_c``/``vs_c`` are the int8 cache's
-    per-(kv-head, position) scales, ``(B, KH, L)``.
-    """
-    B, T, H, Dh = q.shape
-    KH = kc.shape[1]
-    qg = q.reshape(B, T, KH, H // KH, Dh)
-    s = jnp.einsum(
-        "btkgd,bkld->bkgtl", qg.astype(jnp.float32),
-        kc.astype(jnp.float32),
-    ) / math.sqrt(Dh)
-    if ks_c is not None:
-        s = s * ks_c[:, :, None, None, :]
-    t_idx = jnp.arange(kc.shape[2])
-    visible = (
-        t_idx[None, None, None, None, :]
-        <= q_pos[:, None, None, :, None]
-    )
-    if window:
-        visible &= (
-            t_idx[None, None, None, None, :]
-            > q_pos[:, None, None, :, None] - window
+def _wants_paged_kernel(decode_attention: str) -> bool:
+    """The one reading of the ``decode_attention`` field: whether paged
+    decode steps may run the Pallas kernel.  Any string but the two is
+    refused (the block's call and ``init_cache`` both ask)."""
+    if decode_attention not in ("einsum", "fused"):
+        raise ValueError(
+            f"decode_attention={decode_attention!r}: expected 'einsum' or "
+            "'fused'"
         )
-    s = jnp.where(visible, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    if vs_c is not None:
-        p = p * vs_c[:, :, None, None, :]
-    a = jnp.einsum("bkgtl,bkld->btkgd", p, vc.astype(jnp.float32))
-    return a.reshape(B, T, H, Dh).astype(q.dtype)
+    return decode_attention == "fused"
+
+
+def _quantise_kv(k, v):
+    """A chunk's ``(B, T, KH, Dh)`` keys and values as symmetric-absmax
+    int8 plus one fp32 scale per (token, kv-head) row, ``(B, T, KH)``:
+    ``(k_w, v_w, (k_scale, v_scale))``.  Both cache formats store these."""
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)
+    k_scale = jnp.maximum(
+        jnp.max(jnp.abs(kf), axis=-1), 1e-6
+    ) / 127.0  # (B, T, KH)
+    v_scale = jnp.maximum(
+        jnp.max(jnp.abs(vf), axis=-1), 1e-6
+    ) / 127.0
+    k_w = jnp.clip(
+        jnp.round(kf / k_scale[..., None]), -127, 127
+    ).astype(jnp.int8)
+    v_w = jnp.clip(
+        jnp.round(vf / v_scale[..., None]), -127, 127
+    ).astype(jnp.int8)
+    return k_w, v_w, (k_scale, v_scale)
 
 
 # =====================================================================
@@ -121,21 +116,11 @@ class _DecoderBlock(nn.Module):
     #: last ``window`` positions only; the flash kernel skips out-of-window
     #: blocks (O(T·window) attention compute).
     window: int = 0
-    #: decode-path attention impl: "einsum" (the original XLA path over the
-    #: (B, L, KH, Dh) cache, unchanged) or "fused" — kv-head-major
-    #: (B, KH, L, Dh) cache layout with single-token steps dispatched to
-    #: the Pallas kernel (:func:`~chainermn_tpu.ops.fused_decode_attention`),
-    #: einsum fallback for prefill chunks / window models / lengths past
-    #: ``MAX_FUSED_LEN``.  Training paths are untouched either way.
+    #: :class:`TransformerLM`'s fields of the same names, handed to
+    #: :func:`~chainermn_tpu.ops.decode_attention.paged_attend`.  The mesh
+    #: is static (hashable), so it composes with flax's module dataclass
+    #: and jit caching.
     decode_attention: str = "einsum"
-    #: tensor-parallel serving mesh (``jax.sharding.Mesh``, 1-D) or None.
-    #: When set (``serving.sharding.attach_decode_mesh``) the "fused"
-    #: decode dispatches run the Pallas kernel per shard under
-    #: ``shard_map`` (:func:`~chainermn_tpu.ops.sharded_paged_decode_attention`)
-    #: — queries cut on the head axis, caches/pools on the KV-head axis —
-    #: instead of forcing sharded engines onto the gathered einsum.
-    #: Static (Mesh is hashable) so it composes with flax's module
-    #: dataclass and jit caching.
     decode_mesh: Any = None
     #: "learned" (parent adds a position table to the embeddings) or
     #: "rope" (this block rotates q/k — the parent adds nothing to ``h``
@@ -170,25 +155,20 @@ class _DecoderBlock(nn.Module):
         one set of weights serves training and generation.
 
         ``block_tables`` (``(B, max_blocks)`` int32) switches the decode
-        path to the PAGED cache: the cache entry is one physical block
-        pool ``{"kv": (num_blocks, block_len, KH * 2 * Dh)}`` shared by all
-        rows (token-major, each head's ``[k | v]`` side by side —
-        :mod:`chainermn_tpu.serving.kv_pool`), and each row's positions
-        are mapped through its block table.  ``slot_mask`` (``(B,)`` bool) marks
-        live decode slots — masked rows write nothing (their scatter is
-        redirected to the reserved parking block with their own current
-        value, keeping duplicate-index writes deterministic)."""
+        path to the PAGED cache: the cache entry is one layer's physical
+        block pool, shared by all rows, and each row's positions are
+        mapped through its block table.  ``slot_mask`` (``(B,)`` bool)
+        marks live decode slots — masked rows write nothing.  The pool's
+        format, its write and its two reads are
+        :mod:`chainermn_tpu.ops.decode_attention`'s."""
         from chainermn_tpu.ops import (
-            MAX_FUSED_LEN,
-            MAX_VERIFY_T,
             flash_attention,
-            fused_decode_attention,
-            paged_decode_attention,
-            paged_kernel_takes,
             reference_attention,
             resolve_attention,
-            sharded_fused_decode_attention,
-            sharded_paged_decode_attention,
+        )
+        from chainermn_tpu.ops.decode_attention import (
+            paged_attend,
+            pool_write,
         )
         from chainermn_tpu.ops.rope import apply_rope
 
@@ -206,11 +186,7 @@ class _DecoderBlock(nn.Module):
             # paths — softmax over all-NEG_INF rows degenerates to uniform
             # (causality-violating) weights with no error.
             raise ValueError(f"window must be >= 0, got {self.window}")
-        if self.decode_attention not in ("einsum", "fused"):
-            raise ValueError(
-                f"decode_attention={self.decode_attention!r}: expected "
-                "'einsum' or 'fused'"
-            )
+        paged_kernel = _wants_paged_kernel(self.decode_attention)
         x = nn.LayerNorm(dtype=self.dtype, param_dtype=self.param_dtype, name="ln1")(h)
         with jax.named_scope("attn_qkv"):
             if KH == H:
@@ -239,15 +215,12 @@ class _DecoderBlock(nn.Module):
             # of shorter rows unattended.
             B = k.shape[0]
             paged = block_tables is not None
-            kv_major = paged or self.decode_attention == "fused"
-            if rolling and kv_major:
-                # The ring-buffer slot arithmetic is implemented on the
-                # legacy layout only; streaming decode wants the einsum
-                # path's O(window) cache, not the fused kernel.
+            if rolling and paged:
+                # The ring-buffer slot arithmetic is the contiguous
+                # cache's; a ring over a block table is not implemented.
                 raise ValueError(
-                    "rolling decode requires decode_attention='einsum' "
-                    "and a non-paged cache (got decode_attention="
-                    f"{self.decode_attention!r}, paged={paged})"
+                    "rolling decode requires a non-paged cache (got "
+                    "block_tables)"
                 )
             if rolling:
                 # Ring-buffer cache of size `window`: slot = pos mod W.
@@ -297,200 +270,32 @@ class _DecoderBlock(nn.Module):
             # float cache: the k scale folds into the score einsum's
             # output, the v scale into the probability operand.
             quant = ("kv_scale" if paged else "k_scale") in cache
-            with jax.named_scope("kv_write"):
-                if quant:
-                    kf = k.astype(jnp.float32)
-                    vf = v.astype(jnp.float32)
-                    k_scale = jnp.maximum(
-                        jnp.max(jnp.abs(kf), axis=-1), 1e-6
-                    ) / 127.0  # (B, T, KH)
-                    v_scale = jnp.maximum(
-                        jnp.max(jnp.abs(vf), axis=-1), 1e-6
-                    ) / 127.0
-                    k_w = jnp.clip(
-                        jnp.round(kf / k_scale[..., None]), -127, 127
-                    ).astype(jnp.int8)
-                    v_w = jnp.clip(
-                        jnp.round(vf / v_scale[..., None]), -127, 127
-                    ).astype(jnp.int8)
-                else:
-                    # Float cache: cast to the cache's storage dtype (kv_dtype
-                    # may differ from the compute dtype — e.g. store bf16 under
-                    # fp32 compute).
-                    kvd = cache["kv" if paged else "k"].dtype
-                    k_w = k.astype(kvd)
-                    v_w = v.astype(kvd)
-            write_pos = (
-                decode_pos % self.window if rolling else decode_pos
-            )
+            k_w, v_w, scales = k, v, None
+            if quant:
+                with jax.named_scope("kv_write"):
+                    k_w, v_w, scales = _quantise_kv(k, v)
             if paged:
-                # Paged pool write: each row's positions map through its
-                # block table to physical pool blocks; ONE scatter of whole
-                # token rows ``[k_0|v_0|k_1|v_1|...]`` into the token-major
-                # pool (serving/kv_pool.py says why that layout) — the same
-                # statement for decode (T = 1), verify and prefill chunks.
-                # Masked (idle) slots redirect to the reserved parking
-                # block 0 and write back their own current value —
-                # duplicate indices then carry duplicate VALUES, keeping
-                # the scatter deterministic.
-                Dh = D // H
-                with jax.named_scope("kv_write"):
-                    pool = cache["kv"]
-                    BL = pool.shape[1]
-                    pb = jnp.take_along_axis(
-                        block_tables, q_pos // BL, axis=1
-                    )  # (B, T) physical block per written position
-                    off = q_pos % BL
-                    row = jnp.concatenate([k_w, v_w], axis=-1).reshape(
-                        B, T, KH * 2 * Dh
-                    )
-                    if quant:
-                        # (B, T, KH, 2): a head's k and v scale, as the
-                        # kernel's (2, block_len) scale panel pairs them.
-                        srow = jnp.stack([k_scale, v_scale], axis=-1)
-                    if slot_mask is not None:
-                        live = slot_mask.astype(bool)[:, None]
-                        pb = jnp.where(live, pb, 0)
-                        off = jnp.where(live, off, 0)
-                        row = jnp.where(live[..., None], row, pool[pb, off])
-                        if quant:
-                            srow = jnp.where(
-                                live[..., None, None], srow,
-                                cache["kv_scale"][pb, :, :, off],
-                            )
-                    kvc = pool.at[pb, off].set(row)
-                    sc_c = (
-                        cache["kv_scale"].at[pb, :, :, off].set(srow)
-                        if quant else None
-                    )
-                # The kernel's causal bound is the FIRST query position's
-                # (offset t adds t in-kernel); T == 1 reduces to the
-                # classic decode bound.  Idle slots mask to 0.
-                valid = q_pos[:, 0] + 1
-                if slot_mask is not None:
-                    valid = jnp.where(slot_mask.astype(bool), valid, 0)
-                # Verify chunks (per-row decode_pos, small static T — the
-                # speculative path) keep the Pallas kernel; prefill
-                # chunks (scalar decode_pos, large T) stay on the
-                # gathered einsum, and so does a head width whose
-                # [k|v] lane group the chip's kernel cannot slice.
-                verify = (
-                    jnp.ndim(decode_pos) == 1 and 1 < T <= MAX_VERIFY_T
+                new_cache = pool_write(
+                    cache, k_w, v_w, scales, block_tables, q_pos, slot_mask
                 )
-                if (self.decode_attention == "fused" and not self.window
-                        and (T == 1 or verify) and paged_kernel_takes(Dh)):
-                    with jax.named_scope("attn.paged"):
-                        if self.decode_mesh is not None:
-                            # Tensor-parallel engines: the kernel runs per
-                            # shard under shard_map (q cut on heads, pool on
-                            # kv heads — the placement the serving plane
-                            # already installs); bit-identical to the
-                            # unsharded call, no collective added here.
-                            a = sharded_paged_decode_attention(
-                                q[:, 0] if T == 1 else q, kvc,
-                                block_tables, valid, sc_c,
-                                mesh=self.decode_mesh,
-                            )
-                        else:
-                            a = paged_decode_attention(
-                                q[:, 0] if T == 1 else q, kvc,
-                                block_tables, valid, sc_c,
-                            )
-                        if T == 1:
-                            a = a[:, None]
-                else:
-                    with jax.named_scope("attn.gathered"):
-                        # Gathered fallback (prefill chunks; einsum engines):
-                        # gather each row's blocks and view THEM kv-head
-                        # major — a transpose of one slot's context, never
-                        # of a pool — for the shared einsum path.
-                        MB = block_tables.shape[1]
-                        g = kvc[block_tables].reshape(
-                            B, MB * BL, KH, 2, Dh
-                        )
-                        kg = jnp.transpose(g[:, :, :, 0], (0, 2, 1, 3))
-                        vg = jnp.transpose(g[:, :, :, 1], (0, 2, 1, 3))
-                        ksg = vsg = None
-                        if quant:
-                            # (B, MB, KH, 2, BL) -> (B, KH, 2, MB * BL)
-                            sg = jnp.transpose(
-                                sc_c[block_tables], (0, 2, 3, 1, 4)
-                            ).reshape(B, KH, 2, MB * BL)
-                            ksg, vsg = sg[:, :, 0], sg[:, :, 1]
-                        a = _attend_kv_major(
-                            q, kg, vg, q_pos, self.window, ksg, vsg
-                        )
-                new_cache = (
-                    {"kv": kvc, "kv_scale": sc_c} if quant else {"kv": kvc}
-                )
-            elif kv_major:
-                # kv-head-major contiguous cache (B, KH, L, Dh) — the
-                # fused kernel's layout.  Single-token full-attention steps
-                # run the Pallas kernel; prefill chunks, window models and
-                # L > MAX_FUSED_LEN take the layout-matched einsum.
-                with jax.named_scope("kv_write"):
-                    k_t = jnp.swapaxes(k_w, 1, 2)  # (B, KH, T, Dh)
-                    v_t = jnp.swapaxes(v_w, 1, 2)
-                    if jnp.ndim(decode_pos) == 0:
-                        kc = lax.dynamic_update_slice(
-                            cache["k"], k_t, (0, 0, write_pos, 0)
-                        )
-                        vc = lax.dynamic_update_slice(
-                            cache["v"], v_t, (0, 0, write_pos, 0)
-                        )
-                        if quant:
-                            ks_c = lax.dynamic_update_slice(
-                                cache["k_scale"],
-                                jnp.swapaxes(k_scale, 1, 2), (0, 0, write_pos),
-                            )
-                            vs_c = lax.dynamic_update_slice(
-                                cache["v_scale"],
-                                jnp.swapaxes(v_scale, 1, 2), (0, 0, write_pos),
-                            )
-                    else:
-                        rows = jnp.arange(B)[:, None]
-                        cols = write_pos[:, None] + jnp.arange(T)[None]
-                        # Advanced indices (rows, cols) straddling the KH
-                        # slice land the broadcast axes up front: the indexed
-                        # view is (B, T, KH, ...), exactly k_w's layout.
-                        kc = cache["k"].at[rows, :, cols].set(k_w)
-                        vc = cache["v"].at[rows, :, cols].set(v_w)
-                        if quant:
-                            ks_c = cache["k_scale"].at[rows, :, cols].set(
-                                k_scale
-                            )
-                            vs_c = cache["v_scale"].at[rows, :, cols].set(
-                                v_scale
-                            )
-                if (T == 1 and not self.window
-                        and cache["k"].shape[2] <= MAX_FUSED_LEN):
-                    with jax.named_scope("attn.fused"):
-                        if self.decode_mesh is not None:
-                            a = sharded_fused_decode_attention(
-                                q[:, 0], kc, vc, q_pos[:, 0] + 1,
-                                k_scale=ks_c if quant else None,
-                                v_scale=vs_c if quant else None,
-                                mesh=self.decode_mesh,
-                            )[:, None]
-                        else:
-                            a = fused_decode_attention(
-                                q[:, 0], kc, vc, q_pos[:, 0] + 1,
-                                k_scale=ks_c if quant else None,
-                                v_scale=vs_c if quant else None,
-                            )[:, None]
-                else:
-                    with jax.named_scope("attn.kv_major_einsum"):
-                        a = _attend_kv_major(
-                            q, kc, vc, q_pos, self.window,
-                            ks_c if quant else None,
-                            vs_c if quant else None,
-                        )
-                new_cache = (
-                    {"k": kc, "v": vc, "k_scale": ks_c, "v_scale": vs_c}
-                    if quant else {"k": kc, "v": vc}
+                a = paged_attend(
+                    q, new_cache, block_tables, decode_pos, q_pos,
+                    slot_mask, kernel=paged_kernel, window=self.window,
+                    mesh=self.decode_mesh,
                 )
             else:
                 with jax.named_scope("kv_write"):
+                    if quant:
+                        k_scale, v_scale = scales
+                    else:
+                        # Float cache: cast to the cache's storage dtype
+                        # (kv_dtype may differ from the compute dtype —
+                        # e.g. store bf16 under fp32 compute).
+                        kvd = cache["k"].dtype
+                        k_w, v_w = k.astype(kvd), v.astype(kvd)
+                    write_pos = (
+                        decode_pos % self.window if rolling else decode_pos
+                    )
                     if jnp.ndim(decode_pos) == 0:
                         kc = lax.dynamic_update_slice(
                             cache["k"], k_w, (0, write_pos, 0, 0)
@@ -754,21 +559,19 @@ class TransformerLM(nn.Module):
     #: out-of-window blocks — O(T·window)) AND in KV-cache decode (same
     #: mask, so generation bit-matches training semantics).
     window: int = 0
-    #: decode-path attention impl.  "einsum" (default): the original XLA
-    #: path over the (B, L, KH, Dh) cache — unchanged semantics.  "fused":
-    #: ``init_cache`` lays the cache out kv-head major (B, KH, L, Dh) and
-    #: every single-token decode step runs the Pallas kernel
-    #: (:func:`~chainermn_tpu.ops.fused_decode_attention`) — each K/V byte
-    #: streams through VMEM once at storage width instead of the einsum's
-    #: two fp32 passes; prefill chunks, sliding-window models and caches
-    #: past ``ops.MAX_FUSED_LEN`` fall back to a layout-matched einsum.
-    #: Composes with ``n_kv_heads`` (GQA) and ``kv_dtype=jnp.int8``;
-    #: ``rolling`` streaming decode requires "einsum".  Training paths are
-    #: untouched either way.
+    #: whether PAGED decode steps (the serving engine's, ``block_tables``
+    #: given) may run the Pallas kernel
+    #: (:func:`~chainermn_tpu.ops.paged_decode_attention`).  "fused": yes,
+    #: wherever it applies — single-token steps and speculative verify
+    #: chunks of a full-attention model; prefill chunks and window models
+    #: take the gathered read.  "einsum" (default): the gathered read
+    #: everywhere — the reference path tests compare the kernel with.
+    #: Nothing else reads it: the contiguous cache (``init_cache``,
+    #: ``lm_generate``, ``rolling``) and training are the same under both.
     decode_attention: str = "einsum"
     #: tensor-parallel serving mesh (``jax.sharding.Mesh``, 1-D) or None.
     #: Set by ``serving.sharding.attach_decode_mesh`` on mesh-sharded
-    #: engines: "fused" decode steps then run the Pallas kernels per
+    #: engines: "fused" decode steps then run the Pallas kernel per
     #: shard under ``shard_map`` (KV-head cut, no new collectives)
     #: instead of the gathered einsum.  Threads straight through to
     #: :class:`_DecoderBlock`; single-device use leaves it ``None``.
@@ -920,23 +723,12 @@ class TransformerLM(nn.Module):
         batches fit in HBM).  With ``kv_dtype=jnp.int8`` the entries are
         int8 plus per-(token, kv-head) fp32 ``{"k_scale","v_scale"}`` of
         shape ``(batch, max_len, kv_heads)`` — half the bf16 bytes (the
-        scale adds 2/head_dim fp32 words per row).
-
-        Under ``decode_attention="fused"`` the layout is kv-head major —
-        ``{"k","v"}`` of ``(batch, kv_heads, max_len, head_dim)`` and
-        scales ``(batch, kv_heads, max_len)`` — so each fused-kernel grid
-        program reads a contiguous ``(L, head_dim)`` panel."""
-        if self.decode_attention not in ("einsum", "fused"):
-            raise ValueError(
-                f"decode_attention={self.decode_attention!r}: expected "
-                "'einsum' or 'fused'"
-            )
+        scale adds 2/head_dim fp32 words per row).  The one contiguous
+        layout, whatever ``decode_attention`` says."""
+        _wants_paged_kernel(self.decode_attention)  # refuse a bad string
         L = max_len or self.max_len
         kvh = self.n_kv_heads or self.n_heads
-        if self.decode_attention == "fused":
-            shape = (batch, kvh, L, self.d_model // self.n_heads)
-        else:
-            shape = (batch, L, kvh, self.d_model // self.n_heads)
+        shape = (batch, L, kvh, self.d_model // self.n_heads)
         kvd = self.kv_dtype if self.kv_dtype is not None else self.dtype
         if jnp.dtype(kvd) == jnp.int8:
             return [
@@ -1029,12 +821,6 @@ def lm_generate(
         if not model.window:
             raise ValueError(
                 "rolling=True needs a sliding-window model (window > 0)"
-            )
-        if model.decode_attention == "fused":
-            # The ring-collapse below and the block's slot arithmetic are
-            # legacy-layout only.
-            raise ValueError(
-                "rolling=True requires decode_attention='einsum'"
             )
         if prompt_lengths is not None:
             raise ValueError(

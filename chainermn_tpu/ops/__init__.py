@@ -9,12 +9,9 @@ code in interpret mode).
 
 from chainermn_tpu.ops.chunked_ce import chunked_softmax_cross_entropy
 from chainermn_tpu.ops.decode_attention import (
-    MAX_FUSED_LEN,
     MAX_VERIFY_T,
-    fused_decode_attention,
     paged_decode_attention,
     paged_kernel_takes,
-    sharded_fused_decode_attention,
     sharded_paged_decode_attention,
 )
 from chainermn_tpu.ops.rope import apply_rope
@@ -41,12 +38,9 @@ __all__ = [
     "FLASH_MIN_SEQ",
     "FLASH_MIN_SEQ_NONCAUSAL",
     "max_pool_fused",
-    "fused_decode_attention",
     "paged_decode_attention",
     "paged_kernel_takes",
-    "sharded_fused_decode_attention",
     "sharded_paged_decode_attention",
-    "MAX_FUSED_LEN",
     "MAX_VERIFY_T",
     "chunked_softmax_cross_entropy",
     "apply_rope",

@@ -1,52 +1,42 @@
-"""Fused single-position decode attention — Pallas TPU kernel.
+"""The paged KV pool: its row format, the write, the two reads.
 
-The KV-cache generation step is bandwidth-bound: each token reads the
-whole cache for a (B, heads) set of matvecs (measured:
-``result/decode_tpu_b64.json`` vs ``decode_tpu_gqa.json`` — throughput
-follows cache bytes, 3.54× from GQA's shrink alone).  The XLA einsum
-path (`models/transformer.py` `_DecoderBlock` decode branch) converts
-the cache to fp32 for the score/value einsums and makes two passes; this
-kernel streams each K/V byte through VMEM ONCE at its storage width
-(bf16, or int8 with the per-(position, kv-head) scales dequantized
-in-register) and fuses score → mask → softmax → value-weighting in one
-program.
+The serving engine's cache (``chainermn_tpu/serving``) lives in a fixed
+device-resident **block pool**, one array per layer, and this module is
+the one place that knows what a pool row looks like:
 
-Layout: the fused path expects the cache **(B, KH, L, Dh)** (kv-head
-major) so each grid program ``(b, kh)`` reads a contiguous ``(L, Dh)``
-panel.  WIRED into :class:`TransformerLM` via the
-``decode_attention="fused"`` knob: ``init_cache`` then lays the cache
-out kv-head major and the decode branch dispatches every single-token
-step (``T == 1``, full attention, ``L <= MAX_FUSED_LEN``) to
-:func:`fused_decode_attention`, falling back to the layout-matched
-einsum path for prefill chunks, sliding-window models, and lengths past
-the VMEM budget (``models/transformer.py`` ``_DecoderBlock._attend_kv_major``).
-Grid ``(B, KH)``; each program stages its panel in VMEM (L·Dh·itemsize —
-~1 MB at L=4096, Dh=128 bf16), computes the G=H/KH query heads' scores
-against it, masks positions ``>= valid_len`` (causality at decode = a
-length bound), and writes the (G, Dh) output block.  One-shot softmax —
-no online recurrence needed since L fits VMEM for every decode-practical
-length; lengths beyond the VMEM budget fall back to the einsum path
-upstream.
+* :func:`pool_shapes` — the shapes :mod:`~chainermn_tpu.serving.kv_pool`
+  allocates;
+* :func:`pool_write` — pack a chunk's tokens into rows and scatter them
+  through the block tables (masked slots park on block 0);
+* :func:`paged_decode_attention` — the Pallas kernel that walks a slot's
+  table over the pool in HBM (below), and its ``shard_map`` twin;
+* :func:`pool_context_attend` — the gathered read: a slot's table width
+  viewed kv-head major and attended in float32 (prefill chunks, and the
+  reference path the tests compare the kernel with);
+* :func:`paged_attend` — the one place that chooses between the two reads.
 
-:func:`paged_decode_attention` is the continuous-batching twin
-(``chainermn_tpu/serving``): the cache lives in a fixed device-resident
-**block pool**, one array per layer, token-major and lane-dense —
-``(num_blocks, block_len, KH * 2 * Dh)`` with each head's key and value
-side by side in one lane group ``[k_h | v_h]`` (why that layout and no
-other: :mod:`chainermn_tpu.serving.kv_pool`) — and each slot owns a
+The model's block (``models/transformer.py`` ``_DecoderBlock``) hands
+over q, k, v, the tables and the positions and knows none of this.
+
+:func:`paged_decode_attention` reads a pool that is token-major and
+lane-dense — ``(num_blocks, block_len, KH * 2 * Dh)`` with each head's
+key and value side by side in one lane group ``[k_h | v_h]`` (why that
+layout and no other: :mod:`chainermn_tpu.serving.kv_pool`), an int8 pool
+with its scale plane ``(num_blocks, KH, 2, block_len)`` beside it, and
+physical block 0 the parking block no table maps — and each slot owns a
 block table mapping logical cache blocks to physical pool blocks
 (vLLM/PagedAttention, Kwon et al. 2023).  Grid ``(S,)``, one step a
 slot, with the block tables and each slot's count of resident blocks
 scalar-prefetched: the pool stays in HBM and the step loops over the
 slot's OWN blocks, DMAing the whole contiguous row
 ``pool[table[s, i]]`` — every KV head of the block, 102 KB at GPT-2 XL's
-25 heads of 64 in bf16 — into a double buffer while the row before it
-is consumed.  The kernel walks the table directly, no gathered
+25 heads of 64 in bf16 — into one of ``_ROWS_IN_VMEM`` buffers while
+the rows before it are consumed.  The kernel walks the table directly, no gathered
 contiguous copy is ever materialized, and a table entry past a slot's
 length costs neither a DMA nor a loop iteration, an idle slot nothing
 but its zeros.  Blocks accumulate through the online-softmax recurrence
-(running max / normalizer / fp32 accumulator in VMEM scratch), so there
-is no ``MAX_FUSED_LEN`` cap: VMEM holds two rows at a time.  How a
+(running max / normalizer / fp32 accumulator in VMEM scratch), so no
+context length is too long for VMEM: it holds a few rows at a time.  How a
 row's heads are handled follows from the static shapes: one query head
 a KV head (MHA) as lane-dense VPU arithmetic over the whole row — the
 slot's queries laid along the row's own lanes, one cross-lane sum a
@@ -60,27 +50,26 @@ attends positions ``< valid_len + t`` — per-position causality inside
 the verify chunk, one kernel launch for all ``k + 1`` positions
 (``T <= MAX_VERIFY_T``; ``T == 1`` is bit-identical to the 3-D call).
 On a chip a head's lane group ``2 * Dh`` has to be a multiple of 128
-(:func:`paged_kernel_takes`); any other head width takes the model's
-gathered einsum path.
+(:func:`paged_kernel_takes`); any other head width takes the gathered
+read.
 
 No reference counterpart (the reference has no incremental-decode stack;
 SURVEY §2.9's examples are training-side) — this extends the repo's
 Pallas hot-op family (``ops/flash_attention.py``) to the inference loop.
 On non-TPU backends the kernel runs in Pallas interpret mode;
 ``tests/ops_tests/test_decode_attention.py`` pins its numerics against
-an einsum oracle (MHA/GQA, ragged ``valid_len``, int8 cache + scales).
+an einsum oracle (MHA/GQA, ragged ``valid_len``, int8 pool + scales),
+and the write and the gathered read against plain ``numpy``.
 
-**Tensor-parallel (shard_map) entry points**: the Pallas kernels carry
+**Tensor-parallel (shard_map) entry point**: a Pallas kernel carries
 no GSPMD partitioning rule, so a mesh-sharded caller cannot simply let
 the partitioner propagate through ``pallas_call``.
-:func:`sharded_paged_decode_attention` and
-:func:`sharded_fused_decode_attention` close the gap by running the
+:func:`sharded_paged_decode_attention` closes the gap by running the
 kernel **per shard** under ``jax.shard_map`` over a 1-D mesh: queries
-shard on the query-head axis, contiguous caches on their KV-head axis,
-paged pools on their LAST axis (whole ``[k_h | v_h]`` lane groups, so a
-cut on KV heads is a plain block cut), block tables / lengths ride
-replicated, and each shard runs the unmodified kernel over its local
-``KH / n`` heads.
+shard on the query-head axis, the pool on its LAST axis (whole
+``[k_h | v_h]`` lane groups, so a cut on KV heads is a plain block cut),
+block tables / lengths ride replicated, and each shard runs the
+unmodified kernel over its local ``KH / n`` heads.
 Attention is embarrassingly parallel across KV heads, so the sharded
 output is bit-identical to the unsharded kernel's — no collective is
 introduced; the row-parallel output projection's existing ``psum``
@@ -101,113 +90,83 @@ from jax.experimental.pallas import tpu as pltpu
 
 from chainermn_tpu.ops.flash_attention import NEG_INF, _use_interpret
 
-#: stage-whole-panel VMEM budget: k + v panels at Dh=128 bf16 hit ~4 MB
-#: at this L; callers fall back to the einsum path past it.
-MAX_FUSED_LEN = 16384
-
 #: query-position cap for :func:`paged_decode_attention`'s multi-query
 #: (speculative-verify) mode: T query offsets multiply the per-program
 #: row count (T·G rows vs G), so unbounded T would blow the scratch
-#: budget — and verify chunks are k+1 ≤ a handful anyway.  The model's
-#: paged decode branch falls back to the gathered einsum past it.
+#: budget — and verify chunks are k+1 ≤ a handful anyway.
+#: :func:`paged_attend` takes the gathered read past it.
 MAX_VERIFY_T = 16
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, *rest, scale, quant):
-    """One (batch row, kv head): q (1,1,G,Dh) vs the (1,1,L,Dh) panel."""
-    if quant:
-        ks_ref, vs_ref, o_ref = rest
-    else:
-        (o_ref,) = rest
-    G = q_ref.shape[2]
-    L = k_ref.shape[2]
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, Dh)
-    k = k_ref[0, 0].astype(jnp.float32)          # (L, Dh) — int8 or float
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (G, L)
-    if quant:
-        # Per-position k scale commutes out of the Dh contraction; v scale
-        # folds into the probability operand below.
-        s = s * ks_ref[0, 0, :, 0][None, :]
-    valid = len_ref[0, 0, 0, 0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (G, L), 1)
-    s = jnp.where(pos < valid, s, NEG_INF)
-    m = jnp.max(s, axis=1)
-    p = jnp.exp(s - m[:, None])
-    l = jnp.sum(p, axis=1)
-    if quant:
-        p = p * vs_ref[0, 0, :, 0][None, :]
-    o = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    o_ref[0, 0] = (o / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+def pool_shapes(num_blocks: int, block_len: int, kv_heads: int,
+                head_dim: int):
+    """``(row_shape, scale_shape)`` of one layer's pool: the ``"kv"`` array
+    ``(num_blocks, block_len, KH * 2 * Dh)`` — a token's row is
+    ``[k_0 | v_0 | k_1 | v_1 | ...]`` — and, for an int8 pool, the fp32
+    ``"kv_scale"`` plane ``(num_blocks, KH, 2, block_len)`` that pairs a
+    head's key and value scale the same way, positions minor-most."""
+    return ((num_blocks, block_len, kv_heads * 2 * head_dim),
+            (num_blocks, kv_heads, 2, block_len))
 
 
-def fused_decode_attention(
-    q: jax.Array,
-    kc: jax.Array,
-    vc: jax.Array,
-    valid_len: jax.Array,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Single-position attention against a kv-head-major cache.
+def pool_write(cache, k, v, scales, block_tables, q_pos, slot_mask=None):
+    """Write a chunk's keys and values into the pool; returns the new
+    cache entry (same keys as ``cache``).
+
+    Each row's positions map through its block table to physical pool
+    blocks; ONE scatter of whole token rows ``[k_0|v_0|k_1|v_1|...]`` into
+    the token-major pool (serving/kv_pool.py says why that layout) — the
+    same statement for decode (T = 1), verify and prefill chunks.  Masked
+    (idle) slots redirect to the reserved parking block 0 and write back
+    their own current value — duplicate indices then carry duplicate
+    VALUES, keeping the scatter deterministic.
 
     Args:
-      q: ``(B, H, Dh)`` — the current position's queries.
-      kc/vc: ``(B, KH, L, Dh)`` cache panels (float, or int8 with scales).
-      valid_len: ``(B,)`` int32 — positions ``< valid_len[b]`` are
-        attendable (the decode-time causal bound, ragged rows included).
-      k_scale/v_scale: ``(B, KH, L)`` fp32 — required iff the cache is
-        int8 (symmetric-absmax dequantization, folded into the einsums).
-
-    Returns ``(B, H, Dh)`` in ``q``'s dtype.
+      cache: ``{"kv": pool}``, an int8 pool with ``"kv_scale"`` beside it
+        (:func:`pool_shapes`).
+      k, v: ``(B, T, KH, Dh)`` — floats, stored at the pool's dtype, or
+        already int8 with ``scales``.
+      scales: ``None``, or the int8 values' ``(k_scale, v_scale)``, each
+        ``(B, T, KH)`` fp32 — given iff the cache has a scale plane.
+      block_tables: ``(B, max_blocks)`` int32.
+      q_pos: ``(B, T)`` int32 — the position each token is written at.
+      slot_mask: ``(B,)`` bool or ``None`` — rows that write nothing.
     """
-    B, H, Dh = q.shape
-    _, KH, L, _ = kc.shape
-    if H % KH:
-        raise ValueError(f"H ({H}) must be a multiple of KH ({KH})")
-    G = H // KH
-    quant = kc.dtype == jnp.int8
-    if quant and (k_scale is None or v_scale is None):
-        raise ValueError("int8 cache needs k_scale and v_scale")
-    qg = q.reshape(B, KH, G, Dh)
-    lens = jnp.broadcast_to(
-        jnp.asarray(valid_len, jnp.int32).reshape(B, 1, 1, 1), (B, 1, 1, 1)
-    )
-    operands = [qg, kc, vc, lens]
-    in_specs = [
-        pl.BlockSpec((1, 1, G, Dh), lambda b, h: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, L, Dh), lambda b, h: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, L, Dh), lambda b, h: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, 1, 1), lambda b, h: (b, 0, 0, 0)),
-    ]
-    if quant:
-        operands += [
-            k_scale.reshape(B, KH, L, 1),
-            v_scale.reshape(B, KH, L, 1),
-        ]
-        in_specs += [
-            pl.BlockSpec((1, 1, L, 1), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, L, 1), lambda b, h: (b, h, 0, 0)),
-        ]
-    out = pl.pallas_call(
-        lambda *refs: _decode_kernel(
-            *refs, scale=1.0 / math.sqrt(Dh), quant=quant
-        ),
-        grid=(B, KH),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, Dh), lambda b, h: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, Dh), q.dtype),
-        interpret=_use_interpret(),
-        name="fused_decode",
-    )(*operands)
-    return out.reshape(B, H, Dh)
-
+    pool = cache["kv"]
+    quant = scales is not None
+    if quant != ("kv_scale" in cache):
+        raise ValueError(
+            "an int8 pool (a cache with 'kv_scale') is written with "
+            f"scales and no other: cache has {sorted(cache)}, scales "
+            f"{'given' if quant else 'None'}"
+        )
+    B, T, KH, Dh = k.shape
+    with jax.named_scope("kv_write"):
+        k, v = k.astype(pool.dtype), v.astype(pool.dtype)
+        BL = pool.shape[1]
+        pb = jnp.take_along_axis(
+            block_tables, q_pos // BL, axis=1
+        )  # (B, T) physical block per written position
+        off = q_pos % BL
+        row = jnp.concatenate([k, v], axis=-1).reshape(B, T, KH * 2 * Dh)
+        if quant:
+            # (B, T, KH, 2): a head's k and v scale, as the kernel's
+            # (2, block_len) scale panel pairs them.
+            srow = jnp.stack(scales, axis=-1)
+        if slot_mask is not None:
+            live = slot_mask.astype(bool)[:, None]
+            pb = jnp.where(live, pb, 0)
+            off = jnp.where(live, off, 0)
+            row = jnp.where(live[..., None], row, pool[pb, off])
+            if quant:
+                srow = jnp.where(
+                    live[..., None, None], srow,
+                    cache["kv_scale"][pb, :, :, off],
+                )
+        new = {"kv": pool.at[pb, off].set(row)}
+        if quant:
+            new["kv_scale"] = cache["kv_scale"].at[pb, :, :, off].set(srow)
+    return new
 
 def paged_kernel_takes(head_dim: int) -> bool:
     """Whether :func:`paged_decode_attention` can read a pool of this
@@ -429,8 +388,8 @@ def paged_decode_attention(
     ``pool[block_tables[s, i]]`` — every KV head of the block in one
     contiguous read — ``_ROWS_IN_VMEM - 1`` blocks ahead of the one it
     folds through the online-softmax recurrence.  No contiguous per-slot
-    cache copy is ever materialized, there is no ``MAX_FUSED_LEN`` cap, and
-    a table entry past the slot's last resident block costs neither a DMA
+    cache copy is ever materialized, VMEM bounds no context length, and a
+    table entry past the slot's last resident block costs neither a DMA
     nor a loop iteration.
 
     How the heads of a row are handled follows from the static shapes
@@ -445,7 +404,7 @@ def paged_decode_attention(
         query offset ``t`` of slot ``s`` attends positions
         ``< valid_len[s] + t`` (per-position causality inside the chunk;
         the chunk's K/V must already be written to the pool).  ``T`` is
-        static and small (``<= MAX_VERIFY_T`` by the model's dispatch).
+        static and small (``<= MAX_VERIFY_T`` by :func:`paged_attend`).
       kv_pool: ``(num_blocks, block_len, KH * 2 * Dh)`` — the physical
         pool (float, or int8 with ``kv_scale``); lanes
         ``[h*2*Dh, h*2*Dh + Dh)`` of a row are head ``h``'s key, the next
@@ -463,8 +422,8 @@ def paged_decode_attention(
         discarded).
       kv_scale: ``(num_blocks, KH, 2, block_len)`` fp32 — required iff the
         pool is int8: row 0 of a head's pair is the per-position key
-        scale, row 1 the value scale (same symmetric-absmax convention as
-        :func:`fused_decode_attention`).
+        scale, row 1 the value scale (symmetric absmax: a stored value
+        times its position's scale is the float it stood for).
 
     Returns ``(S, H, Dh)`` or ``(S, T, H, Dh)`` (matching ``q``) in
     ``q``'s dtype.
@@ -575,19 +534,18 @@ def paged_decode_attention(
 
 
 # ---------------------------------------------------------------------------
-# Tensor-parallel (shard_map) entry points
+# Tensor-parallel (shard_map) entry point
 # ---------------------------------------------------------------------------
 #
-# Both kernels are embarrassingly parallel across KV heads: what they
-# compute for kv head ``kh`` touches only that head's panel (the fused
-# kernel) or lane group of a pool row (the paged one) and query group
-# ``kh`` of q.  A 1-D mesh cut on the KV-head axis therefore needs
-# NO collective — each shard runs the unmodified kernel over its
+# The kernel is embarrassingly parallel across KV heads: what it computes
+# for kv head ``kh`` touches only that head's lane group of a pool row and
+# query group ``kh`` of q.  A 1-D mesh cut on the KV-head axis therefore
+# needs NO collective — each shard runs the unmodified kernel over its
 # ``KH / n`` local heads and the per-shard outputs concatenate on the
 # (query-)head axis, which is exactly the Megatron column cut the
 # serving plane's attention projections already use
-# (``serving/sharding.py — param_spec``).  The wrappers below only
-# declare that cut to ``shard_map``; the kernel body is reused verbatim.
+# (``serving/sharding.py — param_spec``).  The wrapper below only
+# declares that cut to ``shard_map``; the kernel body is reused verbatim.
 
 
 def _mesh_axis(mesh, axis: Optional[str]) -> str:
@@ -658,65 +616,104 @@ def sharded_paged_decode_attention(
     return sm(*operands)
 
 
-def sharded_fused_decode_attention(
-    q: jax.Array,
-    kc: jax.Array,
-    vc: jax.Array,
-    valid_len: jax.Array,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-    *,
-    mesh,
-    axis: Optional[str] = None,
-) -> jax.Array:
-    """:func:`fused_decode_attention` under ``shard_map`` on a 1-D mesh.
+# ---------------------------------------------------------------------------
+# The gathered read, and the choice between it and the kernel
+# ---------------------------------------------------------------------------
 
-    The contiguous kv-major cache ``(B, KH, L, Dh)`` shards on its
-    KV-head axis 1, queries on the head axis, lengths replicated — the
-    same head cut as :func:`sharded_paged_decode_attention`, applied to
-    the single-sequence (non-paged) decode cache.
+
+def pool_context_attend(q, cache, block_tables, q_pos, window=0):
+    """Grouped-query attention of a ``(B, T, H, Dh)`` query chunk over each
+    row's whole table width, gathered: gather each row's blocks and view
+    THEM kv-head major ``(B, KH, L, Dh)`` — a transpose of one slot's
+    context, never of a pool — for float32 einsums.  Query ``t`` of row
+    ``b`` attends positions ``<= q_pos[b, t]`` (the last ``window`` of
+    them, if given): the mask of the model's contiguous ``(B, L, KH, Dh)``
+    einsum path exactly, only the context's axis order differs.  An int8
+    pool's per-(kv-head, position) scales fold into the scores (k) and the
+    probabilities (v).  ``cache`` is the entry :func:`pool_write`
+    returned: the chunk's own tokens are in it."""
+    pool = cache["kv"]
+    B, T, H, Dh = q.shape
+    BL = pool.shape[1]
+    KH = pool.shape[2] // (2 * Dh)
+    with jax.named_scope("attn.gathered"):
+        MB = block_tables.shape[1]
+        g = pool[block_tables].reshape(B, MB * BL, KH, 2, Dh)
+        kc = jnp.transpose(g[:, :, :, 0], (0, 2, 1, 3))
+        vc = jnp.transpose(g[:, :, :, 1], (0, 2, 1, 3))
+        ks_c = vs_c = None
+        if "kv_scale" in cache:
+            # (B, MB, KH, 2, BL) -> (B, KH, 2, MB * BL)
+            sg = jnp.transpose(
+                cache["kv_scale"][block_tables], (0, 2, 3, 1, 4)
+            ).reshape(B, KH, 2, MB * BL)
+            ks_c, vs_c = sg[:, :, 0], sg[:, :, 1]
+        qg = q.reshape(B, T, KH, H // KH, Dh)
+        s = jnp.einsum(
+            "btkgd,bkld->bkgtl", qg.astype(jnp.float32),
+            kc.astype(jnp.float32),
+        ) / math.sqrt(Dh)
+        if ks_c is not None:
+            s = s * ks_c[:, :, None, None, :]
+        t_idx = jnp.arange(kc.shape[2])
+        visible = (
+            t_idx[None, None, None, None, :]
+            <= q_pos[:, None, None, :, None]
+        )
+        if window:
+            visible &= (
+                t_idx[None, None, None, None, :]
+                > q_pos[:, None, None, :, None] - window
+            )
+        s = jnp.where(visible, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        if vs_c is not None:
+            p = p * vs_c[:, :, None, None, :]
+        a = jnp.einsum("bkgtl,bkld->btkgd", p, vc.astype(jnp.float32))
+        return a.reshape(B, T, H, Dh).astype(q.dtype)
+
+
+def paged_attend(q, cache, block_tables, decode_pos, q_pos, slot_mask=None,
+                 *, kernel, window=0, mesh=None):
+    """A query chunk's attention over the pool — the one place that
+    chooses between the Pallas kernel (scope ``attn.paged``) and the
+    gathered read (``attn.gathered``), from what it can observe.
+
+    The kernel takes single-token steps (``T == 1``) and verify chunks —
+    per-row positions, ``1 < T <= MAX_VERIFY_T``: the speculative path —
+    of a full-attention model whose head width it can slice
+    (:func:`paged_kernel_takes`), where the caller allows it
+    (``kernel``).  Prefill chunks (one scalar position for every row,
+    large ``T``), window models and every ``kernel=False`` caller — the
+    reference path — take :func:`pool_context_attend`.
+
+    ``q`` is ``(B, T, H, Dh)``; ``cache``, ``block_tables``, ``q_pos`` and
+    ``slot_mask`` are :func:`pool_write`'s (its result, for ``cache``).
+    ``decode_pos`` is the chunk's first position as the model was given
+    it, a scalar or ``(B,)`` per row — only its rank is read, ``q_pos`` is
+    it spread over the chunk.  ``mesh``: a 1-D serving mesh, the kernel
+    then runs per shard (:func:`sharded_paged_decode_attention`).
     """
-    axis = _mesh_axis(mesh, axis)
-    n = int(mesh.shape[axis])
-    if n == 1:
-        return fused_decode_attention(q, kc, vc, valid_len, k_scale, v_scale)
-    KH = kc.shape[1]
-    if KH % n:
-        raise ValueError(
-            f"KV heads ({KH}, cache axis 1) are not divisible by mesh "
-            f"axis '{axis}' ({n}); the per-shard fused kernel needs a "
-            f"whole number of local KV heads"
-        )
-    q_spec = jax.sharding.PartitionSpec(None, axis, None)
-    cache_spec = jax.sharding.PartitionSpec(None, axis, None, None)
-    scale_spec = jax.sharding.PartitionSpec(None, axis, None)
-    rep1 = jax.sharding.PartitionSpec(None)
-    quant = kc.dtype == jnp.int8
-    if quant:
-        if k_scale is None or v_scale is None:
-            raise ValueError("int8 cache needs k_scale and v_scale")
-
-        def body(q, kc, vc, lens, ks, vs):
-            return fused_decode_attention(q, kc, vc, lens, ks, vs)
-
-        sm = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(q_spec, cache_spec, cache_spec, rep1,
-                      scale_spec, scale_spec),
-            out_specs=q_spec,
-            check_vma=False,
-        )
-        return sm(q, kc, vc, valid_len, k_scale, v_scale)
-
-    def body(q, kc, vc, lens):
-        return fused_decode_attention(q, kc, vc, lens)
-
-    sm = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(q_spec, cache_spec, cache_spec, rep1),
-        out_specs=q_spec,
-        check_vma=False,
-    )
-    return sm(q, kc, vc, valid_len)
+    T, Dh = q.shape[1], q.shape[3]
+    # The kernel's causal bound is the FIRST query position's (offset t
+    # adds t in-kernel); T == 1 reduces to the classic decode bound.  Idle
+    # slots mask to 0.
+    valid = q_pos[:, 0] + 1
+    if slot_mask is not None:
+        valid = jnp.where(slot_mask.astype(bool), valid, 0)
+    verify = jnp.ndim(decode_pos) == 1 and 1 < T <= MAX_VERIFY_T
+    if not (kernel and not window and (T == 1 or verify)
+            and paged_kernel_takes(Dh)):
+        return pool_context_attend(q, cache, block_tables, q_pos, window)
+    with jax.named_scope("attn.paged"):
+        args = (q[:, 0] if T == 1 else q, cache["kv"], block_tables, valid,
+                cache.get("kv_scale"))
+        if mesh is not None:
+            # Tensor-parallel engines: the kernel runs per shard under
+            # shard_map (q cut on heads, pool on kv heads — the placement
+            # the serving plane already installs); bit-identical to the
+            # unsharded call, no collective added here.
+            a = sharded_paged_decode_attention(*args, mesh=mesh)
+        else:
+            a = paged_decode_attention(*args)
+        return a[:, None] if T == 1 else a

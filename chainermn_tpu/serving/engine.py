@@ -9,9 +9,10 @@ compiles **exactly once** (``tests/serving_tests/test_engine.py`` pins this
 with a compilation-count guard).  Idle slots ride along masked: their cache
 writes are parked on reserved block 0 and their sampled tokens discarded.
 
-One step = gather block tables → paged decode attention
-(:func:`~chainermn_tpu.ops.paged_decode_attention` under
-``decode_attention="fused"``, the gathered einsum fallback otherwise) →
+One step = gather block tables → the pool's write and read
+(:mod:`chainermn_tpu.ops.decode_attention`: the Pallas kernel
+:func:`~chainermn_tpu.ops.paged_decode_attention` where the model's
+``decode_attention="fused"`` allows it, the gathered read otherwise) →
 per-slot sampling (independent RNG lanes, per-slot temperature, engine-wide
 ``top_k``).
 
@@ -81,7 +82,8 @@ class DecodeEngine:
     Args:
       model: a :class:`~chainermn_tpu.models.TransformerLM`.  Works with
         either ``decode_attention`` setting — "fused" runs the paged Pallas
-        kernel in the hot loop, "einsum" the gathered fallback.
+        kernel in the hot loop, "einsum" the gathered read (the
+        reference path).
       params: the model's parameter pytree.
       capacity: decode slots per step (the fixed batch dimension).
       num_blocks: physical blocks in the pool (block 0 stays reserved).
@@ -112,8 +114,8 @@ class DecodeEngine:
         tables / allocator / prefix trie untouched (pure host
         bookkeeping over block ids), control vectors uploaded
         replicated.  Both decode paths work under a mesh:
-        ``decode_attention="fused"`` (the default fast path) runs the
-        Pallas kernels per shard under ``shard_map`` on the KV-head
+        ``decode_attention="fused"`` (the fast path) runs the paged
+        Pallas kernel per shard under ``shard_map`` on the KV-head
         cut — bit-identical to the unsharded kernel, no new
         collectives — while ``"einsum"`` remains the gathered GSPMD
         fallback.  The geometry must divide the mesh on the KV-head

@@ -4,7 +4,10 @@ The static-batch decode path (:func:`~chainermn_tpu.models.lm_generate`)
 sizes one contiguous ``(B, L, ...)`` cache to the LONGEST request and holds
 it for the whole batch — memory proportional to ``B · max_len`` even when
 most rows finished long ago.  The serving engine instead draws from one
-physical **block pool** per layer, token-major and lane-dense:
+physical **block pool** per layer, token-major and lane-dense (the
+shapes, the write and both reads are
+:mod:`chainermn_tpu.ops.decode_attention`'s — ``pool_shapes`` there is
+what this module allocates):
 
     ``{"kv"}``:  ``(num_blocks, block_len, KH * 2 * Dh)``
     ``{"kv_scale"}`` (int8 pools): ``(num_blocks, KH, 2, block_len)`` fp32
@@ -63,11 +66,11 @@ prefix in many block tables (and stay pinned by the prefix trie after its
 requests retire), and it returns to the free list only when the last
 holder lets go.
 
-Physical block 0 is reserved as the **parking block**: the paged decode
-branch redirects idle slots' scatter writes there (with their own current
-value, so duplicate indices carry duplicate values and the scatter stays
-deterministic — ``models/transformer.py``).  The allocator never hands it
-out.
+Physical block 0 is reserved as the **parking block**: the pool's write
+redirects idle slots' scatter there (with their own current value, so
+duplicate indices carry duplicate values and the scatter stays
+deterministic — ``ops/decode_attention.py`` ``pool_write``).  The
+allocator never hands it out.
 """
 
 from __future__ import annotations
@@ -172,7 +175,8 @@ class PagedKVPool:
     :class:`BlockAllocator`.
 
     Built from the model's own geometry so the pool entries are exactly
-    what :meth:`TransformerLM.__call__`'s paged decode branch expects.
+    what :meth:`TransformerLM.__call__`'s paged decode branch hands
+    ``pool_write``.
     ``kv_dtype=jnp.int8`` models get int8 pools with fp32 scale planes —
     the same symmetric-absmax convention as the contiguous cache, at half
     the bf16 pool bytes.
@@ -191,23 +195,25 @@ class PagedKVPool:
                  placement=None):
         import jax.numpy as jnp
 
+        from chainermn_tpu.ops.decode_attention import pool_shapes
+
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1, got {block_len}")
         kvh = model.n_kv_heads or model.n_heads
         dh = model.d_model // model.n_heads
         kvd = model.kv_dtype if model.kv_dtype is not None else model.dtype
-        shape = (num_blocks, block_len, kvh * 2 * dh)
+        shape, scale_shape = pool_shapes(num_blocks, block_len, kvh, dh)
         self.block_len = block_len
         self.num_blocks = num_blocks
         self.allocator = BlockAllocator(num_blocks)
         if jnp.dtype(kvd) == jnp.int8:
             self.pools: List[Dict] = [
                 {"kv": jnp.zeros(shape, jnp.int8),
-                 "kv_scale": jnp.zeros(
-                     (num_blocks, kvh, 2, block_len), jnp.float32)}
+                 "kv_scale": jnp.zeros(scale_shape, jnp.float32)}
                 for _ in range(model.n_layers)
             ]
-            per_layer = 2 * kvh * block_len * (dh + 4)  # k+v int8 + scales
+            # a block's int8 rows and its fp32 scale panels
+            per_layer = math.prod(shape[1:]) + 4 * math.prod(scale_shape[1:])
         else:
             if not jnp.issubdtype(jnp.dtype(kvd), jnp.floating):
                 raise ValueError(
@@ -216,7 +222,7 @@ class PagedKVPool:
             self.pools = [
                 {"kv": jnp.zeros(shape, kvd)} for _ in range(model.n_layers)
             ]
-            per_layer = 2 * kvh * block_len * dh * jnp.dtype(kvd).itemsize
+            per_layer = math.prod(shape[1:]) * jnp.dtype(kvd).itemsize
         if placement is not None:
             self.pools = [
                 {n: placement(arr) for n, arr in layer.items()}

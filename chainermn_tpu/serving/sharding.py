@@ -41,9 +41,9 @@ decode step gathers one row per slot per token, and a sharded gather
 would turn that into a collective on the hot path for a table that is a
 rounding error next to the KV pool.
 
-The Pallas fused/paged decode kernels do not carry GSPMD partitioning
-rules, so GSPMD alone cannot propagate through ``pallas_call`` — instead
-a sharded ``decode_attention="fused"`` engine runs the kernels **per
+The Pallas paged decode kernel carries no GSPMD partitioning rule, so
+GSPMD alone cannot propagate through ``pallas_call`` — instead a
+sharded ``decode_attention="fused"`` engine runs the kernel **per
 shard under** ``shard_map`` (:func:`~chainermn_tpu.ops.
 sharded_paged_decode_attention`): queries cut on the head axis, pools on
 KV heads (the placement above), block tables replicated.
@@ -51,8 +51,10 @@ Attention never crosses KV heads, so the per-shard outputs are
 bit-identical to the unsharded kernel's and no new collective lands on
 the decode hot path — the row-parallel ``proj`` psum that already exists
 completes the reduction.  :func:`attach_decode_mesh` wires the mesh into
-the model's dispatch; ``decode_attention="einsum"`` remains an explicit
-fallback knob (the gathered path partitions cleanly under plain GSPMD).
+the model, which hands it to the pool's dispatch
+(``ops/decode_attention.py`` ``paged_attend``); ``decode_attention=
+"einsum"`` keeps every paged step on the gathered read, which partitions
+cleanly under plain GSPMD.
 """
 
 from __future__ import annotations
@@ -110,15 +112,15 @@ def validate_geometry(model, mesh) -> None:
     """Fail fast when ``model``'s geometry cannot split ``n`` ways.
 
     Only the KV-head axis is MANDATORY: :func:`pool_placement` shards
-    every pool on its KV heads and the per-shard Pallas kernels
-    (``decode_attention="fused"``) need a whole number of local KV
+    every pool on its KV heads and the per-shard Pallas kernel
+    (``decode_attention="fused"``) needs a whole number of local KV
     heads, so ``KH % n`` must hold (and with GQA, ``H = KH * groups``,
     so the query heads divide whenever KH does).  Any OTHER indivisible
     parameter axis (an odd vocab, a prime ``d_ff``) simply falls back to
     replication leaf-by-leaf in :func:`shard_params` — correct, just
     less parallel — rather than refusing the model.  Both decode paths
-    ("fused" shard_map kernels, "einsum" gathered fallback) are legal
-    under a mesh.
+    ("fused": the shard_map kernel; "einsum": the gathered read) are
+    legal under a mesh.
     """
     n = mesh_model_size(mesh)
     if n == 1:
@@ -137,7 +139,8 @@ def validate_geometry(model, mesh) -> None:
 def attach_decode_mesh(model, mesh):
     """Return ``model`` with the serving mesh wired into its decode
     dispatch (``decode_mesh`` static field), so ``decode_attention=
-    "fused"`` steps run the Pallas kernels per shard under ``shard_map``.
+    "fused"`` steps run the paged Pallas kernel per shard under
+    ``shard_map``.
 
     A no-op (the same model comes back) for size-1 meshes and for
     einsum engines — their decode path never consults the mesh.

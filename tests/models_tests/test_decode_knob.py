@@ -1,13 +1,12 @@
-"""``TransformerLM(decode_attention="fused")`` parity with the einsum path.
+"""``TransformerLM.decode_attention`` and the contiguous cache.
 
-The knob swaps the decode cache to the kv-head-major layout and routes
-single-token steps through the Pallas kernel
-(:func:`~chainermn_tpu.ops.fused_decode_attention`) — greedy generation
-must be TOKEN-identical to the default einsum cache path on every decode
-configuration the model supports: MHA and GQA, ragged right-padded
-prompts, the int8 quantized cache, and the sliding-window einsum
-fallback.  Any drift means the kernel wiring changed semantics, not just
-layout.
+The field decides one thing: whether PAGED decode steps (the serving
+engine's) may run the Pallas kernel.  The contiguous cache — ``init_cache``,
+``lm_generate``, the ``rolling`` ring — has one layout and one attention
+path and reads the field nowhere: same shapes, same tokens under both
+values.  (What the field does to a paged step:
+``tests/ops_tests/test_decode_attention.py::test_paged_attend_chooses_by_shape``
+and ``tests/serving_tests/test_engine.py::test_einsum_engine_same_tokens``.)
 """
 
 import jax
@@ -32,11 +31,8 @@ def prompt():
 
 
 def _pair(**over):
-    """(einsum model, fused model, shared params) for one config.
-
-    Params must come from the config's own einsum model — GQA/int8
-    variants change the parameter tree, and the knob itself must not
-    (same weights drive both paths)."""
+    """(einsum model, fused model, shared params) for one config: GQA
+    changes the parameter tree, the knob itself must not."""
     merged = {**KW, **over}
     m_e = TransformerLM(**merged)
     m_f = TransformerLM(decode_attention="fused", **merged)
@@ -46,47 +42,28 @@ def _pair(**over):
     return m_e, m_f, params
 
 
-@pytest.mark.parametrize(
-    "over",
-    [
-        {},                      # MHA, full attention -> fused kernel
-        {"n_kv_heads": 2},       # GQA grouped panel reads
-        {"kv_dtype": jnp.int8},  # quantized cache + scale planes
-        {"window": 8},           # sliding window -> einsum fallback branch
-    ],
-    ids=["mha", "gqa", "int8", "window"],
-)
-def test_fused_knob_greedy_token_identical(prompt, over):
-    m_e, m_f, params = _pair(**over)
-    t_e = np.asarray(lm_generate(m_e, params, prompt, 16))
-    t_f = np.asarray(lm_generate(m_f, params, prompt, 16))
-    np.testing.assert_array_equal(t_e, t_f)
-
-
-def test_fused_knob_ragged_prompts(prompt):
-    m_e, m_f, params = _pair(n_kv_heads=2)
+def test_contiguous_cache_ignores_the_knob(prompt):
+    m_e, m_f, params = _pair(n_kv_heads=2, kv_dtype=jnp.int8)
+    ce, cf = m_e.init_cache(batch=3, max_len=32), m_f.init_cache(3, 32)
+    assert ce[0]["k"].shape == (3, 32, 2, 16)   # (B, L, KH, Dh)
+    assert ce[0]["k_scale"].shape == (3, 32, 2)
+    assert (jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), ce)
+            == jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), cf))
     lens = jnp.asarray([5, 12, 9], jnp.int32)
-    t_e = np.asarray(
-        lm_generate(m_e, params, prompt, 12, prompt_lengths=lens)
-    )
-    t_f = np.asarray(
-        lm_generate(m_f, params, prompt, 12, prompt_lengths=lens)
-    )
-    np.testing.assert_array_equal(t_e, t_f)
+    for kw in ({}, {"prompt_lengths": lens}):
+        t_e = np.asarray(lm_generate(m_e, params, prompt, 12, **kw))
+        t_f = np.asarray(lm_generate(m_f, params, prompt, 12, **kw))
+        np.testing.assert_array_equal(t_e, t_f)
 
 
-def test_fused_cache_layout_is_kv_head_major():
-    m_e, m_f, _ = _pair(n_kv_heads=2)
-    ce = m_e.init_cache(batch=3, max_len=32)[0]
-    cf = m_f.init_cache(batch=3, max_len=32)[0]
-    assert ce["k"].shape == (3, 32, 2, 16)   # (B, L, KH, Dh)
-    assert cf["k"].shape == (3, 2, 32, 16)   # (B, KH, L, Dh)
-
-
-def test_rolling_requires_einsum(prompt):
-    _, m_f, params = _pair(window=8)
-    with pytest.raises(ValueError, match="rolling"):
-        lm_generate(m_f, params, prompt, 8, rolling=True)
+def test_rolling_decode_runs_under_either_value(prompt):
+    """The ring-buffer cache is the contiguous cache's: both values run
+    it, to the same tokens as each other and as the full cache."""
+    m_e, m_f, params = _pair(window=8)
+    full = np.asarray(lm_generate(m_e, params, prompt, 16))
+    for m in (m_e, m_f):
+        ring = np.asarray(lm_generate(m, params, prompt, 16, rolling=True))
+        np.testing.assert_array_equal(ring, full)
 
 
 def test_bad_knob_rejected():

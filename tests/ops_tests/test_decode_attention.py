@@ -1,10 +1,14 @@
-"""fused_decode_attention vs the einsum oracle (Pallas interpret on CPU).
+"""The paged KV pool (``ops/decode_attention.py``): the Pallas kernel
+against a float32 oracle (interpret mode on the CPU), and the pool row's
+write, gathered read and kernel choice against plain ``numpy``.
 
-The oracle is the math the TransformerLM decode branch runs — fp32
-score/softmax/value einsums with the length-bound mask — written directly
-over the kernel's kv-head-major (B, KH, L, Dh) layout.  Covers MHA, GQA
-grouping, ragged ``valid_len`` rows, the int8 cache with per-(position,
-kv-head) scales, and the argument-validation contract.
+The kernel's oracle gathers each slot's context through its block table
+and runs score / mask / softmax / value in float32: MHA, GQA grouping,
+ragged ``valid_len`` rows, verify chunks, the int8 pool with its scale
+plane, and the argument-validation contract.  The write
+(:func:`pool_write`), the gathered read (:func:`pool_context_attend`) and
+the choice (:func:`paged_attend`) are what the model's block calls for a
+paged cache; their tests need no model.
 """
 
 import numpy as np
@@ -13,129 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from chainermn_tpu.ops import fused_decode_attention
-
 pytestmark = pytest.mark.tier1  # small shapes; interpret mode is fast here
-
-
-def _oracle(q, kc, vc, valid_len, k_scale=None, v_scale=None):
-    """fp32 einsum reference over the kv-head-major cache layout."""
-    B, H, Dh = q.shape
-    _, KH, L, _ = kc.shape
-    G = H // KH
-    qg = np.asarray(q, np.float32).reshape(B, KH, G, Dh) / np.sqrt(Dh)
-    k = np.asarray(kc, np.float32)
-    v = np.asarray(vc, np.float32)
-    s = np.einsum("bhgd,bhld->bhgl", qg, k)
-    if k_scale is not None:
-        s = s * np.asarray(k_scale, np.float32)[:, :, None, :]
-    pos = np.arange(L)[None, None, None, :]
-    mask = pos < np.asarray(valid_len, np.int64)[:, None, None, None]
-    s = np.where(mask, s, -1e30)
-    m = s.max(axis=-1, keepdims=True)
-    p = np.exp(s - m)
-    l = p.sum(axis=-1)
-    if v_scale is not None:
-        p = p * np.asarray(v_scale, np.float32)[:, :, None, :]
-    o = np.einsum("bhgl,bhld->bhgd", p, v) / np.maximum(l, 1e-30)[..., None]
-    return o.reshape(B, H, Dh)
-
-
-def _setup(B=2, H=4, KH=4, L=32, Dh=8, seed=0):
-    rng = np.random.RandomState(seed)
-    q = rng.randn(B, H, Dh).astype(np.float32)
-    kc = rng.randn(B, KH, L, Dh).astype(np.float32)
-    vc = rng.randn(B, KH, L, Dh).astype(np.float32)
-    return q, kc, vc
-
-
-def test_mha_full_length_matches_oracle():
-    q, kc, vc = _setup()
-    valid = np.array([32, 32], np.int32)
-    got = fused_decode_attention(
-        jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(valid)
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), _oracle(q, kc, vc, valid), rtol=1e-5, atol=1e-5
-    )
-
-
-def test_gqa_grouping_matches_oracle():
-    q, kc, vc = _setup(B=2, H=8, KH=2, L=16, Dh=8, seed=1)
-    valid = np.array([16, 16], np.int32)
-    got = fused_decode_attention(
-        jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(valid)
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), _oracle(q, kc, vc, valid), rtol=1e-5, atol=1e-5
-    )
-
-
-def test_ragged_valid_len_masks_tail():
-    q, kc, vc = _setup(B=3, H=4, KH=4, L=24, Dh=8, seed=2)
-    valid = np.array([24, 7, 1], np.int32)
-    got = np.asarray(fused_decode_attention(
-        jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(valid)
-    ))
-    np.testing.assert_allclose(
-        got, _oracle(q, kc, vc, valid), rtol=1e-5, atol=1e-5
-    )
-    # The masked tail must be INERT: corrupting positions >= valid_len
-    # cannot change the output (the real ragged-row guarantee, not just
-    # agreement-on-this-sample).
-    kc2, vc2 = kc.copy(), vc.copy()
-    kc2[1, :, 7:, :] = 1e3
-    vc2[1, :, 7:, :] = -1e3
-    got2 = np.asarray(fused_decode_attention(
-        jnp.asarray(q), jnp.asarray(kc2), jnp.asarray(vc2),
-        jnp.asarray(valid)
-    ))
-    np.testing.assert_allclose(got2[1], got[1], rtol=1e-6, atol=1e-6)
-
-
-def test_int8_cache_matches_dequantized_oracle():
-    q, kc, vc = _setup(B=2, H=4, KH=2, L=16, Dh=8, seed=3)
-    q = q.astype(np.float32)
-    # Symmetric absmax per (b, kh, l) row — the kv-quant cache contract.
-    k_scale = (np.abs(kc).max(axis=-1) / 127.0 + 1e-8).astype(np.float32)
-    v_scale = (np.abs(vc).max(axis=-1) / 127.0 + 1e-8).astype(np.float32)
-    k8 = np.clip(np.round(kc / k_scale[..., None]), -127, 127)
-    v8 = np.clip(np.round(vc / v_scale[..., None]), -127, 127)
-    valid = np.array([16, 11], np.int32)
-    got = fused_decode_attention(
-        jnp.asarray(q), jnp.asarray(k8, np.int8), jnp.asarray(v8, np.int8),
-        jnp.asarray(valid), k_scale=jnp.asarray(k_scale),
-        v_scale=jnp.asarray(v_scale),
-    )
-    # Oracle over the int8 codes with the scales folded exactly where the
-    # kernel folds them (k scale on scores, v scale on probabilities).
-    want = _oracle(q, k8, v8, valid, k_scale=k_scale, v_scale=v_scale)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
-
-
-def test_output_dtype_follows_query():
-    q, kc, vc = _setup(B=1, H=2, KH=2, L=8, Dh=8, seed=4)
-    got = fused_decode_attention(
-        jnp.asarray(q, jnp.bfloat16), jnp.asarray(kc, jnp.bfloat16),
-        jnp.asarray(vc, jnp.bfloat16), jnp.asarray([8], jnp.int32)
-    )
-    assert got.dtype == jnp.bfloat16
-    assert got.shape == (1, 2, 8)
-
-
-def test_validation_errors():
-    q, kc, vc = _setup(B=1, H=3, KH=2, L=8, Dh=8, seed=5)
-    with pytest.raises(ValueError, match="multiple of KH"):
-        fused_decode_attention(
-            jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-            jnp.asarray([8], jnp.int32)
-        )
-    q, kc, vc = _setup(B=1, H=2, KH=2, L=8, Dh=8, seed=6)
-    with pytest.raises(ValueError, match="int8 cache needs"):
-        fused_decode_attention(
-            jnp.asarray(q), jnp.asarray(kc, jnp.int8),
-            jnp.asarray(vc, jnp.int8), jnp.asarray([8], jnp.int32)
-        )
 
 
 # ---------------------------------------------------------------- paged
@@ -297,7 +179,7 @@ def test_paged_int8_pool_matches_dequantized_oracle():
 def test_paged_pool_shape_is_checked():
     """A pool that is not (num_blocks, block_len, KH * 2 * Dh) for the
     query's head width is refused by name, as is an int8 pool without its
-    scale plane."""
+    scale plane — read or written."""
     from chainermn_tpu.ops import paged_decode_attention
 
     q = jnp.zeros((2, 4, 8), jnp.float32)
@@ -311,6 +193,18 @@ def test_paged_pool_shape_is_checked():
         paged_decode_attention(q, jnp.zeros((6, 4, 3 * 16)), tbl, valid)
     with pytest.raises(ValueError, match="int8 pool needs kv_scale"):
         paged_decode_attention(q, jnp.zeros((6, 4, 32), jnp.int8), tbl, valid)
+    # the write holds an int8 pool and its scales together the same way
+    from chainermn_tpu.ops.decode_attention import pool_write
+
+    kv = jnp.zeros((2, 1, 2, 8), jnp.int8)
+    pos = jnp.zeros((2, 1), jnp.int32)
+    with pytest.raises(ValueError, match="int8 pool"):
+        pool_write({"kv": jnp.zeros((6, 4, 32), jnp.int8),
+                    "kv_scale": jnp.zeros((6, 2, 2, 4))}, kv, kv, None, tbl,
+                   pos)
+    with pytest.raises(ValueError, match="int8 pool"):
+        pool_write({"kv": jnp.zeros((6, 4, 32))}, kv, kv,
+                   (jnp.ones((2, 1, 2)),) * 2, tbl, pos)
 
 
 #: slot lengths of one call (block_len 16, a table 4 wide), by name
@@ -449,24 +343,6 @@ def test_sharded_paged_single_query_is_multi_query_t1():
     assert (np.asarray(a) == np.asarray(b)).all()
 
 
-def test_sharded_fused_bit_identical_to_unsharded():
-    from chainermn_tpu.ops import (
-        fused_decode_attention,
-        sharded_fused_decode_attention,
-    )
-
-    mesh = _mesh2()
-    rng = np.random.RandomState(9)
-    B, H, KH, L, Dh = 3, 4, 2, 8, 8
-    q = jnp.asarray(rng.randn(B, H, Dh), jnp.float32)
-    kc = jnp.asarray(rng.randn(B, KH, L, Dh), jnp.float32)
-    vc = jnp.asarray(rng.randn(B, KH, L, Dh), jnp.float32)
-    valid = jnp.asarray([3, 8, 5], jnp.int32)
-    ref = fused_decode_attention(q, kc, vc, valid)
-    out = sharded_fused_decode_attention(q, kc, vc, valid, mesh=mesh)
-    assert (np.asarray(out) == np.asarray(ref)).all()
-
-
 def test_sharded_wrapper_validation():
     """Indivisible KV heads must fail up front, naming both axes; a
     size-1 mesh falls through to the plain kernel call."""
@@ -494,3 +370,214 @@ def test_sharded_wrapper_validation():
     out = sharded_paged_decode_attention(q, pool, tbl, valid,
                                          mesh=serving_mesh(1))
     assert (np.asarray(out) == np.asarray(ref)).all()
+
+
+# ------------------------------------------- the pool row: write and reads
+_POS = {  # name -> (B, T, the chunk's first position: per row, or a scalar)
+    "decode": (3, 1, [0, 9, 21]),
+    "verify_t4": (3, 4, [2, 7, 17]),
+    "prefill_c32": (1, 32, 5),
+}
+_KH, _DH, _PBL, _PMB = 2, 16, 8, 6
+
+
+def _positions(mode):
+    """``q_pos`` ``(B, T)`` as the model's block forms it."""
+    B, T, p0 = _POS[mode]
+    return np.broadcast_to(
+        np.asarray(p0).reshape(-1, 1) + np.arange(T)[None], (B, T))
+
+
+def _tables(B, rng):
+    """Block tables over disjoint physical blocks (block 0 is parking)."""
+    ids = 1 + rng.permutation(B * _PMB)
+    return ids.reshape(B, _PMB).astype(np.int32), B * _PMB + 1
+
+
+def _random_cache(NB, kind, rng):
+    """A pool with something in every row, so an untouched row shows."""
+    if kind == "int8":
+        return {
+            "kv": jnp.asarray(rng.randint(
+                -127, 128, size=(NB, _PBL, _KH * 2 * _DH)).astype(np.int8)),
+            "kv_scale": jnp.asarray(
+                (rng.rand(NB, _KH, 2, _PBL) + 0.5).astype(np.float32)),
+        }
+    return {"kv": jnp.asarray(rng.randn(NB, _PBL, _KH * 2 * _DH),
+                              jnp.bfloat16)}
+
+
+def _chunk(B, T, kind, rng):
+    """A chunk's k, v and scales as the block hands them to the write."""
+    if kind == "int8":
+        k = rng.randint(-127, 128, size=(B, T, _KH, _DH)).astype(np.int8)
+        v = rng.randint(-127, 128, size=(B, T, _KH, _DH)).astype(np.int8)
+        ks = (rng.rand(B, T, _KH) * 0.02 + 0.001).astype(np.float32)
+        vs = (rng.rand(B, T, _KH) * 0.02 + 0.001).astype(np.float32)
+        return k, v, (jnp.asarray(ks), jnp.asarray(vs))
+    k = rng.randn(B, T, _KH, _DH).astype(np.float32)
+    v = rng.randn(B, T, _KH, _DH).astype(np.float32)
+    return k, v, None
+
+
+def _check_written(old, new, k, v, scales, tbl, q_pos, live):
+    """Live rows' tokens sit at ``(table[pos // BL], pos % BL)`` as
+    ``[k_h | v_h]`` lane groups (scales at ``[block, h, :, off]``); every
+    other row of the pool and of the scale plane is bit-identical."""
+    old = {n: np.asarray(a) for n, a in old.items()}
+    new = {n: np.asarray(a) for n, a in new.items()}
+    assert sorted(old) == sorted(new)
+    dt = old["kv"].dtype
+    touched = np.zeros(old["kv"].shape[:2], bool)
+    for b in np.flatnonzero(live):
+        for t in range(q_pos.shape[1]):
+            blk, off = tbl[b, q_pos[b, t] // _PBL], q_pos[b, t] % _PBL
+            row = new["kv"][blk, off].reshape(_KH, 2, _DH)
+            assert (row[:, 0] == np.asarray(jnp.asarray(k[b, t], dt))).all()
+            assert (row[:, 1] == np.asarray(jnp.asarray(v[b, t], dt))).all()
+            if scales is not None:
+                got = new["kv_scale"][blk, :, :, off]
+                assert (got[:, 0] == np.asarray(scales[0])[b, t]).all()
+                assert (got[:, 1] == np.asarray(scales[1])[b, t]).all()
+            touched[blk, off] = True
+    assert touched.sum() == live.sum() * q_pos.shape[1]
+    same = new["kv"].view(np.uint8) == old["kv"].view(np.uint8)
+    assert same.reshape(*touched.shape, -1).all(axis=-1)[~touched].all()
+    if scales is not None:
+        keep = np.broadcast_to(~touched[:, None, None, :],
+                               old["kv_scale"].shape)
+        assert (new["kv_scale"][keep] == old["kv_scale"][keep]).all()
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("mode", sorted(_POS))
+def test_pool_write_then_context_roundtrip(mode, kind):
+    """What a chunk writes comes back at exactly the positions written —
+    in the pool's rows, and in the kv-head-major view the gathered read
+    takes of a slot's table — and no other row of the pool changes."""
+    from chainermn_tpu.ops.decode_attention import pool_write
+
+    B, T, _ = _POS[mode]
+    rng = np.random.RandomState(len(mode) + (kind == "int8"))
+    tbl, NB = _tables(B, rng)
+    q_pos = _positions(mode)
+    old = _random_cache(NB, kind, rng)
+    k, v, scales = _chunk(B, T, kind, rng)
+    new = pool_write(old, jnp.asarray(k), jnp.asarray(v), scales,
+                     jnp.asarray(tbl), jnp.asarray(q_pos, jnp.int32))
+    _check_written(old, new, k, v, scales, tbl, q_pos, np.ones(B, bool))
+    # the gathered view of a slot: its table's blocks, logical order
+    ctx = np.asarray(new["kv"])[tbl].reshape(B, _PMB * _PBL, _KH, 2, _DH)
+    dt = ctx.dtype
+    for b in range(B):
+        assert (ctx[b, q_pos[b], :, 0]
+                == np.asarray(jnp.asarray(k[b], dt))).all()
+        assert (ctx[b, q_pos[b], :, 1]
+                == np.asarray(jnp.asarray(v[b], dt))).all()
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_masked_slot_writes_nothing(kind):
+    """A masked slot's scatter lands on the parking block with the value
+    already there: the pool and the scale plane stay bit-identical but
+    for the live slot's own rows, parking block included."""
+    from chainermn_tpu.ops.decode_attention import pool_write
+
+    B, T = 3, 4
+    rng = np.random.RandomState(3 + (kind == "int8"))
+    tbl, NB = _tables(B, rng)
+    q_pos = np.asarray([2, 7, 17])[:, None] + np.arange(T)[None]
+    live = np.asarray([False, True, False])
+    old = _random_cache(NB, kind, rng)
+    k, v, scales = _chunk(B, T, kind, rng)
+    new = pool_write(old, jnp.asarray(k), jnp.asarray(v), scales,
+                     jnp.asarray(tbl), jnp.asarray(q_pos, jnp.int32),
+                     jnp.asarray(live))
+    _check_written(old, new, k, v, scales, tbl, q_pos, live)
+    for n in old:  # the parking block itself
+        assert (np.asarray(new[n])[0] == np.asarray(old[n])[0]).all()
+    none = pool_write(old, jnp.asarray(k), jnp.asarray(v), scales,
+                      jnp.asarray(tbl), jnp.asarray(q_pos, jnp.int32),
+                      jnp.zeros(B, bool))
+    for n in old:
+        assert (np.asarray(none[n]).view(np.uint8)
+                == np.asarray(old[n]).view(np.uint8)).all()
+
+
+@pytest.mark.parametrize("window", [0, 5], ids=["full", "window"])
+@pytest.mark.parametrize("mode", sorted(_POS))
+def test_pool_context_attend_matches_reference(mode, window):
+    """The gathered read against the repo's one attention oracle on each
+    slot's CONTIGUOUS keys and values: the pool holds a context scattered
+    through the tables; query ``t`` of a chunk starting at ``p`` is row
+    ``p + t`` of the causal (windowed) attention over the slot."""
+    from chainermn_tpu.ops import reference_attention
+    from chainermn_tpu.ops.decode_attention import pool_context_attend
+
+    B, T, _ = _POS[mode]
+    H, L = 2 * _KH, _PMB * _PBL
+    rng = np.random.RandomState(7 + len(mode) + window)
+    tbl, NB = _tables(B, rng)
+    q_pos = _positions(mode)
+    k = rng.randn(B, L, _KH, _DH).astype(np.float32)
+    v = rng.randn(B, L, _KH, _DH).astype(np.float32)
+    q = rng.randn(B, T, H, _DH).astype(np.float32)
+    pool = rng.randn(NB, _PBL, _KH * 2 * _DH).astype(np.float32)
+    rows = np.concatenate([k, v], axis=-1).reshape(B, _PMB, _PBL, -1)
+    for b in range(B):
+        pool[tbl[b]] = rows[b]
+    got = np.asarray(pool_context_attend(
+        jnp.asarray(q), {"kv": jnp.asarray(pool)}, jnp.asarray(tbl),
+        jnp.asarray(q_pos, jnp.int32), window))
+    assert got.shape == (B, T, H, _DH)
+    for b in range(B):
+        qf = np.zeros((1, L, H, _DH), np.float32)
+        qf[0, q_pos[b]] = q[b]
+        want = reference_attention(
+            jnp.asarray(qf), jnp.asarray(k[b:b + 1]), jnp.asarray(v[b:b + 1]),
+            causal=True, window=window or None)
+        np.testing.assert_allclose(got[b], np.asarray(want)[0, q_pos[b]],
+                                   atol=2e-5)
+
+
+#: the dispatch table as data: name -> (T, per-row positions?, window,
+#: the caller's ``kernel`` flag, the scope taken)
+_CHOICE = {
+    "t1": (1, True, 0, True, "attn.paged"),
+    "verify_t4": (4, True, 0, True, "attn.paged"),
+    "prefill_chunk": (4, False, 0, True, "attn.gathered"),
+    "window": (1, True, 8, True, "attn.gathered"),
+    "kernel_off": (1, True, 0, False, "attn.gathered"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHOICE))
+def test_paged_attend_chooses_by_shape(case):
+    """``paged_attend`` is the one place that chooses a read, from the
+    chunk's length, the rank of the position it was given, the window and
+    the caller's flag: the lowered text holds the scope of the read taken
+    and not the other's.  (A verify chunk and a prefill chunk of the SAME
+    length differ only in that rank.)"""
+    from chainermn_tpu.ops.decode_attention import paged_attend
+
+    T, per_row, window, kernel, taken = _CHOICE[case]
+    B, H = 2, 2 * _KH
+    rng = np.random.RandomState(5)
+    tbl, NB = _tables(B, rng)
+    cache = _random_cache(NB, "bf16", rng)
+    q = jnp.asarray(rng.randn(B, T, H, _DH), jnp.float32)
+    decode_pos = jnp.asarray([3, 11], jnp.int32) if per_row else jnp.int32(3)
+
+    def attend(q, cache, tbl, decode_pos):
+        q_pos = (decode_pos[:, None] if per_row else decode_pos) \
+            + jnp.arange(T)[None]
+        q_pos = jnp.broadcast_to(q_pos, (B, T))
+        return paged_attend(q, cache, tbl, decode_pos, q_pos,
+                            jnp.ones(B, bool), kernel=kernel, window=window)
+
+    fn = jax.jit(attend)
+    args = (q, cache, jnp.asarray(tbl), decode_pos)
+    text = fn.lower(*args).as_text(debug_info=True)
+    other = ({"attn.paged", "attn.gathered"} - {taken}).pop()
+    assert taken in text and other not in text
+    assert fn(*args).shape == q.shape

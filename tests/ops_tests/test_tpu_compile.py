@@ -32,7 +32,6 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from chainermn_tpu.ops import (  # noqa: E402
     flash_attention,
-    fused_decode_attention,
     paged_decode_attention,
 )
 
@@ -84,13 +83,6 @@ def _paged(q_shape, kv_dtype, kv_heads=H, nb=NB):
     return paged_decode_attention, args
 
 
-def _fused():
-    cache = ((S, H, 1024, DH), jnp.bfloat16)
-    return fused_decode_attention, [
-        ((S, H, DH), jnp.bfloat16), cache, cache, ((S,), jnp.int32)
-    ]
-
-
 def _flash(backward):
     qkv = [((8, 2048, H, DH), jnp.bfloat16)] * 3
 
@@ -111,7 +103,6 @@ _CASES = {
     "paged_int8": (_paged((S, H, DH), jnp.int8), 1, ["paged_decode"]),
     "paged_verify_t4": (_paged((S, 4, H, DH), jnp.bfloat16), 1,
                         ["paged_decode"]),
-    "fused_l1024": (_fused(), 1, ["fused_decode"]),
     "flash_fwd": (_flash(False), 1, ["flash_fwd"]),
     # forward (for the residuals) + the dq and dk/dv kernels
     "flash_bwd": (_flash(True), 3,
