@@ -427,9 +427,10 @@ def main():
     )
     # Block tables sized to the drawn traffic's LONGEST request (padded to
     # the prefill chunk), not the model's max_len: the einsum fallback
-    # gathers the full table width every step, so table slack is pure
-    # masked compute in the hot loop.  A real deployment knows its length
-    # cap the same way.
+    # gathers a fraction of the table's width (an eighth ... the whole:
+    # the narrowest that holds the longest live context), so table slack
+    # is masked compute in the hot loop.  A real deployment knows its
+    # length cap the same way.
     from chainermn_tpu.serving.kv_pool import blocks_for
 
     longest = int((plens + new_counts).max())
@@ -913,12 +914,12 @@ def main():
     # kernel per shard under shard_map) vs "einsum" (the gathered GSPMD
     # fallback) — on IDENTICAL steady-state full-capacity clean decode
     # steps.  The per-step comparison is the honest one: the einsum
-    # path gathers and scores every slot's FULL padded table width each
-    # step, while the paged kernel streams each pool byte once at
-    # storage width and walks only the blocks a slot has actually
-    # filled (the block-skip recurrence) — the PagedAttention claim,
-    # now held under sharding.  A small greedy drain on both engines
-    # doubles as the token-identity verdict.
+    # path gathers and scores, for EVERY slot, the table width that holds
+    # the longest live context each step, while the paged kernel streams
+    # each pool byte once at storage width and walks only the blocks a
+    # slot has actually filled (the block-skip recurrence) — the
+    # PagedAttention claim, now held under sharding.  A small greedy
+    # drain on both engines doubles as the token-identity verdict.
     #
     # CPU caveat (measured, not assumed): off-TPU the Pallas kernels
     # run in Pallas INTERPRET mode, whose per-grid-program emulation

@@ -10,9 +10,10 @@ the one place that knows what a pool row looks like:
   through the block tables (masked slots park on block 0);
 * :func:`paged_decode_attention` — the Pallas kernel that walks a slot's
   table over the pool in HBM (below), and its ``shard_map`` twin;
-* :func:`pool_context_attend` — the gathered read: a slot's table width
-  viewed kv-head major and attended in float32 (prefill chunks, and the
-  reference path the tests compare the kernel with);
+* :func:`pool_context_attend` — the gathered read: a slot's blocks up to
+  the chunk's last position (:func:`context_widths`), contracted as stored
+  into float32 scores (prefill chunks, and the reference path the tests
+  compare the kernel with);
 * :func:`paged_attend` — the one place that chooses between the two reads.
 
 The model's block (``models/transformer.py`` ``_DecoderBlock``) hands
@@ -621,56 +622,99 @@ def sharded_paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def pool_context_attend(q, cache, block_tables, q_pos, window=0):
-    """Grouped-query attention of a ``(B, T, H, Dh)`` query chunk over each
-    row's whole table width, gathered: gather each row's blocks and view
-    THEM kv-head major ``(B, KH, L, Dh)`` — a transpose of one slot's
-    context, never of a pool — for float32 einsums.  Query ``t`` of row
-    ``b`` attends positions ``<= q_pos[b, t]`` (the last ``window`` of
-    them, if given): the mask of the model's contiguous ``(B, L, KH, Dh)``
-    einsum path exactly, only the context's axis order differs.  An int8
-    pool's per-(kv-head, position) scales fold into the scores (k) and the
-    probabilities (v).  ``cache`` is the entry :func:`pool_write`
-    returned: the chunk's own tokens are in it."""
-    pool = cache["kv"]
+def context_widths(max_blocks: int):
+    """The widths, in blocks, :func:`pool_context_attend` may read of a
+    table ``max_blocks`` wide: an eighth, a quarter, a half and the whole
+    of it, rounded up (64 -> 8, 16, 32, 64)."""
+    return tuple(sorted({-(-max_blocks // d) for d in (8, 4, 2, 1)}))
+
+
+def _context_rung(last_pos, block_len: int, widths):
+    """Index of the narrowest of ``widths`` that holds position
+    ``last_pos`` — a Python int on the host, a traced scalar in the
+    program; past the table's end the widest."""
+    return sum(last_pos >= w * block_len for w in widths[:-1])
+
+
+def context_blocks(last_pos: int, block_len: int, max_blocks: int) -> int:
+    """How many blocks of each row's table :func:`pool_context_attend`
+    reads for a chunk whose last query sits at ``last_pos`` — the host's
+    side of the choice its program makes (the scheduler's
+    ``ctx_blocks=``)."""
+    widths = context_widths(max_blocks)
+    return widths[_context_rung(int(last_pos), block_len, widths)]
+
+
+def _attend_width(W, window, q, pool, scale, block_tables, q_pos):
+    """:func:`pool_context_attend` over the first ``W`` blocks of each
+    row's table (``W`` static: one branch of its switch).
+
+    The gathered rows are contracted as stored, a head's whole lane group
+    ``[k_h | v_h]`` at a time: the queries ride zero-padded over the value
+    lanes (exact zeros into the scores) and the value product keeps its
+    value half.  Slicing k and v out of the row first — its
+    ``(L, KH, 2, Dh)`` view — costs the chip a padded relayout of the
+    context for each (measured, ``PERF.md`` §6 PR 31); a lane group is
+    whole ``(8, 128)`` tiles, one relayout serves both products, and the
+    MXU has the room (``T`` query rows a head)."""
     B, T, H, Dh = q.shape
     BL = pool.shape[1]
     KH = pool.shape[2] // (2 * Dh)
-    with jax.named_scope("attn.gathered"):
-        MB = block_tables.shape[1]
-        g = pool[block_tables].reshape(B, MB * BL, KH, 2, Dh)
-        kc = jnp.transpose(g[:, :, :, 0], (0, 2, 1, 3))
-        vc = jnp.transpose(g[:, :, :, 1], (0, 2, 1, 3))
-        ks_c = vs_c = None
-        if "kv_scale" in cache:
-            # (B, MB, KH, 2, BL) -> (B, KH, 2, MB * BL)
-            sg = jnp.transpose(
-                cache["kv_scale"][block_tables], (0, 2, 3, 1, 4)
-            ).reshape(B, KH, 2, MB * BL)
-            ks_c, vs_c = sg[:, :, 0], sg[:, :, 1]
-        qg = q.reshape(B, T, KH, H // KH, Dh)
-        s = jnp.einsum(
-            "btkgd,bkld->bkgtl", qg.astype(jnp.float32),
-            kc.astype(jnp.float32),
-        ) / math.sqrt(Dh)
-        if ks_c is not None:
-            s = s * ks_c[:, :, None, None, :]
-        t_idx = jnp.arange(kc.shape[2])
-        visible = (
+    L = W * BL
+    tbl = block_tables[:, :W]
+    g = pool[tbl].reshape(B, L, KH, 2 * Dh)
+    qg = q.reshape(B, T, KH, H // KH, Dh)
+    s = jnp.einsum(
+        "btkgd,blkd->bkgtl", jnp.concatenate([qg, jnp.zeros_like(qg)], -1),
+        g, preferred_element_type=jnp.float32,
+    ) / math.sqrt(Dh)
+    if scale is not None:
+        # (B, W, KH, 2, BL) -> (B, KH, 2, L)
+        sg = jnp.transpose(scale[tbl], (0, 2, 3, 1, 4)).reshape(B, KH, 2, L)
+        s = s * sg[:, :, 0][:, :, None, None, :]
+    t_idx = jnp.arange(L)
+    visible = (
+        t_idx[None, None, None, None, :] <= q_pos[:, None, None, :, None]
+    )
+    if window:
+        visible &= (
             t_idx[None, None, None, None, :]
-            <= q_pos[:, None, None, :, None]
+            > q_pos[:, None, None, :, None] - window
         )
-        if window:
-            visible &= (
-                t_idx[None, None, None, None, :]
-                > q_pos[:, None, None, :, None] - window
-            )
-        s = jnp.where(visible, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        if vs_c is not None:
-            p = p * vs_c[:, :, None, None, :]
-        a = jnp.einsum("bkgtl,bkld->btkgd", p, vc.astype(jnp.float32))
-        return a.reshape(B, T, H, Dh).astype(q.dtype)
+    s = jnp.where(visible, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    if scale is not None:
+        p = p * sg[:, :, 1][:, :, None, None, :]
+    a = jnp.einsum(
+        "bkgtl,blkd->btkgd", p, g, preferred_element_type=jnp.float32
+    )[..., Dh:]
+    return a.reshape(B, T, H, Dh).astype(q.dtype)
+
+
+# a model's layers call it alike: its branches traced once, not once a layer
+@functools.partial(jax.jit, static_argnames="window")
+def pool_context_attend(q, cache, block_tables, q_pos, window=0):
+    """Grouped-query attention of a ``(B, T, H, Dh)`` query chunk over the
+    context it has, gathered: the blocks of each row's table up to the
+    chunk's last position ``max(q_pos)`` — the narrowest of
+    :func:`context_widths` that holds it, chosen by a ``lax.switch`` inside
+    the program, so one program serves a chunk size wherever the chunk
+    sits — contracted as stored (the pool's dtype, no kv-head-major copy)
+    into float32 scores.  Query ``t`` of row ``b`` attends positions
+    ``<= q_pos[b, t]`` (the last ``window`` of them, if given): the mask of
+    the model's contiguous ``(B, L, KH, Dh)`` einsum path exactly, and the
+    blocks left out are those it gives probability 0.0.  An int8 pool's
+    per-(kv-head, position) scales fold into the scores (k) and the
+    probabilities (v).  ``cache`` is the entry :func:`pool_write`
+    returned: the chunk's own tokens are in it."""
+    widths = context_widths(block_tables.shape[1])
+    with jax.named_scope("attn.gathered"):
+        rung = _context_rung(jnp.max(q_pos), cache["kv"].shape[1], widths)
+        return jax.lax.switch(
+            rung,
+            [functools.partial(_attend_width, w, window) for w in widths],
+            q, cache["kv"], cache.get("kv_scale"), block_tables, q_pos,
+        )
 
 
 def paged_attend(q, cache, block_tables, decode_pos, q_pos, slot_mask=None,

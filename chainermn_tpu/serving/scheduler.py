@@ -119,6 +119,7 @@ from chainermn_tpu.observability.tracing import annotate as _annotate
 from chainermn_tpu.observability.metrics import (
     NoopInstrument as _NoopInstrument,
 )
+from chainermn_tpu.ops.decode_attention import context_blocks
 from chainermn_tpu.serving.kv_pool import PoolExhausted, blocks_for
 
 
@@ -1258,9 +1259,12 @@ class Scheduler:
         last = end == len(slot.text)
         tc = self.clock.now()
         t0 = time.perf_counter()
+        # ctx_blocks: the table width the program reads for this chunk —
+        # the choice it makes itself from the padded chunk's last position.
         with _annotate("cmn_serve_prefill", req=slot.entry.req.id,
                        slot=slot.idx, p0=p0, tokens=end - p0, padded=size,
-                       final=int(last)):
+                       final=int(last), ctx_blocks=lambda: context_blocks(
+                           p0 + size - 1, eng.block_len, eng.max_blocks)):
             tok = eng.prefill(
                 slot.idx, chunk, p0, slot.table,
                 last_idx=(end - p0 - 1) if last else -1,

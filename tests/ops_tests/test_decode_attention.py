@@ -379,11 +379,28 @@ _POS = {  # name -> (B, T, the chunk's first position: per row, or a scalar)
     "prefill_c32": (1, 32, 5),
 }
 _KH, _DH, _PBL, _PMB = 2, 16, 8, 6
+#: chunks whose LAST position lands in each width the gathered read may
+#: take of this geometry's 6-block table — ``context_widths(6)`` is 1, 2,
+#: 3, 6 blocks of 8: name -> (B, T, first position(s), the width taken)
+_LADDER = {
+    "c4_first_block": (1, 4, 0, 1),
+    "c4_on_w1_edge": (1, 4, 4, 1),      # last position 7: the width's last
+    "c4_past_w1_edge": (1, 4, 5, 2),    # last position 8: one past it
+    "c8_on_w2_edge": (1, 8, 8, 2),
+    "c8_past_w2_edge": (1, 8, 9, 3),
+    "c8_past_w3_edge": (1, 8, 17, 6),
+    "c8_full_table": (1, 8, 40, 6),     # last position 47: the table's last
+    "rows_w1": (3, 1, [0, 3, 7], 1),
+    "rows_on_w3_edge": (3, 4, [0, 9, 20], 3),  # the widest row decides
+    "rows_past_w3_edge": (3, 4, [21, 9, 0], 6),
+    "rows_full_table": (3, 4, [1, 20, 44], 6),
+}
+_READS = {**{m: v + (None,) for m, v in _POS.items()}, **_LADDER}
 
 
 def _positions(mode):
     """``q_pos`` ``(B, T)`` as the model's block forms it."""
-    B, T, p0 = _POS[mode]
+    B, T, p0 = _READS[mode][:3]
     return np.broadcast_to(
         np.asarray(p0).reshape(-1, 1) + np.arange(T)[None], (B, T))
 
@@ -453,8 +470,8 @@ def _check_written(old, new, k, v, scales, tbl, q_pos, live):
 @pytest.mark.parametrize("mode", sorted(_POS))
 def test_pool_write_then_context_roundtrip(mode, kind):
     """What a chunk writes comes back at exactly the positions written —
-    in the pool's rows, and in the kv-head-major view the gathered read
-    takes of a slot's table — and no other row of the pool changes."""
+    in the pool's rows, and in the logical order the gathered read takes a
+    slot's table in — and no other row of the pool changes."""
     from chainermn_tpu.ops.decode_attention import pool_write
 
     B, T, _ = _POS[mode]
@@ -504,31 +521,63 @@ def test_masked_slot_writes_nothing(kind):
                 == np.asarray(old[n]).view(np.uint8)).all()
 
 
-@pytest.mark.parametrize("window", [0, 5], ids=["full", "window"])
-@pytest.mark.parametrize("mode", sorted(_POS))
-def test_pool_context_attend_matches_reference(mode, window):
-    """The gathered read against the repo's one attention oracle on each
-    slot's CONTIGUOUS keys and values: the pool holds a context scattered
-    through the tables; query ``t`` of a chunk starting at ``p`` is row
-    ``p + t`` of the causal (windowed) attention over the slot."""
-    from chainermn_tpu.ops import reference_attention
-    from chainermn_tpu.ops.decode_attention import pool_context_attend
-
-    B, T, _ = _POS[mode]
+def _context_case(mode, kind, window):
+    """A context scattered through the tables, and a query chunk: the
+    arguments of the gathered read, and each slot's contiguous float keys
+    and values (an int8 pool's, dequantised) for the oracle."""
+    B, T = _READS[mode][:2]
     H, L = 2 * _KH, _PMB * _PBL
     rng = np.random.RandomState(7 + len(mode) + window)
     tbl, NB = _tables(B, rng)
     q_pos = _positions(mode)
-    k = rng.randn(B, L, _KH, _DH).astype(np.float32)
-    v = rng.randn(B, L, _KH, _DH).astype(np.float32)
     q = rng.randn(B, T, H, _DH).astype(np.float32)
-    pool = rng.randn(NB, _PBL, _KH * 2 * _DH).astype(np.float32)
-    rows = np.concatenate([k, v], axis=-1).reshape(B, _PMB, _PBL, -1)
+    cache = {}
+    if kind == "int8":
+        kv = rng.randint(-127, 128, size=(B, L, _KH, 2, _DH)).astype(np.int8)
+        sc = (rng.rand(B, L, _KH, 2) * 0.02 + 0.001).astype(np.float32)
+        k, v = (kv[:, :, :, i] * sc[:, :, :, i, None] for i in (0, 1))
+        plane = rng.rand(NB, _KH, 2, _PBL).astype(np.float32)
+        for b in range(B):  # (L, KH, 2) -> (MB, KH, 2, BL)
+            plane[tbl[b]] = sc[b].reshape(_PMB, _PBL, _KH, 2).transpose(
+                0, 2, 3, 1)
+        cache["kv_scale"] = jnp.asarray(plane)
+        pool = rng.randint(-127, 128, size=(NB, _PBL, _KH * 2 * _DH)).astype(
+            np.int8)
+    else:
+        kv = rng.randn(B, L, _KH, 2, _DH).astype(np.float32)
+        k, v = kv[:, :, :, 0], kv[:, :, :, 1]
+        pool = rng.randn(NB, _PBL, _KH * 2 * _DH).astype(np.float32)
     for b in range(B):
-        pool[tbl[b]] = rows[b]
-    got = np.asarray(pool_context_attend(
-        jnp.asarray(q), {"kv": jnp.asarray(pool)}, jnp.asarray(tbl),
-        jnp.asarray(q_pos, jnp.int32), window))
+        pool[tbl[b]] = kv[b].reshape(_PMB, _PBL, -1)
+    cache["kv"] = jnp.asarray(pool)
+    return (jnp.asarray(q), cache, jnp.asarray(tbl),
+            jnp.asarray(q_pos, jnp.int32)), (q, k, v, q_pos)
+
+
+@pytest.mark.parametrize("kind", ["float", "int8"])
+@pytest.mark.parametrize("window", [0, 5], ids=["full", "window"])
+@pytest.mark.parametrize("mode", sorted(_READS))
+def test_pool_context_attend_matches_reference(mode, window, kind):
+    """The gathered read against the repo's one attention oracle on each
+    slot's CONTIGUOUS keys and values: the pool holds a context scattered
+    through the tables; query ``t`` of a chunk starting at ``p`` is row
+    ``p + t`` of the causal (windowed) attention over the slot — whichever
+    width of the table the chunk's last position makes the read take
+    (``_LADDER``: first block only, on a width's edge, one past it, the
+    whole table; one position for the chunk or one a row), float pool or
+    int8 with its scale plane."""
+    from chainermn_tpu.ops import reference_attention
+    from chainermn_tpu.ops.decode_attention import (
+        context_blocks,
+        pool_context_attend,
+    )
+
+    args, (q, k, v, q_pos) = _context_case(mode, kind, window)
+    B, T, _, width = _READS[mode]
+    H, L = 2 * _KH, _PMB * _PBL
+    if width is not None:
+        assert context_blocks(q_pos.max(), _PBL, _PMB) == width
+    got = np.asarray(pool_context_attend(*args, window))
     assert got.shape == (B, T, H, _DH)
     for b in range(B):
         qf = np.zeros((1, L, H, _DH), np.float32)
@@ -538,6 +587,48 @@ def test_pool_context_attend_matches_reference(mode, window):
             causal=True, window=window or None)
         np.testing.assert_allclose(got[b], np.asarray(want)[0, q_pos[b]],
                                    atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["float", "int8"])
+@pytest.mark.parametrize("window", [0, 5], ids=["full", "window"])
+@pytest.mark.parametrize("mode", sorted(_READS))
+def test_context_width_read_is_the_full_width_read(mode, window, kind):
+    """Reading only the blocks up to the chunk's last position changes no
+    bit of the result in float32: every column left out carried
+    probability exactly 0.0 in the read over the table's whole width."""
+    from chainermn_tpu.ops.decode_attention import (
+        _attend_width,
+        pool_context_attend,
+    )
+
+    (q, cache, tbl, q_pos), _ = _context_case(mode, kind, window)
+    got = np.asarray(pool_context_attend(q, cache, tbl, q_pos, window))
+    whole = np.asarray(_attend_width(
+        _PMB, window, q, cache["kv"], cache.get("kv_scale"), tbl, q_pos))
+    assert got.dtype == np.float32
+    assert (got.view(np.uint32) == whole.view(np.uint32)).all()
+
+
+def test_context_widths_follow_the_table():
+    """The ladder is derived from the table's width — an eighth, a
+    quarter, a half, the whole, rounded up — and the host's
+    ``context_blocks`` makes the program's choice: the narrowest width
+    holding the position, the widest past the table's end."""
+    from chainermn_tpu.ops.decode_attention import (
+        context_blocks,
+        context_widths,
+    )
+
+    assert context_widths(64) == (8, 16, 32, 64)
+    assert context_widths(16) == (2, 4, 8, 16)
+    assert context_widths(20) == (3, 5, 10, 20)
+    assert context_widths(_PMB) == (1, 2, 3, 6)  # what ``_LADDER`` walks
+    assert context_widths(2) == (1, 2)
+    assert context_widths(1) == (1,)
+    got = [context_blocks(p, 16, 64) for p in (0, 127, 128, 255, 256, 511,
+                                               512, 1023, 5000)]
+    assert got == [8, 8, 16, 16, 32, 32, 64, 64, 64]
+    assert context_blocks(0, 8, 1) == 1
 
 
 #: the dispatch table as data: name -> (T, per-row positions?, window,

@@ -55,6 +55,48 @@ def test_steady_state_compiles_exactly_once(fused_engine_run):
     )
 
 
+def test_prefill_across_every_context_width_is_one_program_a_chunk_size(
+    make_model, tiny_params, oracle
+):
+    """The gathered read's width follows the chunk's position INSIDE the
+    program: a prompt long enough to cross every width of the rehearse
+    geometry's ladder (a table of 16 blocks of 8: 2, 4, 8, 16) prefills
+    with one compiled program a chunk size, inside the compile watch's
+    budget, and serves the tokens the contiguous cache generates."""
+    from chainermn_tpu.observability import device as odev
+    from chainermn_tpu.ops.decode_attention import (
+        context_blocks,
+        context_widths,
+    )
+
+    model = make_model(max_len=128)
+    eng = DecodeEngine(
+        model, tiny_params, capacity=2, num_blocks=40, block_len=8,
+        max_blocks_per_slot=16, prefill_chunk=16,
+    )
+    assert eng.prefill_ladder == (8, 16)
+    rng = np.random.RandomState(3)
+    long, short = (rng.randint(1, 128, size=n).tolist() for n in (100, 21))
+    # chunks of 16 from 0, then the tail's ladder size 8 at 96
+    crossed = {context_blocks(p0 + size - 1, eng.block_len, eng.max_blocks)
+               for p0, size in [(p, 16) for p in range(0, 96, 16)] + [(96, 8)]}
+    assert crossed == set(context_widths(16)) == {2, 4, 8, 16}
+    # the watch is the process's: other tests of this file recompile on purpose
+    violations = odev.watch().budget_violations
+    comps = Scheduler(eng).run([
+        Request(id=0, prompt=long, max_new_tokens=12),
+        Request(id=1, prompt=short, max_new_tokens=12),
+    ])
+    assert eng.prefill_compiles == len(eng.prefill_ladder)
+    assert eng.decode_compiles == 1
+    assert not eng._prefill.over_budget and not eng._step.over_budget
+    assert odev.watch().budget_violations == violations
+    assert "compile_over_budget" not in eng.stats()
+    for c in comps:
+        want = oracle(model, tiny_params, (long, short)[c.id], 12)
+        assert c.tokens == want, (c.id, c.tokens, want)
+
+
 def test_continuous_batching_matches_sequential_greedy(
     fused_engine_run, tiny_params, prompts, oracle
 ):
