@@ -13,8 +13,9 @@ plus the per-row logsumexp (LSE) for the backward.
 
 Backward (custom VJP, flash-style recomputation): ``delta = rowsum(dO·O)`` in
 XLA, then one kernel over K/V blocks accumulating ``dK``/``dV`` across the Q
-loop, and one over Q blocks accumulating ``dQ`` across the K loop — the
-standard dataflow that keeps every intermediate in VMEM.
+loop (and across the query heads that share a KV head, inside the launch),
+and one over Q blocks accumulating ``dQ`` across the K loop — the standard
+dataflow that keeps every intermediate in VMEM.
 
 On non-TPU backends the same kernels run in Pallas interpret mode (tests), so
 numerics are identical everywhere.
@@ -130,15 +131,16 @@ def _reference_attention_lse(q, k, v, causal: bool = False,
 # block-skipping loop bounds: the forward and both backward kernels must
 # agree on these EXACTLY or gradients silently diverge from the forward.
 
-def _mask_scores(s, q0, k0, causal, window):
+def _mask_scores(s, q0, k0, causal, window, q_axis=0):
     """Apply causal (``q >= k``) and sliding-window (``|q - k| < window``)
     masks to a score block whose rows start at absolute q position ``q0``
-    and columns at k position ``k0``."""
+    and columns at k position ``k0`` — or, with ``q_axis=1``, the transposed
+    block the dK/dV kernel builds (rows are keys, columns queries): the same
+    comparisons on the same absolute positions."""
     if not causal and window is None:
         return s
-    bq, bk = s.shape
-    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     if causal:
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
     if window is not None:
@@ -275,40 +277,56 @@ def _kv_row(heads: int, kv_heads: int):
 
 #: VMEM budget (bytes) for a kernel's two double-buffered full-sequence
 #: refs — k+v in the fwd/dQ kernels, q+do in the dK/dV kernel.  Half the
-#: ~16 MB per-core VMEM; the rest covers block tiles, the score matrix, and
-#: accumulators.  Sequences whose staged refs exceed this are transparently
-#: chunked (:func:`_stage_chunk`) and the partials merged through their
-#: logsumexps — same math, unbounded T (the real chip rejected the
-#: unchunked kernel at T=16384, D=128: 16.25 MB scoped > 16 MB).
+#: 16 MB of VMEM a kernel is given by default; the rest covers block tiles,
+#: the score matrix, and accumulators.  Sequences whose staged refs exceed
+#: this are transparently chunked (:func:`_stage_chunk`) and the partials
+#: merged through their logsumexps — same math, unbounded T (the real chip
+#: rejected the unchunked kernel at T=16384, D=128: 16.25 MB scoped > 16 MB).
 _STAGE_BUDGET_BYTES = 8 * 1024 * 1024
 
-#: Mosaic lane-pads the trailing singleton dim of the per-row refs
-#: ((1, T, 1) lse/delta/segment arrays) to a full 128-lane tile — a staged
-#: f32 row costs 512 bytes, not 4.  The on-chip OOM that motivated this
-#: accounting: the dK/dV kernel at T=16384, D=128 with q+do staged under a
-#: naive 2·2·D·itemsize budget still allocated 17 MB, the extra ~8 MB
-#: being exactly the double-buffered lane-padded lse+delta rows.
+#: VMEM budget (bytes) for the float32 dK and dV rows the dK/dV kernel keeps
+#: resident while a KV head's whole query group passes over them (one
+#: buffer each: they leave once a head).  4,096 rows at D=128; a longer kv
+#: sequence takes a grid axis of such chunks.
+_RESIDENT_BUDGET_BYTES = 4 * 1024 * 1024
+
+#: Mosaic pads a block's last two dims to whole (8, 128) tiles.  A per-row
+#: ref with a trailing singleton lane dim ((1, T, 1): the fwd/dQ kernels'
+#: lse/delta blocks and their staged kv segment row) costs 512 bytes a f32
+#: row, not 4 — the on-chip OOM that motivated this accounting: the dK/dV
+#: kernel at T=16384, D=128 with q+do staged under a naive 2·2·D·itemsize
+#: budget still allocated 17 MB, the extra ~8 MB being exactly the
+#: double-buffered lane-padded lse+delta rows.  That kernel stages its
+#: per-row refs lane-dense, ``(T // block_q, block_q)`` — a q block a
+#: sublane row, read as a ``(1, block_q)`` row that broadcasts down the
+#: transposed score tile: 4 bytes a row, 32 when a chunk of fewer than
+#: eight q blocks still fills eight sublanes.
 _LANE = 128
+_SUBLANE = 8
 
 
-def _row_bytes(depth, itemsize, n_padded_f32=0, segmented=False):
+def _row_bytes(depth, itemsize, n_dense=0, block=_LANE, segmented=False):
     """Double-buffered VMEM bytes per staged sequence row: two (row, depth)
-    arrays (k+v or q+do) plus ``n_padded_f32`` lane-padded f32 per-row refs
-    (lse/delta) plus the int32 segment row when segmented."""
+    arrays (k+v or q+do), plus ``n_dense`` lane-dense 32-bit per-row refs in
+    blocks of ``block`` rows (the dK/dV kernel's lse/delta/query segments,
+    counted at eight sublanes a block), plus the lane-padded int32 kv
+    segment row the fwd/dQ kernels stage when segmented."""
     b = 2 * 2 * depth * itemsize
-    b += 2 * n_padded_f32 * _LANE * 4
+    lanes = -(-block // _LANE) * _LANE
+    b += -(-2 * n_dense * _SUBLANE * 4 * lanes // block)
     if segmented:
         b += 2 * _LANE * 4
     return b
 
 
-def _stage_chunk(length, row_bytes, block, max_rows):
+def _stage_chunk(length, row_bytes, block, max_rows,
+                 budget=_STAGE_BUDGET_BYTES):
     """Chunk length for the full-row staged refs: the largest divisor of
-    ``length`` that is a multiple of ``block`` and fits the stage budget
-    at ``row_bytes`` per row (:func:`_row_bytes`).  ``length`` itself when
-    it already fits — the chunk-free fast path, byte-identical to the
-    unchunked kernel."""
-    rows = _STAGE_BUDGET_BYTES // row_bytes
+    ``length`` that is a multiple of ``block`` and fits ``budget`` (the
+    stage budget) at ``row_bytes`` per row (:func:`_row_bytes`).  ``length``
+    itself when it already fits — the chunk-free fast path, byte-identical
+    to the unchunked kernel."""
+    rows = budget // row_bytes
     if max_rows is not None:
         rows = min(rows, max_rows)
     if length <= rows:
@@ -428,19 +446,27 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     block_q, causal, segmented, scale, window=None, q_off=0,
 ):
-    # k/v_ref, dk/dv_ref: (1, BK, D); q/do_ref: (1, T, D); per-row refs
-    # (lse/delta/segments) carry the trailing singleton lane dim (1, T, 1).
+    # Written from the kv block's side.  Grid (batch·kv_head, kv chunk,
+    # query head of the group, kv block of the chunk); one step takes the
+    # block's k/v_ref (1, BK, D) past the staged q/do_ref (1, T, D) of ONE
+    # query head.  Per-row refs (lse/delta/query segments) are lane-dense
+    # (1, T // BQ, BQ) — q block ``qi`` is sublane row ``qi`` — and the kv
+    # block's segments a (1, BK, 1) column.  dk/dv_ref (1, Sc, D) fp32 hold
+    # the kv head's whole chunk: their block index ignores the two inner
+    # axes, so they stay in VMEM while the group passes and leave once.
     if segmented:
         segq_ref, segk_ref, dk_ref, dv_ref = rest
     else:
         dk_ref, dv_ref = rest
-    ki = pl.program_id(1)
+    g = pl.program_id(2)
+    i = pl.program_id(3)
     bk = k_ref.shape[1]
     T = q_ref.shape[1]
     D = k_ref.shape[2]
+    ki = pl.program_id(1) * (dk_ref.shape[1] // bk) + i  # block of all S
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    seg_k = segk_ref[0, :, 0] if segmented else None  # (BK,)
+    seg_k = segk_ref[0] if segmented else None  # (BK, 1)
 
     n_q = T // block_q
     q_start_blk, q_end_blk = _q_block_range(
@@ -451,33 +477,36 @@ def _bwd_dkv_kernel(
         dk, dv = carry
         q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32) * scale
         do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+        lse = lse_ref[0, pl.ds(qi, 1), :]  # (1, BQ)
+        delta = delta_ref[0, pl.ds(qi, 1), :]
+        # Scores and dP TRANSPOSED, (BK, BQ): k·qᵀ and v·doᵀ contract the
+        # last dims like the dQ kernel's products, and pᵀ / dsᵀ then enter
+        # the two accumulations as plain (BK, BQ)·(BQ, D) left operands —
+        # no score tile is turned.
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
-        s = _mask_scores(s, qi * block_q + q_off, ki * bk, causal, window)
+        )
+        st = _mask_scores(st, qi * block_q + q_off, ki * bk, causal, window,
+                          q_axis=1)
         if segmented:
-            seg_q = segq_ref[0, pl.ds(qi * block_q, block_q), 0]
-            s = jnp.where(seg_q[:, None] == seg_k[None, :], s, NEG_INF)
+            seg_q = segq_ref[0, pl.ds(qi, 1), :]  # (1, BQ)
+            st = jnp.where(seg_k == seg_q, st, NEG_INF)
         # Exact softmax via saved LSE.  Rows with lse == NEG_INF carried no
         # mass in the forward (fully masked); s - lse would cancel the
         # finite NEG_INF there (p = 1), so mask them to zero explicitly.
-        p = jnp.where(
-            (lse > NEG_INF * 0.5)[:, None], jnp.exp(s - lse[:, None]), 0.0
-        )  # (BQ, BK)
+        pt = jnp.where(lse > NEG_INF * 0.5, jnp.exp(st - lse), 0.0)
         dv_new = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            pt, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (BQ, BK)
-        ds = p * (dp - delta[:, None])
+        )  # (BK, BQ)
+        dst = pt * (dpt - delta)
         dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return dk_new, dv_new
@@ -486,8 +515,19 @@ def _bwd_dkv_kernel(
     dv0 = jnp.zeros((bk, D), jnp.float32)
     dk, dv = jax.lax.fori_loop(q_start_blk, q_end_blk, body, (dk0, dv0))
     # dk = dsᵀ·(q·scale): the softmax scale flows in through the scaled q.
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    # The group sums where it stands: the first query head's pass sets the
+    # block's rows, the others add to them, all in fp32.
+    rows = pl.ds(i * bk, bk)
+
+    @pl.when(g == 0)
+    def _():
+        dk_ref[0, rows, :] = dk
+        dv_ref[0, rows, :] = dv
+
+    @pl.when(g > 0)
+    def _():
+        dk_ref[0, rows, :] += dk
+        dv_ref[0, rows, :] += dv
 
 
 def _bwd_dq_kernel(
@@ -548,15 +588,17 @@ def _bwd(segmented, heads, kv_heads, causal, block_q, block_k, interpret,
     p_ij``, so the lse cotangent just shifts the per-row delta —
     ``ds = p·(dp − (delta − dlse))`` — and both kernels run unchanged.
 
-    Under GQA (``kv_heads < heads``) the dK/dV kernel still writes one
-    gradient row per QUERY head (reading the shared kv row through the same
-    index map as the forward); the group sum down to ``kv_heads`` rows is a
-    single fused XLA reduction afterwards — the kernels never need a
-    revisited-output accumulation pattern."""
+    Under GQA (``kv_heads < heads``) the dK/dV kernel owns a KV head's rows
+    for its whole group: the query heads pass over them on an inner grid
+    axis (each reading the shared kv block through the forward's index map)
+    and add into one resident fp32 block, so what leaves the kernel is one
+    ``(B·kv_heads, S, D)`` tensor each — no per-query-head gradient ever
+    reaches HBM, and ``heads == kv_heads`` is the same body with a group
+    axis of length one."""
     q, k, v, seg_q, seg_kv, o, lse = residuals
     do = g
     BH, T, D = q.shape
-    S = k.shape[1]
+    BKH, S = k.shape[:2]
     group = heads // kv_heads
     scale = 1.0 / math.sqrt(D)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -567,63 +609,77 @@ def _bwd(segmented, heads, kv_heads, causal, block_q, block_k, interpret,
     vma = _vma_union(q, k, v, do, lse, delta,
                      *([seg_q, seg_kv] if segmented else []))
 
-    def dkv_call(q_c, do_c, lse_c, delta_c, seg_q_c, q_off, out_dtypes):
-        """dK/dV over ALL kv rows from one q-chunk (``(1, Tc, D)`` staged
-        q/do refs; kv blocked through the grid)."""
+    # kv rows whose fp32 dK + dV stay resident (single-buffered) at a time.
+    Sc = _stage_chunk(S, 2 * D * 4, block_k, max_stage_rows,
+                      budget=_RESIDENT_BUDGET_BYTES)
+    n_i = Sc // block_k
+
+    def dkv_call(q_c, do_c, lse_c, delta_c, seg_q_c, q_off):
+        """fp32 dK/dV over ALL kv rows from one q-chunk (``(1, Tc, D)``
+        staged q/do refs, once a query head and kv chunk; kv blocked
+        through the grid)."""
         Tc = q_c.shape[1]
         dkv_kernel = functools.partial(
             _bwd_dkv_kernel, block_q=block_q, causal=causal,
             segmented=segmented, scale=scale, window=window, q_off=q_off,
         )
+
+        def dense(x):  # (rows, Tc) → one q block a sublane row
+            return x.reshape(x.shape[0], Tc // block_q, block_q)
+
+        def qh(b, c, g, i):  # the group's g-th query head
+            return (b * group + g, 0, 0)
+
+        def kvb(b, c, g, i):  # kv block i of chunk c
+            return (b, c * n_i + i, 0)
+
+        dense_block = (1, Tc // block_q, block_q)
+        stat = pl.BlockSpec(dense_block, qh)
         in_specs = [
-            pl.BlockSpec((1, Tc, D), lambda b, i: (b, 0, 0)),       # q
-            pl.BlockSpec((1, block_k, D), lambda b, i: (kvr(b), i, 0)),  # k
-            pl.BlockSpec((1, block_k, D), lambda b, i: (kvr(b), i, 0)),  # v
-            pl.BlockSpec((1, Tc, D), lambda b, i: (b, 0, 0)),       # do
-            pl.BlockSpec((1, Tc, 1), lambda b, i: (b, 0, 0)),       # lse
-            pl.BlockSpec((1, Tc, 1), lambda b, i: (b, 0, 0)),       # delta
+            pl.BlockSpec((1, Tc, D), qh),          # q
+            pl.BlockSpec((1, block_k, D), kvb),    # k
+            pl.BlockSpec((1, block_k, D), kvb),    # v
+            pl.BlockSpec((1, Tc, D), qh),          # do
+            stat,                                  # lse
+            stat,                                  # delta
         ]
-        args = [q_c, k, v, do_c, lse_c[..., None], delta_c[..., None]]
+        args = [q_c, k, v, do_c, dense(lse_c), dense(delta_c)]
         if segmented:
             in_specs += [
-                pl.BlockSpec((1, Tc, 1),
-                             lambda b, i: (b // heads, 0, 0)),   # seg (q rows)
+                pl.BlockSpec(dense_block,
+                             lambda b, c, g, i: (b // kv_heads, 0, 0)),
                 pl.BlockSpec((1, block_k, 1),
-                             lambda b, i: (b // heads, i, 0)),   # seg (k blk)
+                             lambda b, c, g, i: (b // kv_heads,
+                                                 c * n_i + i, 0)),
             ]
-            args += [seg_q_c[..., None], seg_kv[..., None]]
+            args += [dense(seg_q_c), seg_kv[..., None]]
+        resident = pl.BlockSpec((1, Sc, D), lambda b, c, g, i: (b, c, 0),
+                                pipeline_mode=pl.Buffered(1))
         return pl.pallas_call(
             dkv_kernel,
-            grid=(BH, S // block_k),
+            grid=(BKH, S // Sc, group, n_i),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            ],
+            out_specs=[resident, resident],
             out_shape=[
-                jax.ShapeDtypeStruct((BH, S, D), out_dtypes[0], vma=vma),
-                jax.ShapeDtypeStruct((BH, S, D), out_dtypes[1], vma=vma),
+                jax.ShapeDtypeStruct((BKH, S, D), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((BKH, S, D), jnp.float32, vma=vma),
             ],
             interpret=interpret,
             name="flash_bwd_dkv",
         )(*args)
 
-    # Under GQA the per-query-head partials leave the kernel in fp32 (the
-    # kernel accumulates fp32 anyway) so the group sum adds unrounded
-    # addends.  Transient HBM cost: dk/dv are (B·heads, S, D) fp32 before
-    # the reduction — i.e. group × (and × 2 vs a bf16 wire) the size of the
-    # final (B·kv_heads, S, D) gradients.  q-chunked accumulation (long T,
-    # :func:`_stage_chunk`) also sums in fp32 and rounds once at the end.
+    # The kernel accumulates and sums the group in fp32, so fp32 is what it
+    # hands over: (B·kv_heads, S, D), the size of the final gradients × 2
+    # against a bf16 wire.  The partials of a q-chunked sequence (long T,
+    # :func:`_stage_chunk`) add in fp32 too; everything rounds once, here.
     Cq = _stage_chunk(
         T,
-        _row_bytes(D, q.dtype.itemsize, n_padded_f32=2, segmented=segmented),
+        _row_bytes(D, q.dtype.itemsize, n_dense=3 if segmented else 2,
+                   block=block_q),
         block_q, max_stage_rows,
     )
     if Cq >= T:
-        dkv_dtypes = (
-            (jnp.float32, jnp.float32) if group > 1 else (k.dtype, v.dtype)
-        )
-        dk, dv = dkv_call(q, do, lse, delta, seg_q, 0, dkv_dtypes)
+        dk, dv = dkv_call(q, do, lse, delta, seg_q, 0)
     else:
         dk = dv = None
         for off in range(0, T, Cq):
@@ -632,24 +688,11 @@ def _bwd(segmented, heads, kv_heads, causal, block_q, block_k, interpret,
             dkc, dvc = dkv_call(
                 sl(q), sl(do), sl(lse), sl(delta),
                 sl(seg_q) if segmented else seg_q, off,
-                (jnp.float32, jnp.float32),
             )
             dk = dkc if dk is None else dk + dkc
             dv = dvc if dv is None else dv + dvc
-    if group > 1:
-        # Per-query-head kv gradients → per-kv-head (sum over each group of
-        # consecutive query heads) in fp32, rounded once at the end.
-        B = BH // heads
-
-        def group_sum(d, dtype):
-            d = d.reshape(B, kv_heads, group, S, D)
-            return d.sum(axis=2).reshape(B * kv_heads, S, D).astype(dtype)
-
-        dk = group_sum(dk, k.dtype)
-        dv = group_sum(dv, v.dtype)
-    elif dk.dtype != k.dtype:
-        dk = dk.astype(k.dtype)
-        dv = dv.astype(v.dtype)
+    dk = dk.astype(k.dtype)
+    dv = dv.astype(v.dtype)
 
     def dq_call(k_c, v_c, seg_kv_c, kv_off, out_dtype):
         """dQ over all q rows from one kv-chunk (``(1, Sc, D)`` staged k/v
@@ -752,7 +795,12 @@ def _default_block(length: int, cap: int) -> int:
     The on-chip sweep (result/flash_tpu.json, TPU v5 lite, T=2048) showed
     (block_q=128, block_k=128) — the old defaults — running 0.78× of XLA
     attention while (256, 512) runs 2.1× faster fwd+bwd: bigger kv blocks
-    amortize the online-softmax rescale over more MXU work."""
+    amortize the online-softmax rescale over more MXU work.  (512, 512),
+    which that sweep left out at D=128, is faster again (PERF.md §6, PR 36:
+    4.01 ms against 4.63 a layer's fwd+bwd at 24 / 2 heads of 128, T=4096,
+    the dK/dV kernel 1.64 against 2.12), as result/flash_tpu_d64.json had
+    it at D=64: a taller q block amortizes the dK/dV kernel's 2·block_k·D
+    fp32 carry over twice the cells."""
     b = min(cap, length)
     b -= b % 8
     while b >= 8:
@@ -893,15 +941,7 @@ def flash_attention_lse(
     if interpret is None:
         interpret = _use_interpret()
     # Sweep-informed defaults (see _default_block); explicit args win.
-    # Head-dim-aware q cap: the on-chip sweeps found fwd+bwd optima at
-    # (256, 512) for D=128 (result/flash_tpu.json) but (512, 512) for D=64
-    # (result/flash_tpu_d64.json, 10% faster than (256, 512) there) — a
-    # narrower head halves each tile's VMEM, so a taller q block pays.
-    block_q = (
-        _default_block(T, 512 if D <= 64 else 256)
-        if block_q is None
-        else block_q
-    )
+    block_q = _default_block(T, 512) if block_q is None else block_q
     block_k = _default_block(S, 512) if block_k is None else block_k
     block_q = min(block_q, T)
     block_k = min(block_k, S)
@@ -987,11 +1027,10 @@ def flash_attention(
     (``(batch, kv_len)``) masks the key side independently (defaults to
     ``segment_ids``).  Requires lengths divisible by the block sizes (pad
     upstream; the data layer's bucketing keeps XLA-friendly static shapes
-    anyway).  ``block_q``/``block_k`` default to the largest sweep-winning
-    multiple-of-8 divisors — ``block_q`` capped at 512 for head dim ≤64
-    and 256 above (on-chip optima, ``result/flash_tpu{_d64,}.json``),
-    ``block_k`` at 512; see ``_default_block``.  Pass explicit values to
-    override.  Differentiable via the flash backward.
+    anyway).  ``block_q``/``block_k`` default to the largest multiple-of-8
+    divisors up to 512, the on-chip optimum (``result/flash_tpu_d64.json``;
+    PERF.md §6, PR 36 at head dim 128); see ``_default_block``.  Pass
+    explicit values to override.  Differentiable via the flash backward.
     ``interpret=None`` auto-selects interpret mode off-TPU.
 
     ``window`` enables sliding-window (local) attention: query ``i``

@@ -353,6 +353,104 @@ def test_flash_gqa_gradients_match_oracle(causal):
         )
 
 
+#: mask kind -> (kv length, flash/oracle keyword arguments).  B = 2 batch
+#: rows of T = 64 queries in blocks of 16 / 32: every kind runs the dK/dV
+#: kernel's q loop over several trips and its kv grid over several blocks.
+def _group_case(kind):
+    T = 64
+    if kind == "cross":  # S != T
+        return 96, {}
+    if kind == "segmented":  # q and kv ids DIFFER: a swap would show
+        seg = np.repeat(np.array([[0, 1, 2, 2], [0, 0, 1, 7]], np.int32),
+                        T // 4, axis=1)
+        kv_seg = np.where(seg == 7, 8, seg).astype(np.int32)
+        return T, {"causal": True, "segment_ids": jnp.asarray(seg),
+                   "kv_segment_ids": jnp.asarray(kv_seg)}
+    return T, {"causal": {"causal": True, "window": None},
+               "full": {"causal": False, "window": None},
+               "window": {"causal": True, "window": 24}}[kind]
+
+
+@pytest.mark.parametrize("with_dlse", [False, True], ids=["o", "o+lse"])
+@pytest.mark.parametrize("kind",
+                         ["causal", "full", "window", "segmented", "cross"])
+@pytest.mark.parametrize("group", [1, 2, 12])
+def test_flash_group_gradients_match_oracle(group, kind, with_dlse):
+    """The dK/dV kernel owns a KV head's rows for its whole query group
+    (the group an inner grid axis adding into one resident fp32 block):
+    dQ / dK / dV equal the fp32 oracle's for a group of 1 (the same body,
+    an axis of length one), 2 and 12 under every mask, with and without a
+    cotangent on the logsumexp (``dlse`` folds into ``delta``)."""
+    from chainermn_tpu.ops.flash_attention import (
+        _reference_attention_lse, flash_attention_lse,
+    )
+
+    S, kw = _group_case(kind)
+    rng = np.random.RandomState(31)
+    B, T, H, D = 2, 64, 12, 16
+    KH = H // group
+    q = jnp.asarray((rng.normal(size=(B, T, H, D)) * 0.6).astype(np.float32))
+    k = jnp.asarray((rng.normal(size=(B, S, KH, D)) * 0.6).astype(np.float32))
+    v = jnp.asarray((rng.normal(size=(B, S, KH, D)) * 0.6).astype(np.float32))
+    probe = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+    lse_probe = jnp.asarray(rng.normal(size=(B, H, T)).astype(np.float32))
+
+    def loss(qkv, fn):
+        o, lse = fn(*qkv)
+        out = jnp.sum(o * probe)
+        if with_dlse:
+            # A fully masked row's lse is the NEG_INF sentinel in both.
+            out = out + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * lse_probe)
+        return out
+
+    def flash_fn(q, k, v):
+        return flash_attention_lse(q, k, v, block_q=16, block_k=32, **kw)
+
+    def oracle_fn(q, k, v):
+        return _reference_attention_lse(
+            q, k, v, kw.get("causal", False), kw.get("segment_ids"),
+            kw.get("kv_segment_ids"), kw.get("window"))
+
+    g = jax.grad(loss)((q, k, v), flash_fn)
+    og = jax.grad(loss)((q, k, v), oracle_fn)
+    assert g[1].shape == (B, S, KH, D) and g[2].shape == (B, S, KH, D)
+    for name, a, b in zip("qkv", g, og):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=1e-3,
+            err_msg=f"d{name} mismatch",
+        )
+
+
+def test_flash_gradients_at_the_training_cell_head_shape():
+    """StarCoder2's heads — 24 query / 2 KV of 128, bf16 — at a short T, at
+    the default blocks: the gradients the training cell's step takes, against
+    the fp32 oracle on the same bf16 inputs (bf16 tolerance: the kernel
+    rounds dQ/dK/dV once, from fp32 sums)."""
+    rng = np.random.RandomState(32)
+    B, T, H, KH, D = 1, 512, 24, 2, 128
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(B, T, h, D)) * 0.5, jnp.bfloat16)
+        for h in (H, KH, KH)
+    )
+    probe = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
+
+    def loss(qkv, fn):
+        return jnp.sum(fn(*qkv, causal=True).astype(jnp.float32) * probe)
+
+    def oracle(q, k, v, causal):
+        return reference_attention(q.astype(jnp.float32),
+                                   k.astype(jnp.float32),
+                                   v.astype(jnp.float32), causal=causal)
+
+    g = jax.grad(loss)((q, k, v), flash_attention)
+    og = jax.grad(loss)((q, k, v), oracle)
+    assert g[1].dtype == jnp.bfloat16 and g[1].shape == (B, T, KH, D)
+    for name, a, b in zip("qkv", g, og):
+        a = np.asarray(a, np.float32)
+        b = np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 1e-2 * np.abs(b).max(), name
+
+
 def test_flash_gqa_segments_match_oracle():
     """GQA composes with packed-segment masking (shared (B, T) segment rows
     are head-count independent)."""

@@ -17,6 +17,7 @@ import pytest
 from chainermn_tpu.ops import flash_attention, reference_attention
 from chainermn_tpu.ops.flash_attention import (
     NEG_INF,
+    _RESIDENT_BUDGET_BYTES,
     _merge_partials,
     _row_bytes,
     _stage_chunk,
@@ -123,11 +124,15 @@ def test_chunked_segments_and_padding():
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
 
 
-def test_chunked_gqa_cross_attention():
+@pytest.mark.parametrize("H, KH", [(4, 2), (12, 1)],
+                         ids=["group2", "group12"])
+def test_chunked_gqa_cross_attention(H, KH):
     # Grouped-query + cross-attention (q len ≠ kv len) through the chunked
-    # path: the kv-row index map and the group-summed dK/dV must both
-    # survive chunk offsets.
-    q, k, v = _inputs(B=2, T=64, S=192, H=4, KH=2)
+    # path: the kv-row index map and the dK/dV summed over the group inside
+    # the kernel must both survive chunk offsets — the q rows in two chunks
+    # of 32 (fp32 partials added outside) and the resident dK/dV rows in
+    # four kv chunks of 48 (a grid axis).
+    q, k, v = _inputs(B=2, T=64, S=192, H=H, KH=KH)
     want = reference_attention(q, k, v)
     got = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True,
                           max_stage_rows=48)
@@ -151,11 +156,21 @@ def test_stage_chunk_arithmetic():
     assert _stage_chunk(16384, kv128, 512, None) == 8192
     # Narrow heads double the row budget.
     assert _stage_chunk(16384, _row_bytes(64, 2), 512, None) == 16384
-    # The dK/dV kernel's lane-padded lse+delta rows triple the row cost:
-    # chunks shrink to the largest block-multiple divisor that fits.
-    qdo128 = _row_bytes(128, 2, n_padded_f32=2)
-    assert qdo128 == 1024 + 2048
-    assert _stage_chunk(16384, qdo128, 256, None) == 2048
+    # The dK/dV kernel's lse+delta rows are lane-dense (a q block a sublane
+    # row, counted at eight sublanes a block): 64 bytes a double-buffered
+    # row each, not 1024 — the cell's T=4096 stages whole (it took two
+    # chunks of 2048 when the rows were lane-padded), T=16384 in quarters.
+    qdo128 = _row_bytes(128, 2, n_dense=2, block=256)
+    assert qdo128 == 1024 + 128
+    assert _stage_chunk(4096, qdo128, 256, None) == 4096
+    assert _stage_chunk(16384, qdo128, 256, None) == 4096
+    # A segmented call stages the query rows' ids the same way; a block
+    # that is no multiple of 128 pays for the lanes it leaves empty.
+    assert _row_bytes(128, 2, n_dense=3, block=256) == 1024 + 192
+    assert _row_bytes(128, 2, n_dense=2, block=200) == 1024 + 164
+    # The resident fp32 dK + dV rows: 4096 a kv chunk at D=128.
+    assert _stage_chunk(16384, 2 * 128 * 4, 512, None,
+                        budget=_RESIDENT_BUDGET_BYTES) == 4096
     # Explicit cap wins; result stays a block-multiple divisor.
     assert _stage_chunk(256, _row_bytes(32, 4), 32, 96) == 64
     with pytest.raises(ValueError, match="stage budget"):

@@ -144,6 +144,44 @@ def test_kernel_compiles_for_v5e(name, chip):
     _assert_mosaic_took(text, launches, kernels, name)
 
 
+@pytest.mark.parametrize("t, dkv_launches, dq_launches", [
+    (4096, 1, 1), (16384, 4, 2),
+], ids=["cell_t4096", "chunked_t16384"])
+def test_dkv_kernel_sums_the_group_in_one_launch(t, dkv_launches,
+                                                 dq_launches, chip):
+    """The training cell's attention backward — StarCoder2's 24 query / 2 KV
+    heads of 128, bf16, one row of T = 4,096, causal — is ONE
+    ``flash_bwd_dkv`` launch beside one ``flash_bwd_dq``: the per-row
+    statistics stage lane-dense, so all 4,096 query rows stage at once, and
+    the query group passes over a KV head's resident fp32 dK / dV inside the
+    launch, so what leaves it is ``f32[2,T,128]`` — no gradient row per
+    QUERY head anywhere in the program and no reduction over a group axis
+    after the kernel.  Compiling is Mosaic accepting the scoped VMEM (q + do
+    double-buffered 4 MB, the two resident outputs single-buffered 4 MB,
+    the tiles).  At T = 16,384 the sequence still chunks: four launches
+    over quarters of the query rows (each with a grid axis of four kv
+    chunks), two of the dQ kernel over halves of the kv rows."""
+    qkv = [((1, t, heads, 128), jnp.bfloat16) for heads in (24, 2, 2)]
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on(chip, qkv)).compile().as_text()
+    calls = re.findall(
+        r"%(\S+) = ([^\n]*?) custom-call\([^\n]*tpu_custom_call", text)
+    dkv = [result for name, result in calls if "flash_bwd_dkv" in name]
+    assert len(dkv) == dkv_launches, calls
+    assert len([n for n, _ in calls if "flash_bwd_dq" in n]) == dq_launches
+    for result in dkv:
+        assert re.findall(r"(\w+\[[\d,]*\])", result) == [
+            "f32[2,%d,128]" % t] * 2, result
+    # (a kv-chunked dQ's partials are fp32 a query head: that is dQ's shape)
+    assert not [c for c in calls if "f32[24,%d,128]" % t in c[1]
+                and "flash_bwd_dq" not in c[0]], calls
+    assert not re.search(r"\[(1,)?2,12,\d+,128\]", text)  # no group axis
+
+
 #: the serving cells' pool: GPT-2 XL heads (25 of 64) and StarCoder2's
 #: (24 query / 2 KV heads of 128), 32 slots x 1024 positions in blocks of 16
 _XL_NB = 2049

@@ -29,7 +29,16 @@ pytestmark = [pytest.mark.tier1, pytest.mark.serving]
 
 
 @pytest.fixture(scope="module")
-def churn_engine_run(make_model, tiny_params, prompts):
+def violations_before():
+    """The watch is the process's, and another file of this xdist worker
+    may have gone over a budget on purpose before this one runs
+    (``test_elastic.py``'s rolling deploy does): the gauge is held to
+    what it read before this file's engine was built."""
+    return odev.watch().budget_violations
+
+
+@pytest.fixture(scope="module")
+def churn_engine_run(make_model, tiny_params, prompts, violations_before):
     """Sharing + speculative engine over the churny 5-requests / 3-slots
     workload (the ISSUE 7 guard geometry), with a long enough tail that
     the scheduler crosses its device-publish cadence."""
@@ -74,15 +83,16 @@ def test_watcher_counts_identical_to_cache_size(churn_engine_run):
     assert eng._step.compiles == 0
 
 
-def test_budgets_hold_and_gauge_reads_zero(churn_engine_run):
+def test_budgets_hold_and_gauge_reads_zero(churn_engine_run,
+                                           violations_before):
     eng, _, _, _ = churn_engine_run
     for wf in (eng._step, eng._spec, eng._prefill, eng._cow):
         assert not wf.over_budget, wf.program
     assert eng._prefill.budget == len(eng.prefill_ladder)
-    # Process-level accounting: nothing in this tier ever exceeded a
+    # Process-level accounting: nothing in this file ever exceeded a
     # declared budget (induced-recompile tests run on private watches).
     w = odev.watch()
-    assert w.budget_violations == 0
+    assert w.budget_violations == violations_before
     assert "compile_over_budget" not in eng.stats()
     sec = w.flight_section()
     by_name = {}
