@@ -52,6 +52,7 @@ from chainermn_tpu.models.transformer import (
     lm_loss_chunked,
     parallel_lm_specs,
 )
+from chainermn_tpu.models.hybrid import HybridLM
 from chainermn_tpu.models.decoding import (
     lm_beam_search,
     lm_speculative_generate,
@@ -86,6 +87,7 @@ __all__ = [
     "beam_decode",
     "greedy_decode",
     "TransformerLM",
+    "HybridLM",
     "lm_generate",
     "lm_beam_search",
     "lm_speculative_generate",

@@ -944,15 +944,22 @@ def lm_generate(
     )
 
 
-def _moe_stats(mutables):
-    """Mean sown ``moe_aux`` / ``moe_dropped`` across blocks (sow stores
-    per-call tuples; one forward → one entry each)."""
+#: what a capacity-MoE ``TransformerLM`` sows, merged over its blocks
+_MOE_STATS = {"moe_aux": jnp.mean, "moe_dropped": jnp.mean}
+
+
+def _sown_counters(mutables, merges):
+    """``{name: merge(values sown under name, one a layer)}`` (sow stores
+    per-call tuples; one forward -> one entry each)."""
     from flax import traverse_util
 
-    flat = traverse_util.flatten_dict(mutables["intermediates"])
-    aux = [v for k, vs in flat.items() if k[-1] == "moe_aux" for v in vs]
-    drop = [v for k, vs in flat.items() if k[-1] == "moe_dropped" for v in vs]
-    return jnp.mean(jnp.stack(aux)), jnp.mean(jnp.stack(drop))
+    flat = traverse_util.flatten_dict(mutables.get("intermediates", {}))
+    out = {}
+    for name, merge in merges.items():
+        sown = [v for k, vs in flat.items() if k[-1] == name for v in vs]
+        if sown:
+            out[name] = merge(jnp.stack(sown))
+    return out
 
 
 def lm_loss(model: nn.Module):
@@ -984,10 +991,8 @@ def lm_loss(model: nn.Module):
             loss = jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         metrics = {"ppl_log": loss}
         if moe:
-            aux, dropped = _moe_stats(mut)
-            metrics["moe_aux"] = aux
-            metrics["moe_dropped"] = dropped
-            loss = loss + model.moe_aux_weight * aux
+            metrics.update(_sown_counters(mut, _MOE_STATS))
+            loss = loss + model.moe_aux_weight * metrics["moe_aux"]
         return loss, metrics
 
     return loss_fn
@@ -1005,7 +1010,10 @@ def lm_loss_chunked(model: nn.Module, chunk_size: int = 4096):
         tokens, targets, *rest = batch
         seg = rest[0] if rest else None
         moe = getattr(model, "n_experts", 0)
-        if moe:
+        # a model whose expert layers sow routing counters names them, each
+        # with how the layers' values merge (``HybridLM.routing_counters``)
+        counters = getattr(model, "routing_counters", {})
+        if moe or counters:
             hidden, mut = model.apply(
                 {"params": params}, tokens, segment_ids=seg,
                 return_hidden=True, mutable=["intermediates"],
@@ -1021,16 +1029,16 @@ def lm_loss_chunked(model: nn.Module, chunk_size: int = 4096):
         with jax.named_scope("ce"):
             ce = chunked_softmax_cross_entropy(
                 hidden.astype(jnp.float32), head["kernel"], targets,
-                bias=head["bias"], chunk_size=chunk_size,
+                bias=head.get("bias"), chunk_size=chunk_size,
             )
             mask = (targets >= 0).astype(jnp.float32)
             loss = jnp.sum(ce) / jnp.maximum(jnp.sum(mask), 1.0)
         metrics = {"ppl_log": loss}
         if moe:
-            aux, dropped = _moe_stats(mut)
-            metrics["moe_aux"] = aux
-            metrics["moe_dropped"] = dropped
-            loss = loss + model.moe_aux_weight * aux
+            metrics.update(_sown_counters(mut, _MOE_STATS))
+            loss = loss + model.moe_aux_weight * metrics["moe_aux"]
+        if counters:
+            metrics.update(_sown_counters(mut, counters))
         return loss, metrics
 
     return loss_fn

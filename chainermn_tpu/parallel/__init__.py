@@ -16,6 +16,10 @@ first-class citizens:
 * :mod:`moe` — expert parallelism: capacity-based top-k token dispatch over an
   ``expert`` mesh axis via ``all_to_all`` (built on the same primitive the
   reference exposed as ``chainermn.functions.alltoall``).
+* :mod:`held_experts` — that layer minus its exchange, dropless: a chip told
+  which contiguous range of a layer's experts it holds routes over all of
+  them, sorts the held (token, choice) pairs by expert and runs a grouped
+  matmul over them (one chip's share of an expert-parallel group).
 """
 
 from chainermn_tpu.parallel.ring_attention import (
@@ -31,6 +35,11 @@ from chainermn_tpu.parallel.zigzag import (
     zigzag_unshard,
 )
 from chainermn_tpu.parallel.moe import MoELayer, moe_combine, moe_dispatch
+from chainermn_tpu.parallel.held_experts import (
+    held_experts_ffn,
+    held_range,
+    sigmoid_topk_route,
+)
 
 __all__ = [
     "ring_attention",
@@ -44,4 +53,7 @@ __all__ = [
     "moe_dispatch",
     "moe_combine",
     "MoELayer",
+    "held_experts_ffn",
+    "held_range",
+    "sigmoid_topk_route",
 ]

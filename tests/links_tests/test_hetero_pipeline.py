@@ -304,7 +304,8 @@ def test_hetero_pipeline_beats_replicated_wallclock(devices):
     the shared-core mesh; measured 1.26x idle / 1.03x loaded."""
     import sys
 
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
+    sys.path.insert(0, os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "..")))
     from benchmarks.hetero_pipeline import measure
 
     best = None

@@ -12,7 +12,11 @@ test stays robust on loaded CI machines.
 import sys
 import os
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
+# the repo's root, normalised: what is imported through it (``perfbench``
+# too, by a later test file) keeps a ``__file__`` that compares equal to paths
+# built elsewhere
+sys.path.insert(0, os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..")))
 
 from benchmarks.pipeline import measure  # noqa: E402
 

@@ -197,6 +197,7 @@ def test_spans_share_one_monotonic_base():
     from the SAME clock (perf_counter via the epoch anchor) — two
     back-to-back spans may not overlap or regress within a rank, and the
     derived wall_start tracks t_mono exactly."""
+    import math
     import time
 
     from chainermn_tpu.observability import tracing as otrace
@@ -210,7 +211,17 @@ def test_spans_share_one_monotonic_base():
     assert a["seq"] == 0 and b["seq"] == 1
     # Second span opens AFTER the first closes on the shared clock.
     assert b["t_mono"] >= a["t_mono"] + a["ms"] / 1e3 - 1e-6
+    # Both fields are rounded to 1e-6 s (half a microsecond each way, each)
+    # and the anchor's sum is taken at the wall clock's magnitude (~1.8e9 s,
+    # where a double's spacing is 2.4e-7 s): that much the arithmetic can
+    # differ by, and no more.  A wall clock read on its own (time.time() at
+    # span open) would miss by the microseconds between the two reads.
     for rec in (a, b):
+        slack = 1e-6 + 4 * math.ulp(rec["wall_start"])
         assert rec["wall_start"] == pytest.approx(
-            otrace.mono_to_wall(rec["t_mono"]), abs=1e-6
+            otrace.mono_to_wall(rec["t_mono"]), abs=slack
         )
+    # ... and through the one anchor the two clocks keep the same distance
+    # between the spans.
+    assert b["wall_start"] - a["wall_start"] == pytest.approx(
+        b["t_mono"] - a["t_mono"], abs=2e-6 + 4 * math.ulp(b["wall_start"]))

@@ -30,6 +30,8 @@ from jax.experimental.compilation_cache import (  # noqa: E402
 )
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
+import chainermn_tpu.ops.grouped_matmul  # noqa: E402,F401  (steered below)
+
 from chainermn_tpu.ops import (  # noqa: E402
     flash_attention,
     paged_decode_attention,
@@ -56,7 +58,8 @@ def chip():
     # (ops/__init__ re-exports it over the submodule) — go through
     # sys.modules for the modules.
     mods = [sys.modules["chainermn_tpu.ops.flash_attention"],
-            sys.modules["chainermn_tpu.ops.decode_attention"]]
+            sys.modules["chainermn_tpu.ops.decode_attention"],
+            sys.modules["chainermn_tpu.ops.grouped_matmul"]]
     saved = [m._use_interpret for m in mods]
     for m in mods:
         m._use_interpret = lambda: False
@@ -267,3 +270,93 @@ def test_pool_write_and_kernel_share_one_layout(tokens, per_slot, launches,
     at_rest = [a for a in entry.split(", ") if shape in a]
     assert len(at_rest) == 1 and at_rest[0].startswith(
         "bf16" + shape + "{2,1,0"), at_rest
+
+
+# ------------------------------------------------- the hybrid cell's layers
+def _hybrid_attention():
+    """A ``*`` layer's attention at the cell's shape: 32 query / 2 KV heads
+    of 128, one row of T = 8,192."""
+    qkv = [((1, 8192, heads, 128), jnp.bfloat16) for heads in (32, 2, 2)]
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), qkv
+
+
+def _hybrid_scan():
+    """An ``M`` layer's chunked scan, forward and autodiff's backward: 64
+    heads of 64, 8 groups of 128 state columns, chunks of 128, T = 8,192."""
+    from chainermn_tpu.ops.ssd_scan import ssd_scan
+
+    T, Hm, P, G, N = 8192, 64, 64, 8, 128
+    shapes = [((1, T, Hm, P), jnp.bfloat16), ((1, T, Hm), jnp.float32),
+              ((Hm,), jnp.float32), ((1, T, G, N), jnp.bfloat16),
+              ((1, T, G, N), jnp.bfloat16), ((Hm,), jnp.float32)]
+
+    def loss(x, dt, A, B, C, D):
+        return jnp.sum(ssd_scan(x, dt, A, B, C, chunk=128, D=D) ** 2)
+
+    return jax.grad(loss, argnums=tuple(range(6))), shapes
+
+
+def _hybrid_experts():
+    """An ``E`` layer's routed part, forward and backward: 8,192 tokens,
+    top-6 of 128, the 16 experts held, 2688 -> 1856 -> 2688."""
+    from chainermn_tpu.parallel.held_experts import held_experts_ffn
+
+    N, D, F, E, k = 8192, 2688, 1856, 16, 6
+    shapes = [((N, D), jnp.bfloat16), ((N, k), jnp.int32),
+              ((N, k), jnp.float32), ((E, D, F), jnp.bfloat16),
+              ((E, F, D), jnp.bfloat16)]
+
+    def loss(x, experts, weights, up, down):
+        return jnp.sum(held_experts_ffn(
+            x, experts, weights, up, down, lo=0,
+            row_bound=3 * N * k // 8)[0] ** 2)
+
+    return jax.grad(loss, argnums=(0, 2, 3, 4)), shapes
+
+
+def test_hybrid_attention_backward_walks_two_kv_chunks(chip):
+    """T = 8,192 is two resident 4,096-row chunks of the dK/dV kernel (two
+    launches, results ``f32[2,8192,128]``: the group of 16 summed inside)
+    beside ONE dQ launch — the geometry the hybrid cell alone runs."""
+    fn, shapes = _hybrid_attention()
+    text = jax.jit(fn).lower(*_on(chip, shapes)).compile().as_text()
+    calls = re.findall(
+        r"%(\S+) = ([^\n]*?) custom-call\([^\n]*tpu_custom_call", text)
+    assert len([n for n, _ in calls if "flash_bwd_dkv" in n]) == 2, calls
+    assert len([n for n, _ in calls if "flash_bwd_dq" in n]) == 1, calls
+    assert not re.search(r"f32\[32,8192,128\]", " ".join(
+        r for n, r in calls if "flash_bwd_dkv" in n))
+
+
+def test_hybrid_scan_compiles_with_its_backward(chip):
+    """No kernel of ours: XLA's own dots and fusions, and a working set (the
+    float32 ``(64, 64, 128, 128)`` decay tiles and what autodiff keeps of
+    them) that stays under 3 GB — what a rematerialised block may take of
+    the 16 GB beside 10 GB of parameters and gradients."""
+    fn, shapes = _hybrid_scan()
+    compiled = jax.jit(fn).lower(*_on(chip, shapes)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_hybrid_expert_layer_is_grouped_matmuls_and_gathers(chip):
+    """The routed part is six launches of the repository's own grouped
+    matmul (``ops/grouped_matmul.py``: up and down forward, their two
+    ``dy . W^T`` and two ``x^T . dy``), found by name, at the cell's widths
+    (a group's 2688 x 1856 weights double-buffered: Mosaic takes the raised
+    VMEM limit); both widths of the row buffer compile, the usual one and
+    the one that holds every pair."""
+    fn, shapes = _hybrid_experts()
+    text = jax.jit(fn).lower(*_on(chip, shapes)).compile().as_text()
+    calls = re.findall(r"%(\S+) = [^\n]*tpu_custom_call", text)
+    mm = [c for c in calls if "grouped_matmul" in c and "_dw" not in c]
+    dw = [c for c in calls if "grouped_matmul_dw" in c]
+    # in each of the two branches of the layer's ``lax.cond`` (the usual
+    # buffer, and all pairs): up and down, the same again where the backward
+    # works the branch's forward out anew, their two ``dy . W^T``; and the
+    # two ``x^T . dy``
+    assert (len(mm), len(dw)) == (2 * 6, 2 * 2), calls
