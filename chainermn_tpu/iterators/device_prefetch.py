@@ -21,6 +21,8 @@ from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
+from chainermn_tpu.observability import enabled as _obs_enabled
+from chainermn_tpu.observability.tracing import UnitLedger as _UnitLedger
 from chainermn_tpu.observability.tracing import annotate as _annotate
 
 
@@ -73,6 +75,14 @@ class DevicePrefetchIterator:
         self.iteration = int(getattr(iterator, "iteration", 0))
         self.is_new_epoch = False
         self._epoch_detail = float(getattr(iterator, "epoch_detail", 0.0))
+        #: Unit ledger: every ``__next__`` leaves a record of its wait by
+        #: phase (host batch, device put), joined to a profiler's trace by
+        #: the ``n=`` ordinal; rides the ``CMN_OBS`` switch, latched here.
+        self._waits = 0
+        self._units = (
+            _UnitLedger("input_wait", ordinal="n") if _obs_enabled()
+            else None
+        )
         self._fill()
 
     # ------------------------------------------------------------- pipeline
@@ -111,7 +121,9 @@ class DevicePrefetchIterator:
     def __next__(self):
         if not self._queue:
             raise StopIteration
-        with _annotate("cmn_input_wait"):
+        n = self._waits
+        self._waits += 1
+        with _annotate("cmn_input_wait", ledger=self._units, n=n):
             e = self._queue.popleft()
             self.epoch = e.epoch
             self.is_new_epoch = e.is_new_epoch

@@ -15,8 +15,10 @@ Four cooperating pieces, all default-on and all bounded:
   detector, and training-health guard publish into it.
 * :mod:`~chainermn_tpu.observability.tracing` — span records of host-plane
   ops (send/recv/bcast_obj/…, checkpoint save/restore, guard votes) in a
-  bounded in-memory ring, plus ``jax.profiler`` trace annotations around
-  the train step so device profiles line up with host spans.
+  bounded in-memory ring; host phases named by ``annotate`` (``cmn_*``):
+  ``jax.profiler`` trace annotations under a profiler session, and — in
+  every run — a :class:`~chainermn_tpu.observability.tracing.UnitLedger`
+  record per scheduler tick and per input wait with its time by phase.
 * :mod:`~chainermn_tpu.observability.flight` — flight recorder: snapshots
   the span ring + last-K metric samples + resilience state to a per-rank
   JSONL file on :class:`~chainermn_tpu.resilience.PeerFailedError` /
@@ -127,9 +129,12 @@ from chainermn_tpu.observability.tracing import (  # noqa: E402
     Span,
     SpanRing,
     Tracer,
+    UnitLedger,
+    UnitRecord,
     annotate,
     chrome_trace_events,
     tracer,
+    unit_ledger,
     write_chrome_trace,
 )
 from chainermn_tpu.observability.slo import (  # noqa: E402
@@ -196,6 +201,9 @@ __all__ = [
     "tracer",
     "chrome_trace_events",
     "annotate",
+    "UnitLedger",
+    "UnitRecord",
+    "unit_ledger",
     "write_chrome_trace",
     "SLOMonitor",
     "rolling_quantile",

@@ -15,11 +15,14 @@ Two integration layers:
   fault injector uses), the checkpointer, and the health guard.  Each span
   also feeds the metrics registry (``host_op.<op>`` count/bytes/latency),
   so the aggregated feed carries op rates without reading the ring.
-* **Device annotations** — :func:`annotate` writes a host phase into the
-  profiler's own trace as a ``jax.profiler.TraceAnnotation`` with its
-  counts (the serving tick's phases, every watched program's dispatch and
-  compile, the input iterators), beside the ``jax.named_scope`` s that
-  name device work by layer; both are free while no profiler runs.
+* **Host phases** — :func:`annotate` names a host phase (the serving
+  tick's phases, every watched program's dispatch and compile, the input
+  iterators).  Under a profiler session it is a
+  ``jax.profiler.TraceAnnotation`` with its counts, beside the
+  ``jax.named_scope`` s that name device work by layer; inside a *unit* of
+  a :class:`UnitLedger` (a scheduler tick, a wait for an input batch) it
+  also leaves its seconds in the unit's record, whether a profiler runs
+  or not, and the unit's ordinal joins the two.
 
 Overhead discipline: a span is one ``perf_counter`` pair, one small object,
 one deque append, and three instrument updates — all gated on
@@ -464,33 +467,238 @@ def write_chrome_trace(path: str, events, rank: int = 0) -> str:
     return path
 
 
-# ------------------------------------------------------- device annotations
-# Everything below is written into the PROFILER's own trace and nowhere
-# else: host phases as ``jax.profiler.TraceAnnotation`` s named ``cmn_*``
-# (this section), device work as ``jax.named_scope`` s at the boundaries
-# that are not modules (models/, ops/, optimizers/, serving/engine.py).
-# Both sit on the profiler's clock beside the device operations; both are
-# free while no profiler session is open.  ``docs/observability.md``,
-# "Profiler-clock spans and scopes", lists the vocabulary.
+# ------------------------------------------- host phases and the unit ledger
+# Host phases are named in ONE way: ``annotate("cmn_...")``.  Under a
+# profiler session a phase is a ``jax.profiler.TraceAnnotation`` on the
+# profiler's clock, beside the device operations and the
+# ``jax.named_scope`` s that name device work (models/, ops/, optimizers/,
+# serving/engine.py).  With or without one, a phase that closes inside a
+# *unit* — an outermost span opened through a :class:`UnitLedger`: one
+# scheduler tick, one wait for an input batch — adds its seconds to that
+# unit's record, so every unit of a run leaves its time by phase, not only
+# the few a profiler covered.  ``docs/observability.md``, "Profiler-clock
+# spans and scopes", lists the vocabulary.
+_perf_counter = time.perf_counter
+_tls = threading.local()
+
+
 def _taken(counts: dict) -> dict:
     return {k: v() if callable(v) else v for k, v in counts.items()}
 
 
-class _Span(_TraceAnnotation):
-    """A ``TraceAnnotation`` whose counts may be callables, called when
-    the span is recorded — that is, never while no profiler runs."""
+class UnitRecord:
+    """One closed unit of a :class:`UnitLedger`, fixed in shape: the
+    unit's ``ordinal``, its start ``t_mono`` (``perf_counter``, the
+    module's one clock base: :data:`EPOCH_PERF`), its ``seconds``, per
+    phase name the ``calls`` that closed inside it and their inclusive
+    ``secs`` (a phase nested in another counts in both), ``direct`` — the
+    seconds of the unit's immediate children, which do add up to at most
+    ``seconds`` — and the summed ``counts`` the ledger keeps
+    (``"cmn_serve_prefill.tokens"``)."""
 
-    def __init__(self, name: str, **counts):
-        super().__init__(name, **_taken(counts))
+    __slots__ = ("ordinal", "t_mono", "seconds", "calls", "secs", "direct",
+                 "counts")
+
+    def __init__(self, ordinal: int, t_mono: float):
+        self.ordinal = ordinal
+        self.t_mono = t_mono
+        self.seconds = 0.0
+        # str -> number dicts: the collector never tracks them
+        self.calls: Dict[str, int] = {}
+        self.secs: Dict[str, float] = {}
+        self.direct: Dict[str, float] = {}
+        self.counts: Dict[str, int] = {}
+
+    @property
+    def rows(self) -> Dict[str, tuple]:
+        """``{name: (calls, seconds)}``."""
+        return {k: (n, self.secs[k]) for k, n in self.calls.items()}
+
+    def to_dict(self) -> dict:
+        return {
+            "ordinal": self.ordinal, "t_mono": round(self.t_mono, 6),
+            "ms": round(self.seconds * 1e3, 3),
+            "rows": {k: [n, round(self.secs[k] * 1e3, 3)]
+                     for k, n in self.calls.items()},
+            "counts": dict(self.counts),
+        }
+
+
+#: Units a :class:`UnitLedger` holds unless told otherwise: a 45 s window
+#: of the backlog cell is ~1,710 ticks, ~2,730 at a 16.5 ms tick.
+UNIT_RING_CAPACITY = 4096
+
+#: The newest ledger of each kind.  A ledger holds numbers and names and
+#: nothing of its owner, so this pins no scheduler, engine or device
+#: buffer — and a reader that comes after the owner is gone (a reducer
+#: after the run, a post-mortem) still finds the run's units.
+_ledgers: Dict[str, "UnitLedger"] = {}
+
+
+def unit_ledger(kind: str) -> Optional["UnitLedger"]:
+    """The newest :class:`UnitLedger` of ``kind`` (``"serve_tick"``,
+    ``"input_wait"``), or ``None``."""
+    return _ledgers.get(kind)
+
+
+class UnitLedger:
+    """A bounded ring of :class:`UnitRecord` s, one per unit of its owner.
+
+    The owner opens a unit with ``annotate(name, ledger=self, <ordinal>=i)``;
+    until that span closes, every ``annotate`` span that closes on the
+    same thread adds one call and its inclusive seconds to the unit's row
+    of that name (a would-be unit of another ledger too: inside a unit it
+    is a child).  ``keep`` names, per phase, the counts worth summing —
+    plain integers only; a callable count is never called for the ledger.
+
+    The ordinal is the join to a profiler's trace: the traced span of the
+    unit carries the same count, so ledger unit ``i`` and traced
+    ``cmn_serve_tick(tick=i)`` are one tick, on any clock.
+
+    Capacity :data:`UNIT_RING_CAPACITY` units unless ``capacity`` says
+    otherwise; ``evicted`` counts what fell out.  Owners build one only while observability is
+    on (``CMN_OBS``), so with the switch off no unit ever opens.  The
+    newest ledger of a kind is found by :func:`unit_ledger` and rides
+    every flight record as ``units.<kind>``."""
+
+    def __init__(self, kind: str, ordinal: str,
+                 keep: Optional[Dict[str, tuple]] = None,
+                 capacity: int = UNIT_RING_CAPACITY):
+        if capacity < 1:
+            raise ValueError(f"unit ring capacity must be >= 1: {capacity}")
+        self.kind = kind
+        self.ordinal = ordinal
+        self.capacity = capacity
+        self._keep = {name: tuple((k, f"{name}.{k}") for k in keys)
+                      for name, keys in (keep or {}).items()}
+        self._ring: deque = deque(maxlen=capacity)
+        #: units ever closed (evicted = total - len).
+        self.total = 0
+        _ledgers[kind] = self
+        from chainermn_tpu.observability import flight as _flight
+
+        _flight.register_provider(f"units.{kind}", self.flight_state)
+
+    # No lock: one thread closes units (a deque append is atomic), and a
+    # reader may be a signal handler on that very thread (SIGUSR1 flight
+    # snapshot) — a lock held by the interrupted append would never be
+    # released to it.  A torn read shows one unit ago.
+    def _close(self, rec: UnitRecord) -> None:
+        self._ring.append(rec)
+        self.total += 1
+
+    def units(self) -> List[UnitRecord]:
+        """The units still in the ring, oldest first."""
+        while True:
+            try:
+                return list(self._ring)
+            except RuntimeError:  # another thread appended meanwhile
+                continue
+
+    @property
+    def evicted(self) -> int:
+        return max(0, self.total - len(self._ring))
+
+    def __len__(self) -> int:
+        return len(self._ring)
+
+    def flight_state(self, last: int = 16) -> dict:
+        """The flight record's ``units.<kind>`` section: totals and the
+        last units by phase."""
+        units = self.units()
+        return {"kind": self.kind, "ordinal": self.ordinal,
+                "units": self.total, "evicted": self.evicted,
+                "capacity": self.capacity,
+                "last": [u.to_dict() for u in units[-last:]]}
+
+
+class _Timed:
+    """A phase on ``perf_counter``: ``t0``, ``t1`` and ``seconds`` once
+    closed.  Inside a unit, closing it books the unit's row of its name."""
+
+    __slots__ = ("_name", "_unit", "_keep", "t0", "t1")
+
+    def __init__(self, name: str, unit: Optional["_Unit"], counts):
+        self._name = name
+        self._unit = unit
+        self.t0 = self.t1 = 0.0
+        if unit is None:
+            self._keep = None
+        else:
+            self._keep = keep = unit._keeps.get(name)
+            if keep is not None:
+                self._sum(counts)
+
+    def _sum(self, counts: dict) -> None:
+        kept = self._unit._rec.counts
+        for key, flat in self._keep:
+            v = counts.get(key)
+            if type(v) is int:
+                kept[flat] = kept.get(flat, 0) + v
 
     def set_metadata(self, **counts) -> None:
-        super().set_metadata(**_taken(counts))
+        if self._keep is not None:
+            self._sum(counts)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self):
+        if self._unit is not None:
+            self._unit._depth += 1
+        self.t0 = _perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.t1 = t1 = _perf_counter()
+        unit = self._unit
+        if unit is not None:
+            rec, name, dt = unit._rec, self._name, t1 - self.t0
+            calls, secs = rec.calls, rec.secs
+            if name in calls:
+                calls[name] += 1
+                secs[name] += dt
+            else:
+                calls[name] = 1
+                secs[name] = dt
+            unit._depth -= 1
+            if not unit._depth:
+                rec.direct[name] = rec.direct.get(name, 0.0) + dt
+        return False
+
+
+class _Unit(_Timed):
+    """The outermost span of a unit: the thread's open unit from enter to
+    exit, where it hands its record to the ledger."""
+
+    __slots__ = ("_ledger", "_rec", "_keeps", "_depth")
+
+    def __init__(self, name: str, ledger: UnitLedger, counts):
+        super().__init__(name, None, None)
+        self._ledger = ledger
+        self._keeps = ledger._keep
+        self._depth = 0
+        self._rec = UnitRecord(counts.get(ledger.ordinal, ledger.total), 0.0)
+
+    def __enter__(self):
+        _tls.unit = self
+        self._rec.t_mono = self.t0 = _perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.t1 = _perf_counter()
+        _tls.unit = None
+        self._rec.seconds = self.t1 - self.t0
+        self._ledger._close(self._rec)
+        return False
 
 
 class _NoSpan:
-    """What :func:`annotate` hands back while nothing is being profiled."""
+    """What :func:`annotate` hands back where nothing records a phase."""
 
     __slots__ = ()
+    t0 = t1 = seconds = 0.0
 
     def __enter__(self):
         return self
@@ -505,25 +713,80 @@ class _NoSpan:
 _NO_SPAN = _NoSpan()
 
 
-def annotate(name: str, **counts):
-    """A host phase on the profiler's clock: a
-    ``jax.profiler.TraceAnnotation`` called ``name`` (prefix ``cmn_``)
-    whose ``counts`` arrive in the trace as the event's stats.  Spans
-    nest: a span's parent is the span open around it on the same thread;
-    spans of one request carry ``req=``.
+class _Span(_TraceAnnotation):
+    """Under a profiler session: the ``TraceAnnotation`` (its counts may be
+    callables, called now that the span is really recorded) around the
+    timed phase (the shared no-op where nothing times it), so a traced
+    unit exists twice, with one ordinal."""
 
-    A count given as a callable is called only when the span is really
-    recorded, so one that costs something to take (a sum over the live
-    slots) costs nothing otherwise.  Counts known only at the end of the
-    phase go in through ``span.set_metadata(tokens=...)`` before the
-    ``with`` block closes.
+    def __init__(self, name: str, timed, counts):
+        super().__init__(name, **_taken(counts))
+        self._timed = timed
 
-    While no profiler session is open (``TraceAnnotation.is_enabled()``
-    is false) this is one flag read and returns a shared no-op span;
-    with ``CMN_OBS=0`` it records nothing either."""
-    if not _TraceAnnotation.is_enabled() or not _obs_enabled():
-        return _NO_SPAN
-    return _Span(name, **counts)
+    def set_metadata(self, **counts) -> None:
+        super().set_metadata(**_taken(counts))
+        self._timed.set_metadata(**counts)
+
+    def __enter__(self):
+        super().__enter__()
+        self._timed.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._timed.__exit__(*exc)
+        return super().__exit__(*exc)
+
+    @property
+    def t0(self) -> float:
+        return self._timed.t0
+
+    @property
+    def t1(self) -> float:
+        return self._timed.t1
+
+    @property
+    def seconds(self) -> float:
+        return self._timed.seconds
+
+
+def annotate(name: str, *, ledger: Optional[UnitLedger] = None,
+             timed: bool = False, **counts):
+    """A host phase called ``name`` (prefix ``cmn_``) with its ``counts``.
+    Spans nest: a span's parent is the span open around it on the same
+    thread; spans of one request carry ``req=``.
+
+    * Under a profiler session (and ``CMN_OBS`` on) it is a
+      ``jax.profiler.TraceAnnotation`` whose counts arrive in the trace as
+      the event's stats.
+    * ``ledger=`` makes it a *unit* of that :class:`UnitLedger` (unless a
+      unit is already open on the thread: then it is a child like any
+      other), and inside a unit every span is timed and booked to the
+      unit's record, profiler or not.
+    * ``timed=True`` keeps the span's one clock pair (``span.seconds``,
+      ``span.t0``, ``span.t1``) where nothing else would: a publisher that
+      needs the phase's duration reads it off the span and takes no clock
+      of its own.
+
+    A count given as a callable is called only under a profiler session,
+    so one that costs something to take (a sum over the live slots) costs
+    nothing otherwise; the ledger sums plain integers alone.  Counts known
+    only at the end of the phase go in through
+    ``span.set_metadata(tokens=...)`` before the ``with`` block closes.
+
+    Outside a unit, with no profiler session, no ``ledger`` and no
+    ``timed``, this is two flag reads and returns a shared no-op span."""
+    unit = getattr(_tls, "unit", None)
+    if unit is not None:
+        inner = _Timed(name, unit, counts)
+    elif ledger is not None:
+        inner = _Unit(name, ledger, counts)
+    elif timed:
+        inner = _Timed(name, None, None)
+    else:
+        inner = _NO_SPAN
+    if _TraceAnnotation.is_enabled() and _obs_enabled():
+        return _Span(name, inner, counts)
+    return inner
 
 
 #: Process-wide tracer (lazy singleton, like the metrics registry).

@@ -622,6 +622,7 @@ def sharded_paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
 def context_widths(max_blocks: int):
     """The widths, in blocks, :func:`pool_context_attend` may read of a
     table ``max_blocks`` wide: an eighth, a quarter, a half and the whole

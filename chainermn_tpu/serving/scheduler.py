@@ -82,6 +82,13 @@ switch; ISSUE 6):
   :meth:`Scheduler.export_trace` writes the whole run as Chrome
   trace-event JSON — load it at ui.perfetto.dev (slots as tracks,
   requests as nested slices, evictions as instant events);
+* every tick leaves one record in a
+  :class:`~chainermn_tpu.observability.tracing.UnitLedger`
+  (``observability.unit_ledger("serve_tick")``): its seconds and, per
+  ``cmn_*`` phase that closed inside it, calls, seconds and a few summed
+  counts — the last ticks by phase ride every flight record, and a
+  profiler's trace of a few ticks is joined to the whole run by the
+  ``tick=`` ordinal;
 * a :class:`~chainermn_tpu.observability.slo.SLOMonitor` tracks TTFT,
   queue-wait, and per-token latency (``serve.slo.*``) with rolling
   p50/p95 and p95-drift detection, checked every
@@ -121,6 +128,15 @@ from chainermn_tpu.observability.metrics import (
 )
 from chainermn_tpu.ops.decode_attention import context_blocks
 from chainermn_tpu.serving.kv_pool import PoolExhausted, blocks_for
+
+#: The counts of the tick's phases that the unit ledger sums a tick: plain
+#: integers that are at hand where the span opens or closes.
+_LEDGER_COUNTS = {
+    "cmn_serve_prefill": ("tokens", "padded", "final", "ctx_blocks"),
+    "cmn_serve_decode": ("live",),
+    "cmn_serve_emit": ("tokens", "retired"),
+    "cmn_serve_admit": ("admitted",),
+}
 
 
 @dataclass
@@ -320,6 +336,10 @@ class Scheduler:
         self._admit_seq = 0
         self.completions: List[Completion] = []
         self._iterations = 0
+        #: ticks ever run: the ordinal of a ``cmn_serve_tick`` unit
+        #: (``_iterations`` counts decode steps and repeats while no slot
+        #: decodes, as in the first ticks of a pool fill).
+        self._ticks = 0
         self._emitted = 0  # tokens ever emitted (cmn_serve_emit counts)
         #: True while non-final prefill chunks dispatched since the last
         #: device readback may still be draining — the next decode step's
@@ -515,6 +535,20 @@ class Scheduler:
             )
         else:
             self.timeline = None
+        #: Unit ledger: every tick of the run leaves a record of its time
+        #: by phase (``cmn_*`` spans closing inside ``cmn_serve_tick``),
+        #: joined to a profiler's trace by the ``tick=`` ordinal.  Rides
+        #: the master switch with the rest.
+        self._units = _tracing.UnitLedger(
+            "serve_tick", ordinal="tick", keep=_LEDGER_COUNTS,
+        ) if enabled else None
+        #: Whoever publishes a phase's duration (histograms, the SLO
+        #: stream, the timeline) reads it off the phase's span; outside a
+        #: unit the span keeps its clock pair only if someone does.
+        self._timed = (
+            reg is not None or self.slo is not None
+            or self.timeline is not None
+        )
         # Flight-record provider — ungated by CMN_OBS, like the recorder
         # itself (it answers only to CMN_OBS_FLIGHT*).  Keyed, so the
         # newest scheduler replaces a finished one's state; held via
@@ -1258,18 +1292,19 @@ class Scheduler:
         chunk[: end - p0] = slot.text[p0:end]
         last = end == len(slot.text)
         tc = self.clock.now()
-        t0 = time.perf_counter()
         # ctx_blocks: the table width the program reads for this chunk —
         # the choice it makes itself from the padded chunk's last position.
-        with _annotate("cmn_serve_prefill", req=slot.entry.req.id,
-                       slot=slot.idx, p0=p0, tokens=end - p0, padded=size,
-                       final=int(last), ctx_blocks=lambda: context_blocks(
-                           p0 + size - 1, eng.block_len, eng.max_blocks)):
+        with _annotate("cmn_serve_prefill", timed=self._timed,
+                       req=slot.entry.req.id, slot=slot.idx, p0=p0,
+                       tokens=end - p0, padded=size, final=int(last),
+                       ctx_blocks=context_blocks(
+                           p0 + size - 1, eng.block_len, eng.max_blocks),
+                       ) as span:
             tok = eng.prefill(
                 slot.idx, chunk, p0, slot.table,
                 last_idx=(end - p0 - 1) if last else -1,
             )
-        dur_ms = (time.perf_counter() - t0) * 1e3
+        dur_ms = span.seconds * 1e3
         self._m_prefill.observe(dur_ms)
         if self.ledger is not None:
             # Tokens actually COMPUTED this chunk (pad positions are
@@ -1325,7 +1360,8 @@ class Scheduler:
         ]
         if not live:
             return False
-        # Phases on the profiler's clock (free while none runs): build =
+        # Phases (rows of the tick's ledger record; under a profiler
+        # session also spans on its clock): build =
         # block allocation + the four control vectors; the engine's own
         # upload/dispatch/readback spans; publish = histograms, timeline,
         # SLO / incident / memory / device cadence; emit = ledger, policy,
@@ -1334,7 +1370,7 @@ class Scheduler:
         # handed to the step whatever the contexts are (the paged kernel
         # walks only the resident ones; the gathered fallback reads all).
         with _annotate("cmn_serve_decode") as span:
-            with _annotate("cmn_serve_build"):
+            with _annotate("cmn_serve_build", timed=self._timed) as build:
                 S = self.engine.capacity
                 k = self.engine.spec_k
                 tokens = np.zeros((S,), np.int32)
@@ -1371,7 +1407,6 @@ class Scheduler:
             mixed = self._unsynced_prefill
             self._iterations += 1
             tc = self.clock.now()
-            t0 = time.perf_counter()
             if self._fault is not None:
                 # ``skew@serve_step:N:ms`` — inside the timed window, so an
                 # injected stretch lands in this iteration's histogram
@@ -1383,8 +1418,10 @@ class Scheduler:
                 )
             else:
                 out = self.engine.step(tokens, pos, tables, active)
-            dur_ms = (time.perf_counter() - t0) * 1e3
-            with _annotate("cmn_serve_publish"):
+            with _annotate("cmn_serve_publish", timed=self._timed) as pub:
+                # The step's duration is the two readings its neighbours
+                # already took: from the close of build to here.
+                dur_ms = (pub.t0 - build.t1) * 1e3
                 # The token readback above drained the dispatch queue: any
                 # prefill work queued before this step has now been absorbed
                 # into dur_ms — book the contaminated iteration separately so
@@ -1565,11 +1602,15 @@ class Scheduler:
         work at all).  :meth:`run` is a tick loop over one scheduler;
         the :class:`~chainermn_tpu.serving.router.Router` interleaves
         ticks across replicas on a shared clock."""
-        # The tick's phases are on the profiler's clock as nested
-        # ``cmn_serve_*`` spans with their counts (free while no profiler
-        # runs; vocabulary in docs/observability.md).
+        # The tick is a unit of the ledger and its phases are nested
+        # ``cmn_serve_*`` spans with their counts: rows of the unit's
+        # record in every run, on the profiler's clock as well under a
+        # session (vocabulary in docs/observability.md).
         progressed = False
-        with _annotate("cmn_serve_tick", iter=self._iterations):
+        tick = self._ticks
+        self._ticks += 1
+        with _annotate("cmn_serve_tick", ledger=self._units, tick=tick,
+                       iter=self._iterations):
             with _annotate("cmn_serve_deadlines"):
                 if self._cancel_deadlines():
                     progressed = True
@@ -1748,6 +1789,9 @@ class Scheduler:
         if self.timeline is not None:
             state["timeline_events"] = len(self.timeline)
             state["timeline_dropped"] = self.timeline.dropped
+        if self._units is not None:
+            state["ledger_units"] = self._units.total
+            state["ledger_evicted"] = self._units.evicted
         return state
 
     def export_trace(self, path: str, rank: int = 0) -> Optional[str]:
