@@ -147,7 +147,7 @@ class _DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, h, segment_ids=None, cache=None, decode_pos=None,
                  rope=None, rolling=False, block_tables=None,
-                 slot_mask=None):
+                 slot_mask=None, chunk_rows=0):
         """Full path: ``h`` (B, T, D) → (B, T, D).  Decode path (``cache``
         given): ``h`` (B, 1, D) for position ``decode_pos``, attends against
         the KV cache, returns ``(h, new_cache)``.  Both paths create the
@@ -160,7 +160,9 @@ class _DecoderBlock(nn.Module):
         mapped through its block table.  ``slot_mask`` (``(B,)`` bool)
         marks live decode slots — masked rows write nothing.  The pool's
         format, its write and its two reads are
-        :mod:`chainermn_tpu.ops.decode_attention`'s."""
+        :mod:`chainermn_tpu.ops.decode_attention`'s, ``chunk_rows`` (the
+        last rows are one slot's prefill chunk) among them: every row goes
+        through the projections, the pool write and the FFN alike."""
         from chainermn_tpu.ops import (
             flash_attention,
             reference_attention,
@@ -281,7 +283,7 @@ class _DecoderBlock(nn.Module):
                 a = paged_attend(
                     q, new_cache, block_tables, decode_pos, q_pos,
                     slot_mask, kernel=paged_kernel, window=self.window,
-                    mesh=self.decode_mesh,
+                    mesh=self.decode_mesh, chunk_rows=chunk_rows,
                 )
             else:
                 with jax.named_scope("kv_write"):
@@ -602,7 +604,7 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, segment_ids=None, return_hidden: bool = False,
                  cache=None, decode_pos=None, rolling: bool = False,
-                 block_tables=None, slot_mask=None):
+                 block_tables=None, slot_mask=None, chunk_rows: int = 0):
         """(B, T) int32 → (B, T, vocab) fp32 logits; with
         ``return_hidden=True``, the pre-head (B, T, d_model) hidden states
         instead (for :func:`lm_loss_chunked`, which streams the head, and
@@ -623,9 +625,17 @@ class TransformerLM(nn.Module):
         ``block_tables``/``slot_mask`` switch the decode path to the PAGED
         cache (``cache`` entries are the serving engine's physical block
         pools; see :class:`_DecoderBlock.__call__` and
-        ``chainermn_tpu/serving``)."""
+        ``chainermn_tpu/serving``).  ``chunk_rows`` (static) says the last
+        that many of the ``B`` single-token rows are one slot's prefill
+        chunk riding the decode rows' step: consecutive positions of one
+        sequence in ``decode_pos``, that slot's table in every such row of
+        ``block_tables``, ``slot_mask`` false past the text's end.  Only
+        attention's read tells them from decode rows
+        (:func:`~chainermn_tpu.ops.decode_attention.paged_attend`)."""
         B, T = tokens.shape
         D = self.d_model
+        if chunk_rows and block_tables is None:
+            raise ValueError("chunk_rows needs the paged cache (block_tables)")
         if self.pos_enc not in ("learned", "rope"):
             raise ValueError(
                 f"pos_enc={self.pos_enc!r}: expected 'learned' or 'rope'"
@@ -702,7 +712,7 @@ class TransformerLM(nn.Module):
             if cache is not None:
                 h, c = blk(h, None, cache[i], decode_pos, rope=rope,
                            rolling=rolling, block_tables=block_tables,
-                           slot_mask=slot_mask)
+                           slot_mask=slot_mask, chunk_rows=chunk_rows)
                 new_cache.append(c)
             else:
                 h = blk(h, segment_ids, rope=rope)

@@ -719,7 +719,7 @@ def pool_context_attend(q, cache, block_tables, q_pos, window=0):
 
 
 def paged_attend(q, cache, block_tables, decode_pos, q_pos, slot_mask=None,
-                 *, kernel, window=0, mesh=None):
+                 *, kernel, window=0, mesh=None, chunk_rows=0):
     """A query chunk's attention over the pool — the one place that
     chooses between the Pallas kernel (scope ``attn.paged``) and the
     gathered read (``attn.gathered``), from what it can observe.
@@ -738,8 +738,42 @@ def paged_attend(q, cache, block_tables, decode_pos, q_pos, slot_mask=None,
     it, a scalar or ``(B,)`` per row — only its rank is read, ``q_pos`` is
     it spread over the chunk.  ``mesh``: a 1-D serving mesh, the kernel
     then runs per shard (:func:`sharded_paged_decode_attention`).
+
+    ``chunk_rows`` (static): the LAST ``chunk_rows`` of the ``B``
+    single-token rows are one slot's prefill chunk riding a decode step
+    (the engine's ``mixed_step``) — consecutive positions of one sequence,
+    each row carrying that slot's table.  They part from the rows before
+    them here and nowhere else: those are read as they would be alone, the
+    chunk's rows regrouped ``(1, chunk_rows, H, Dh)`` for the gathered
+    read, whose switch picks its width from the chunk's last position.
     """
     T, Dh = q.shape[1], q.shape[3]
+    if chunk_rows:
+        if T != 1 or jnp.ndim(decode_pos) != 1:
+            raise ValueError(
+                "chunk_rows rides single-token rows with per-row positions "
+                f"(T == 1, decode_pos (B,)), got T = {T}, decode_pos rank "
+                f"{jnp.ndim(decode_pos)}"
+            )
+        S = q.shape[0] - chunk_rows
+        c = pool_context_attend(
+            jnp.swapaxes(q[S:], 0, 1), cache, block_tables[S:S + 1],
+            q_pos[S:].T, window,
+        )
+        # The chunk's read FIRST, and the decode rows' held behind it: its
+        # switch is a conditional, and on the chip a conditional is a fence
+        # that no prefetch of a later operand crosses.  With the kernel
+        # after it, the layer's remaining operands stream in under the
+        # kernel, as they do in a plain decode step; with the kernel before
+        # it they wait where the fence leaves them (PERF.md section 6,
+        # PR 42).
+        rows, c = jax.lax.optimization_barrier((q[:S], c))
+        a = paged_attend(
+            rows, cache, block_tables[:S], decode_pos[:S], q_pos[:S],
+            None if slot_mask is None else slot_mask[:S],
+            kernel=kernel, window=window, mesh=mesh,
+        )
+        return jnp.concatenate([a, jnp.swapaxes(c, 0, 1)], axis=0)
     # The kernel's causal bound is the FIRST query position's (offset t
     # adds t in-kernel); T == 1 reduces to the classic decode bound.  Idle
     # slots mask to 0.

@@ -39,8 +39,19 @@ allocator; the scheduler maps cached prompt blocks at admission and COWs
 shared partial blocks through :meth:`DecodeEngine.cow_copy` (one jitted
 whole-block copy across every layer of every pool — target and draft).
 
-Prefill runs through a second single-row jitted program in chunks drawn
-from a small fixed **ladder** of geometries (``prefill_ladder``, by
+A chunk of a prompt takes one of two ways.  Where the tick also runs a
+decode step, one slot's chunk RIDES it (``mixed_step``, a plain engine's
+third program): the ``capacity`` decode rows and ``prefill_chunk`` more
+single-token rows go through one pass over the weights, one upload and
+one dispatch, and part only at attention's read — the kernel for the
+decode rows, the gathered read for the chunk's.  One geometry: a tail
+shorter than ``prefill_chunk`` rides as inactive rows that write
+nothing — and takes that geometry with no decode row live when it is
+handed to :meth:`DecodeEngine.prefill`, so a plain engine compiles no
+program a ladder size.  Every other chunk (a whole chunk with no decode
+step to ride; a speculative engine's, at every size) runs through a
+second single-row jitted program (``prefill``) in chunks drawn from a
+small fixed **ladder** of geometries (``prefill_ladder``, by
 default ``prefill_chunk`` and its halves down to 8 — one slot per call;
 prefill compute scales with every padded row, so a capacity-wide
 variant would pay the full ``capacity x chunk`` forward even when a
@@ -318,6 +329,50 @@ class DecodeEngine:
                 nxt = jax.vmap(pick)(logits[:, 0], rng, pos, temp)
             return new_pools, nxt
 
+        # A prefill chunk RIDING the decode step: the capacity decode rows
+        # and one slot's chunk — ``prefill_chunk`` more single-token rows
+        # with consecutive positions, the slot's table (row ``capacity``
+        # of ``tables``) and ``active`` false past the text — go through
+        # every projection, FFN, norm and the pool write together, one
+        # pass over the weights, and part only at attention's read
+        # (``chunk_rows``: ops.decode_attention.paged_attend).  The head
+        # runs on the decode rows and the chunk's one sampled row
+        # (``last_idx``; garbage out where it is negative), never on the
+        # whole chunk.  One geometry, so one program.
+        def mixed_impl(params, pools, tokens, pos, tables, active, rng,
+                       temp):
+            S, C = capacity, prefill_chunk
+            # The chunk's slot and ``last_idx`` ride behind the positions:
+            # a transfer of their own costs the host as much as a vector's.
+            pos, slot, last_idx = pos[:-2], pos[-2], pos[-1]
+            row_tables = jnp.concatenate([
+                tables[:S],
+                jnp.broadcast_to(tables[S:], (C, tables.shape[1])),
+            ])
+            h, new_pools = model.apply(
+                {"params": params}, tokens[:, None], cache=pools,
+                decode_pos=pos, block_tables=row_tables, slot_mask=active,
+                chunk_rows=C, return_hidden=True,
+            )
+            take = jnp.concatenate(
+                [jnp.arange(S), S + jnp.maximum(last_idx, 0)[None]]
+            )
+            with jax.named_scope("head"):
+                head = params["lm_head"]
+                logits = (
+                    h[take, 0].astype(jnp.float32)
+                    @ head["kernel"].astype(jnp.float32)
+                    + head["bias"].astype(jnp.float32)
+                )
+            with jax.named_scope("sample"):
+                nxt = jax.vmap(pick)(
+                    logits,
+                    jnp.concatenate([rng, rng[slot][None]]),
+                    pos[take],
+                    jnp.concatenate([temp, temp[slot][None]]),
+                )
+            return new_pools, nxt
+
         # Prefill stays a SINGLE-ROW program (one slot's chunk per call):
         # a fixed-capacity variant would pay the full ``capacity x chunk``
         # forward even when one slot is refilling, and prefill compute —
@@ -475,6 +530,15 @@ class DecodeEngine:
             )
             if draft_model is not None else None
         )
+        # A speculative engine's chunks never ride: its prefill also feeds
+        # the draft's cache, and its hot loop is the round, not the step.
+        self._mixed = (
+            _w.wrap(
+                jax.jit(mixed_impl, donate_argnums=(1,)),
+                program="mixed_step", budget=1,
+            )
+            if draft_model is None else None
+        )
         self._cow = _w.wrap(
             jax.jit(cow_impl, donate_argnums=(0, 1)),
             program="cow", budget=1,
@@ -538,13 +602,29 @@ class DecodeEngine:
         prefix-cache hit resumes at the first unmatched token).
         ``last_idx >= 0`` marks the final chunk: the first generated
         token is sampled from the logits at that in-chunk index and
-        returned.
+        returned.  A plain engine runs a final chunk smaller than
+        ``prefill_chunk`` in the mixed step's geometry (:meth:`mixed_step`,
+        no decode row live): there its pads are inactive rows and write
+        nothing at all.
         """
         if chunk.ndim != 1 or chunk.shape[0] not in self.prefill_ladder:
             raise ValueError(
                 f"chunk must be 1-D with a ladder size "
                 f"{self.prefill_ladder}, got {chunk.shape}"
             )
+        if (self._mixed is not None and last_idx >= 0
+                and chunk.shape[0] < self.prefill_chunk):
+            # A tail (the ladder's smaller sizes are final chunks) has the
+            # mixed step's geometry whether or not a decode row is live:
+            # its rows past the text ride inactive there, so a plain
+            # engine compiles no program a ladder size — one for whole
+            # chunks with nothing to ride, one for everything else.
+            S = self.capacity
+            return self._dispatch_mixed(
+                "prefill", np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+                np.zeros((S, self.max_blocks), np.int32),
+                np.zeros((S,), bool), slot, chunk, p0, table, last_idx,
+            )[1]
         with _annotate("cmn_engine_upload"):
             chunk_d = self._up(np.asarray(chunk, np.int32)[None])
             table_d = self._up(np.asarray(table, np.int32)[None])
@@ -600,6 +680,67 @@ class DecodeEngine:
         # The wait for the device: everything dispatched drains here.
         with _annotate("cmn_engine_readback"):
             return np.asarray(nxt)
+
+    def mixed_step(self, tokens: np.ndarray, pos: np.ndarray,
+                   tables: np.ndarray, active: np.ndarray, slot: int,
+                   chunk: np.ndarray, p0: int, table: np.ndarray,
+                   last_idx: int = -1
+                   ) -> Tuple[np.ndarray, Optional[int]]:
+        """One decode iteration with ``slot``'s next prefill chunk riding
+        it: one upload, one dispatch, one readback for both (a plain
+        engine's; a speculative engine's chunks go through
+        :meth:`prefill`).
+
+        :meth:`step`'s arguments, then :meth:`prefill`'s: ``chunk`` holds
+        up to ``prefill_chunk`` tokens of ``slot``'s text from position
+        ``p0``, ``table`` is the slot's, and ``last_idx >= 0`` marks the
+        final chunk, whose tokens end there — a non-final chunk is whole.
+        The rows past the text ride inactive and write nothing.
+
+        Returns ``(:meth:`step`'s tokens, the chunk's first generated
+        token or None)``.
+        """
+        if self._mixed is None:
+            raise RuntimeError(
+                "mixed_step on a speculative engine — its chunks go "
+                "through prefill()"
+            )
+        # The tick's one decode dispatch, with a chunk aboard: the span
+        # keeps the step's name (what a `cmn_serve_decode` holds is one
+        # `program=decode_step` dispatch), `chunk=1` tells the two apart,
+        # and the watch's own `cmn_dispatch` / `cmn_compile` inside it
+        # say `program=mixed_step`.
+        return self._dispatch_mixed(
+            "decode_step", tokens, pos, tables, active, slot, chunk, p0,
+            table, last_idx,
+        )
+
+    def _dispatch_mixed(self, program, tokens, pos, tables, active, slot,
+                        chunk, p0, table, last_idx):
+        """The mixed program's vectors: the decode rows', then the chunk's
+        ``prefill_chunk`` rows — its tokens at their consecutive
+        positions, the rows past the text inactive at the last position
+        the text has — the slot's table once, and the slot and
+        ``last_idx`` behind the positions (a transfer of their own costs
+        the host as much as a vector's)."""
+        C = self.prefill_chunk
+        n = last_idx + 1 if last_idx >= 0 else C
+        row_tokens = np.zeros((C,), np.int32)
+        row_tokens[:n] = chunk[:n]
+        row_pos = np.full((C + 2,), p0 + n - 1, np.int32)
+        row_pos[:n] = p0 + np.arange(n)
+        row_pos[C:] = slot, last_idx
+        ctrl = self._upload(
+            np.concatenate([tokens, row_tokens]),
+            np.concatenate([pos, row_pos]),
+            np.concatenate([tables, np.asarray(table, np.int32)[None]]),
+            np.concatenate([active, np.arange(C) < n]),
+        )
+        with _annotate("cmn_engine_dispatch", program=program, chunk=1):
+            self.pools, nxt = self._mixed(self.params, self.pools, *ctrl)
+        with _annotate("cmn_engine_readback"):
+            out = np.asarray(nxt)
+        return out[:-1], (int(out[-1]) if last_idx >= 0 else None)
 
     def spec_step(self, tokens: np.ndarray, pos: np.ndarray,
                   tables: np.ndarray, active: np.ndarray
@@ -736,6 +877,12 @@ class DecodeEngine:
     def prefill_compiles(self) -> int:
         return int(self._prefill._cache_size())
 
+    @property
+    def mixed_compiles(self) -> int:
+        """Variants of the decode step a prefill chunk rides (must stay
+        <= 1; 0 on a speculative engine, which has none)."""
+        return int(self._mixed._cache_size()) if self._mixed else 0
+
     def free_blocks(self) -> int:
         return self.pool.allocator.free_blocks
 
@@ -767,8 +914,8 @@ class DecodeEngine:
         # per-program ledger + blame diffs.
         over = [
             getattr(p, "program", "?")
-            for p in (self._step, self._prefill, self._spec, self._cow,
-                      self._gather, self._put)
+            for p in (self._step, self._mixed, self._prefill, self._spec,
+                      self._cow, self._gather, self._put)
             if p is not None and getattr(p, "over_budget", False)
         ]
         if over:

@@ -118,7 +118,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -132,8 +132,8 @@ from chainermn_tpu.serving.kv_pool import PoolExhausted, blocks_for
 #: The counts of the tick's phases that the unit ledger sums a tick: plain
 #: integers that are at hand where the span opens or closes.
 _LEDGER_COUNTS = {
-    "cmn_serve_prefill": ("tokens", "padded", "final", "ctx_blocks"),
-    "cmn_serve_decode": ("live",),
+    "cmn_serve_prefill": ("tokens", "padded", "final", "ctx_blocks", "rode"),
+    "cmn_serve_decode": ("live", "chunk_rows"),
     "cmn_serve_emit": ("tokens", "retired"),
     "cmn_serve_admit": ("admitted",),
 }
@@ -294,6 +294,19 @@ class _Slot:
         return len(self.entry.carried) + len(self.generated)
 
 
+class _StagedChunk(NamedTuple):
+    """A prefill chunk whose blocks and token array are ready and whose
+    call has not been made: it rides the decode step of the same tick."""
+
+    slot: _Slot
+    chunk: np.ndarray  # (prefill_chunk,) int32, zeros past the text
+    p0: int
+    end: int
+    last_idx: int      # the final chunk's last token in the chunk, or -1
+    tc: float          # the scheduler's clock when it was staged
+    dur_ms: float      # its cmn_serve_prefill span: the staging alone
+
+
 class _Clock:
     """Real seconds since construction, with idle gaps skippable."""
 
@@ -345,6 +358,9 @@ class Scheduler:
         #: device readback may still be draining — the next decode step's
         #: wall time would absorb them (the ``serve.mixed_ms`` tag).
         self._unsynced_prefill = False
+        #: the chunk a tick's prefill round left for its decode step to
+        #: carry (``DecodeEngine.mixed_step``); None between ticks.
+        self._staged: Optional[_StagedChunk] = None
         #: fault-injection seam: an explicit injector wins (the chaos
         #: harness gives each replica its own seeded schedule); default
         #: is the process-wide ``CMN_FAULT`` injector.
@@ -1226,8 +1242,16 @@ class Scheduler:
             self.ledger.book(slot.entry.req.id, "cow_copies", 1)
 
     # ------------------------------------------------------------ prefill
-    def _prefill_round(self) -> bool:
+    def _prefill_round(self, decode_follows: bool = False) -> bool:
         """One chunk for EVERY currently-prefilling slot (oldest first).
+
+        Where a decode step follows in this tick (``decode_follows``:
+        :meth:`tick` says so, a prefill-only role does not) and will have
+        a live slot to run for, the oldest prefilling slot's chunk is only
+        STAGED here and rides that step — one program, one pass over the
+        weights, one dispatch for both (``DecodeEngine.mixed_step``).
+        Every further prefilling slot, and every chunk of a speculative
+        engine, is a call of its own as before.
 
         One chunk per slot per iteration keeps the interleave bound — a
         long prompt still cannot stall running decodes for its whole
@@ -1250,6 +1274,10 @@ class Scheduler:
             else None
         )
         spent, first, chunks = 0, True, 0
+        ride = (
+            decode_follows and self.engine.spec_k == 0
+            and any(s is not None and not s.prefilling for s in self._slots)
+        )
         with _annotate("cmn_serve_prefill_round") as span:
             for slot in sorted(
                 (s for s in self._slots if s is not None and s.prefilling),
@@ -1261,25 +1289,38 @@ class Scheduler:
                     self.policy.note_prefill_capped()
                     break
                 p_before = slot.pos
-                progressed = self._prefill_chunk(slot) or progressed
+                progressed = self._prefill_chunk(
+                    slot, ride=ride and self._staged is None
+                ) or progressed
                 # The slot object survives retirement/eviction, and an
                 # eviction-under-pressure bails before advancing pos — the
-                # delta is exactly the tokens this chunk computed.
+                # delta is exactly the tokens this chunk computed (a
+                # staged chunk's are computed by the step that follows).
                 spent += max(0, slot.pos - p_before)
+                if self._staged is not None and self._staged.slot is slot:
+                    spent += self._staged.end - p_before
                 first = False
                 chunks += 1
             span.set_metadata(chunks=chunks)
         return progressed
 
-    def _prefill_chunk(self, slot: _Slot) -> bool:
+    def _prefill_chunk(self, slot: _Slot, ride: bool = False) -> bool:
+        """``slot``'s next chunk: blocks, copy-on-write, the token array,
+        then its own call of the engine — or, with ``ride``, none: the
+        chunk is left in ``_staged`` for this tick's decode step."""
         eng = self.engine
         p0 = slot.pos
         # Ladder policy (one definition: _ladder_size): full-size chunks
         # while more than prefill_chunk tokens remain, then the smallest
         # ladder geometry covering the tail — one final call with
         # minimal padded compute instead of a full prefill_chunk of
-        # mostly-pad forward.
-        size = self._ladder_size(len(slot.text) - p0)
+        # mostly-pad forward.  A riding chunk has the mixed step's one
+        # geometry, prefill_chunk rows: those past the text are inactive
+        # and write nothing, so no bound of the ladder's moves.
+        size = (
+            eng.prefill_chunk if ride
+            else self._ladder_size(len(slot.text) - p0)
+        )
         end = min(p0 + size, len(slot.text))
         self._alloc_for(slot, blocks_for(end, eng.block_len))
         if self._slots[slot.idx] is not slot:
@@ -1288,23 +1329,51 @@ class Scheduler:
         self._resolve_cow(slot)
         if self._slots[slot.idx] is not slot:
             return True
-        chunk = np.zeros((size,), np.int32)
-        chunk[: end - p0] = slot.text[p0:end]
         last = end == len(slot.text)
+        last_idx = (end - p0 - 1) if last else -1
         tc = self.clock.now()
         # ctx_blocks: the table width the program reads for this chunk —
-        # the choice it makes itself from the padded chunk's last position.
+        # the choice it makes itself from the chunk's last position (the
+        # padded chunk's in a call of its own, the text's where it rides).
         with _annotate("cmn_serve_prefill", timed=self._timed,
                        req=slot.entry.req.id, slot=slot.idx, p0=p0,
                        tokens=end - p0, padded=size, final=int(last),
+                       rode=int(ride),
                        ctx_blocks=context_blocks(
-                           p0 + size - 1, eng.block_len, eng.max_blocks),
+                           (end if ride else p0 + size) - 1,
+                           eng.block_len, eng.max_blocks),
                        ) as span:
-            tok = eng.prefill(
-                slot.idx, chunk, p0, slot.table,
-                last_idx=(end - p0 - 1) if last else -1,
-            )
+            chunk = np.zeros((size,), np.int32)
+            chunk[: end - p0] = slot.text[p0:end]
+            if not ride:
+                tok = eng.prefill(
+                    slot.idx, chunk, p0, slot.table, last_idx=last_idx,
+                )
         dur_ms = span.seconds * 1e3
+        if ride:
+            # The span held the staging alone; the chunk's device time
+            # and its books belong to the step it rides.
+            self._staged = _StagedChunk(
+                slot, chunk, p0, end, last_idx, tc, dur_ms
+            )
+            return True
+        # A final chunk's first-token readback drains every dispatch
+        # queued before it; a non-final chunk is dispatch-only and its
+        # compute drains into the NEXT synced op (the mixed-iteration
+        # tag the decode step reads).
+        self._unsynced_prefill = not last
+        self._chunk_done(slot, p0, end, tc, dur_ms, tok)
+        return True
+
+    def _chunk_done(self, slot: _Slot, p0: int, end: int, tc: float,
+                    dur_ms: float, tok: Optional[int]) -> None:
+        """The books of a chunk the engine has taken (after a final
+        chunk's readback): histograms, usage and fair-share charges, the
+        timeline event, the slot's position; a final chunk (``tok``) also
+        registers the text with the prefix trie and emits its first
+        token."""
+        eng = self.engine
+        last = end == len(slot.text)
         self._m_prefill.observe(dur_ms)
         if self.ledger is not None:
             # Tokens actually COMPUTED this chunk (pad positions are
@@ -1320,11 +1389,6 @@ class Scheduler:
             self.policy.charge(
                 slot.entry.req.tenant, "prefill_tokens", end - p0
             )
-        # A final chunk's first-token readback drains every dispatch
-        # queued before it; a non-final chunk is dispatch-only and its
-        # compute drains into the NEXT synced op (the mixed-iteration
-        # tag the decode step reads).
-        self._unsynced_prefill = not last
         if self.timeline is not None:
             self.timeline.record(
                 "prefill", t=tc, req=slot.entry.req.id, slot=slot.idx,
@@ -1351,10 +1415,13 @@ class Scheduler:
                     "ttft",
                     (self.clock.now() - slot.entry.req.arrival) * 1e3,
                 )
-        return True
 
     # ------------------------------------------------------------- decode
     def _decode_step(self) -> bool:
+        # The chunk this tick's prefill round staged rides this step, if
+        # the step runs: with no live slot there is no step to ride, and
+        # the slot's next round prefills it by a call of its own.
+        staged, self._staged = self._staged, None
         live = [
             s for s in self._slots if s is not None and not s.prefilling
         ]
@@ -1390,6 +1457,12 @@ class Scheduler:
                 ]
                 if not live:
                     return True  # everything evicted itself; still progress
+                if staged is not None and \
+                        self._slots[staged.slot.idx] is not staged.slot:
+                    # The allocations above evicted the youngest slot, and
+                    # that was the one staged: its blocks went back with
+                    # it, and nothing is dispatched for it.
+                    staged = None
                 for s in live:
                     tokens[s.idx] = s.last_token
                     pos[s.idx] = s.pos
@@ -1397,6 +1470,7 @@ class Scheduler:
                     active[s.idx] = True
             span.set_metadata(
                 live=len(live),
+                chunk_rows=staged.end - staged.p0 if staged else 0,
                 kv_blocks_resident=lambda: sum(
                     blocks_for(s.pos + 1, self.engine.block_len)
                     for s in live
@@ -1404,7 +1478,7 @@ class Scheduler:
                 kv_blocks_grid=S * self.engine.max_blocks,
                 table_width=self.engine.max_blocks,
             )
-            mixed = self._unsynced_prefill
+            mixed = self._unsynced_prefill or staged is not None
             self._iterations += 1
             tc = self.clock.now()
             if self._fault is not None:
@@ -1416,6 +1490,17 @@ class Scheduler:
                 out, n_accept = self.engine.spec_step(
                     tokens, pos, tables, active
                 )
+            elif staged is not None:
+                slot = staged.slot
+                out, tok = self.engine.mixed_step(
+                    tokens, pos, tables, active, slot.idx, staged.chunk,
+                    staged.p0, slot.table, last_idx=staged.last_idx,
+                )
+                # After the readback, as a call of its own would: a final
+                # chunk's first token is stamped now, and its slot joins
+                # the decode rows from the next tick.
+                self._chunk_done(slot, staged.p0, staged.end, staged.tc,
+                                 staged.dur_ms, tok)
             else:
                 out = self.engine.step(tokens, pos, tables, active)
             with _annotate("cmn_serve_publish", timed=self._timed) as pub:
@@ -1627,7 +1712,7 @@ class Scheduler:
                             if s is not None and s.admit_seq >= seq0
                         ),
                     )
-            if self._prefill_round():
+            if self._prefill_round(decode_follows=True):
                 progressed = True
             if self._decode_step():
                 progressed = True

@@ -672,3 +672,45 @@ def test_paged_attend_chooses_by_shape(case):
     other = ({"attn.paged", "attn.gathered"} - {taken}).pop()
     assert taken in text and other not in text
     assert fn(*args).shape == q.shape
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "gathered"])
+def test_chunk_rows_part_at_the_read_and_nowhere_else(kernel):
+    """``chunk_rows``: the last rows of a batch of single-token rows are one
+    slot's prefill chunk riding a decode step.  They read as that chunk
+    does alone — regrouped ``(1, C, H, Dh)``, the gathered read over the
+    slot's table — and the rows before them as they do alone (the kernel
+    where the caller allows it: then one program holds both scopes)."""
+    from chainermn_tpu.ops.decode_attention import (
+        paged_attend,
+        pool_context_attend,
+    )
+
+    S, C, H = 3, 5, 2 * _KH
+    rng = np.random.RandomState(8)
+    tbl, NB = _tables(S + 1, rng)
+    cache = _random_cache(NB, "bf16", rng)
+    q = jnp.asarray(rng.randn(S + C, 1, H, _DH), jnp.float32)
+    # decode rows anywhere; the chunk at positions 6..10 of the last table
+    pos = jnp.asarray([3, 17, 9] + list(range(6, 6 + C)), jnp.int32)
+    tables = jnp.asarray(np.concatenate([tbl[:S], np.repeat(tbl[S:], C, 0)]))
+    live = jnp.asarray([True, False, True] + [True] * C)
+
+    def mixed(q, cache):
+        return paged_attend(q, cache, tables, pos, pos[:, None], live,
+                            kernel=kernel, chunk_rows=C)
+
+    got = jax.jit(mixed)(q, cache)
+    rows = paged_attend(q[:S], cache, tables[:S], pos[:S], pos[:S, None],
+                        live[:S], kernel=kernel)
+    chunk = pool_context_attend(jnp.swapaxes(q[S:], 0, 1), cache,
+                                tables[S:S + 1], pos[None, S:])
+    assert got.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(got[:S]), np.asarray(rows))
+    np.testing.assert_array_equal(np.asarray(got[S:, 0]),
+                                  np.asarray(chunk[0]))
+    text = jax.jit(mixed).lower(q, cache).as_text(debug_info=True)
+    assert "attn.gathered" in text and ("attn.paged" in text) == kernel
+    with pytest.raises(ValueError, match="single-token rows"):
+        paged_attend(q, cache, tables, jnp.int32(3), pos[:, None], live,
+                     kernel=kernel, chunk_rows=C)

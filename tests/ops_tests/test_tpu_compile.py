@@ -211,11 +211,12 @@ def test_mosaic_accepts_the_kv_panel(heads, kv_heads, dh, t, kv_dtype, chip):
     _assert_mosaic_took(text, 1, ["paged_decode"], (dh, t, kv_dtype))
 
 
-def _xl_layer_step(tokens_per_slot, per_slot_pos):
+def _xl_layer_step(tokens_per_slot, per_slot_pos, chunk_rows=0):
     """One GPT-2 XL-wide decoder layer over the backlog cell's pool, as the
     engine's programs call it: the pools donated, (decode, verify) every
     slot at its own position behind a live mask, (prefill) one slot's chunk
-    at a scalar position."""
+    at a scalar position, (mixed) ``chunk_rows`` more single-token rows, one
+    slot's chunk, behind the decode rows."""
     from chainermn_tpu.models import TransformerLM
     from chainermn_tpu.serving.kv_pool import PagedKVPool
 
@@ -226,13 +227,14 @@ def _xl_layer_step(tokens_per_slot, per_slot_pos):
     params = jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     pools = jax.eval_shape(lambda: PagedKVPool(model, _XL_NB, BL).pools)
-    slots = S if per_slot_pos else 1
+    slots = S + chunk_rows if per_slot_pos else 1
 
     def step(params, pools, tokens, pos, tables, active):
         return model.apply(
             {"params": params}, tokens, cache=pools,
             decode_pos=pos if per_slot_pos else pos[0], block_tables=tables,
-            slot_mask=active if per_slot_pos else None, return_hidden=True)
+            slot_mask=active if per_slot_pos else None, return_hidden=True,
+            chunk_rows=chunk_rows)
 
     args = (params, pools,
             jax.ShapeDtypeStruct((slots, tokens_per_slot), jnp.int32),
@@ -242,20 +244,22 @@ def _xl_layer_step(tokens_per_slot, per_slot_pos):
     return jax.jit(step, donate_argnums=(1,)), args, pools[0]["kv"]
 
 
-@pytest.mark.parametrize("tokens, per_slot, launches", [
-    (1, True, 1), (4, True, 1), (32, False, 0),
-], ids=["decode", "verify_t4", "prefill_c32"])
+@pytest.mark.parametrize("tokens, per_slot, launches, chunk_rows", [
+    (1, True, 1, 0), (4, True, 1, 0), (32, False, 0, 0), (1, True, 1, 32),
+], ids=["decode", "verify_t4", "prefill_c32", "mixed_c32"])
 def test_pool_write_and_kernel_share_one_layout(tokens, per_slot, launches,
-                                                chip):
+                                                chunk_rows, chip):
     """The guard that keeps a whole-pool copy from coming back without a
     chip run (``serving/kv_pool.py`` has the story): at the backlog cell's
     geometry — 25 heads of 64, 2,049 blocks of 16, 32 slots, a table 64
     wide, the pool donated — the model's own write and the kernel (or, for
-    a prefill chunk, the gather) compile to a program in which argument,
-    scatter and Mosaic agree on the pool's layout: no ``copy`` of the
+    a prefill chunk, the gather; for a chunk riding the decode rows, one
+    write, the kernel for those and the gather for it) compile to a program
+    in which argument, scatter and Mosaic agree on the pool's layout: no
+    ``copy`` of the
     pool's shape, no temporary as large as a pool, and the pool at rest
     row-major (so nothing is padded: 2049 x 16 x 3200 x 2 bytes)."""
-    fn, args, pool = _xl_layer_step(tokens, per_slot)
+    fn, args, pool = _xl_layer_step(tokens, per_slot, chunk_rows)
     compiled = fn.lower(*_on(chip, args)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == launches

@@ -62,7 +62,10 @@ def test_prefill_across_every_context_width_is_one_program_a_chunk_size(
     program: a prompt long enough to cross every width of the rehearse
     geometry's ladder (a table of 16 blocks of 8: 2, 4, 8, 16) prefills
     with one compiled program a chunk size, inside the compile watch's
-    budget, and serves the tokens the contiguous cache generates."""
+    budget, and serves the tokens the contiguous cache generates.  Since
+    PR 42 a plain engine's chunk sizes are two: whole chunks with no decode
+    step to ride (``prefill``), and the mixed step's geometry for chunks
+    that ride and for every tail, whose rows past the text are inactive."""
     from chainermn_tpu.observability import device as odev
     from chainermn_tpu.ops.decode_attention import (
         context_blocks,
@@ -87,9 +90,10 @@ def test_prefill_across_every_context_width_is_one_program_a_chunk_size(
         Request(id=0, prompt=long, max_new_tokens=12),
         Request(id=1, prompt=short, max_new_tokens=12),
     ])
-    assert eng.prefill_compiles == len(eng.prefill_ladder)
+    assert eng.prefill_compiles == 1 and eng.mixed_compiles == 1
     assert eng.decode_compiles == 1
     assert not eng._prefill.over_budget and not eng._step.over_budget
+    assert not eng._mixed.over_budget
     assert odev.watch().budget_violations == violations
     assert "compile_over_budget" not in eng.stats()
     for c in comps:
