@@ -47,26 +47,27 @@ def served_gaps(ref, model: Dict[str, Any], params, sample, tokens,
     configuration's plain reference ``ref`` (``Manifest.reference``) handed
     the ``model`` fields.  With ``quant`` (the control) the token judged at
     each position is the one the lower-precision forward puts first, not the
-    served one."""
+    served one.  The reference is asked for one ``(1, pad_to)`` row a call —
+    one compiled shape — so the check holds one row's logits however wide
+    the vocabulary is."""
     import jax.numpy as jnp
 
-    rows = np.zeros((len(sample), pad_to), np.int32)
-    for i, r in enumerate(sample):
-        text = list(r.prompt) + list(tokens[r.id])
-        rows[i, : len(text) - 1] = text[:-1]  # the last token is never fed
-    toks = jnp.asarray(rows)
-    logits = ref.forward_logits(params, toks, model)
-    low = (ref.forward_logits(params, toks, model, quant=quant)
-           if quant else None)
     widest, total, n, flips = 0.0, 0.0, 0, 0
-    for i, r in enumerate(sample):
+    for r in sample:  # one row a call: the check holds one row's logits
+        text = list(r.prompt) + list(tokens[r.id])
+        row = np.zeros((1, pad_to), np.int32)
+        row[0, : len(text) - 1] = text[:-1]  # the last token is never fed
+        toks = jnp.asarray(row)
         got = np.asarray(tokens[r.id], np.int64)
         a, b = len(r.prompt) - 1, len(r.prompt) - 1 + len(got)
-        row = np.asarray(logits[i, a:b], np.float32)
-        if low is not None:
-            got = np.argmax(np.asarray(low[i, a:b], np.float32), axis=-1)
-        best = row.max(axis=-1)
-        gap = best - row[np.arange(len(got)), got]
+        logits = np.asarray(
+            ref.forward_logits(params, toks, model)[0, a:b], np.float32)
+        if quant:
+            got = np.argmax(np.asarray(
+                ref.forward_logits(params, toks, model, quant=quant)[0, a:b],
+                np.float32), axis=-1)
+        best = logits.max(axis=-1)
+        gap = best - logits[np.arange(len(got)), got]
         widest = max(widest, float(gap.max()))
         total += float(gap.sum())
         flips += int((gap > 0).sum())

@@ -9,9 +9,7 @@ dV, dK, dQ) however the program splits it into kernels, so its two kernels
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict
-
-from perfbench.flops.transformer import head_shape
+from typing import Dict
 
 
 def unit_flops(batch: int, heads: int, seq: int, head_dim: int) -> float:
@@ -35,14 +33,3 @@ def backward(batch: int, heads: int, kv_heads: int, seq: int, head_dim: int,
     # reads q, k, v, o, do, lse, delta; writes dq, dk, dv
     return {"flops": 5 * unit_flops(batch, heads, seq, head_dim),
             "bytes": float(3 * q + kv + lse + q + kv)}
-
-
-def at_training_shapes(one_call: Callable[..., Dict[str, float]],
-                       facts: Dict[str, Any], calls: float) -> Dict[str, float]:
-    """``calls`` calls of ``forward`` or ``backward`` at the shapes of the
-    configuration's training step: what ``flops/flash_forward.py`` and
-    ``flops/flash_backward.py`` hand the roofline reducer."""
-    train = facts["config"]["train"]
-    H, KH, Dh = head_shape(facts["config"]["model"])
-    one = one_call(train["rows_per_chip"], H, KH, train["seq_len"], Dh)
-    return {k: v * calls for k, v in one.items()}

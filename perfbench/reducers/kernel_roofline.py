@@ -3,8 +3,11 @@ the operations and bytes its calls *need* over the time its events took in
 the traced window.
 
 ``kernels`` lists ``{"pattern", "need"}``: events whose name matches
-``pattern`` are that kernel's calls, ``events_per_call`` of them to a call (1
-unless said).  A pattern is the name the kernel's ``pallas_call`` gave it
+``pattern`` are that kernel's calls, one event a call (a kernel launched a
+varying number of times a call is found by the launch that comes once, and
+its other launches by a pattern whose need counts nothing,
+``flops/flash_head_dim_time_only.py``).  A pattern is the name the kernel's
+``pallas_call`` gave it
 (``^%?flash_fwd\\b``), never a result type, which two kernels can share.
 ``need`` names the file ``flops/<need>.py`` whose ``need(facts, calls) ->
 {"flops", "bytes"}`` counts what ``calls`` calls on each device require,
@@ -35,7 +38,7 @@ def reduce(facts, args):
             continue
         seconds += sum(e.dur for e in events) / k
         matched[spec["need"]] = matched.get(spec["need"], 0) + len(events)
-        calls = len(events) / k / spec.get("events_per_call", 1)
+        calls = len(events) / k
         needed = facts["manifest"].need(spec["need"])(facts, calls)
         for key in need:
             need[key] += needed[key]

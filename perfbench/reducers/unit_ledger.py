@@ -15,7 +15,10 @@ no tick and draws no batch).
 ``args``: ``ledger`` (the kind), ``span`` and ``ordinal`` (the traced span
 and the stat that holds its ordinal), ``from`` (the traffic file's key),
 ``what`` (a key of :data:`WHAT`) and what that reading takes (``name``,
-``count``, ``over_ms``); ``call`` (the phase a unit may hold many calls of,
+``count``, ``over_ms``); ``over`` (``"traced"``: the reading is taken over
+the traced units alone, which says how far the traced stretch stands for the
+window; the tables stay the whole window's); ``call`` (the phase a unit
+may hold many calls of,
 priced for ``stall_ms`` and the stalls table), ``flags`` (what the stalls
 table says a slow unit held, ``{label: a count's flat name or a phase}``) and
 ``join`` (the phases the join table compares beside the unit itself).  The
@@ -311,4 +314,6 @@ def reduce(facts, args):
     if traced is not None:
         pt.say_once("window_join", t,
                     lambda: join(t, units, traced, args.get("join", ())))
-    return WHAT[args["what"]](units, args)
+    if args.get("over") == "traced" and traced is not None:
+        units = [u for u in units if u.ordinal in traced]
+    return WHAT[args["what"]](units, args) if units else None

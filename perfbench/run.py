@@ -238,7 +238,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.rehearse:
         ctx.say(rehearsal=True, correct=bool(res["correct"]),
                 attempted=res["attempted"], failed=res["failed"],
-                counts=res.get("counts", {}),
+                counts=res.get("counts", {}), **res.get("says", {}),
                 device={"platform": dev["platform"]}, compared=numbers)
         say_compared(numbers)
         return 0 if res["correct"] else 1
@@ -250,6 +250,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     line: Dict[str, Any] = {
         "correct": bool(res["correct"]), "attempted": int(res["attempted"]),
         "failed": int(res["failed"]),
+        **res.get("says", {}),  # what a runner wants read beside its numbers
     }
     if args.trace:
         from perfbench import trace as ptrace

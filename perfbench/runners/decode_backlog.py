@@ -1,5 +1,13 @@
 """Offline generation above the knee: every slot full, a deep queue behind
-them, tokens per second over whole ticks."""
+them, tokens per second over whole ticks.
+
+The schedule is the mix's (``traffic_gen.backlog_lengths``), so every run of
+a cell runs the same chunks in the same ticks; ``info.schedule`` holds each
+run to the host's replay of it, tick by tick, from the program's unit
+ledger.  The window has to close on the clock with requests still queued:
+``info.closed_on`` / ``info.queue_left`` say how it closed, and a queue that
+fell under the mix's ``queue_left_min`` is compared as ``queue_short`` — a
+rate over slots that had nothing to refill from is not the cell's."""
 
 from __future__ import annotations
 
@@ -8,7 +16,36 @@ from typing import Any, Dict, List, Tuple
 
 from perfbench import estimators, serving, traffic_gen, weights
 from perfbench import device as pdevice
+from perfbench.checks import compare
 from perfbench.checks import serve as check
+from perfbench.reducers import unit_ledger
+
+
+def schedule_against_replay(lengths, slots: int, chunk: int, n_ticks: int):
+    """The window's ticks in the program's unit ledger (its last
+    ``n_ticks`` units) against the replay: per tick the prefill chunks
+    started, how many of them rode and the rows of the decode step.  ``None``
+    for a program that keeps no such ledger; else how many ticks were
+    compared, how many differ (a prefix hit between two random prompts may
+    move a chunk by a tick) and the first of those, counted from the
+    window's first tick."""
+    ledger = unit_ledger.live_ledger("serve_tick")
+    units = ledger.units()[-n_ticks:] if ledger is not None else []
+    if len(units) < n_ticks:
+        return None
+    fill, ticks = traffic_gen.replay_backlog(
+        lengths, slots, chunk, max_ticks=units[-1].ordinal + 1)
+    want = [(t.calls, t.rode, t.live) for t in ticks[units[0].ordinal:]]
+    got = [(u.calls.get("cmn_serve_prefill", 0),
+            u.counts.get("cmn_serve_prefill.rode", 0),
+            u.counts.get("cmn_serve_decode.live", 0)) for u in units]
+    off = [i for i, (a, b) in enumerate(zip(got, want)) if a != b]
+    off += list(range(len(want), len(got)))
+    return {"ticks": len(got), "fill": units[0].ordinal, "fill_replay": fill,
+            "off": len(off), "first_off": off[:8],
+            "calls": sum(x[0] for x in got), "rode": sum(x[1] for x in got),
+            "calls_replay": sum(x[0] for x in want),
+            "rode_replay": sum(x[1] for x in want)}
 
 
 def run(ctx) -> Dict[str, Any]:
@@ -45,8 +82,12 @@ def run(ctx) -> Dict[str, Any]:
     t_open = ctx.clock.now()
     while True:
         a = ctx.clock.now()
-        if a - t_open >= ctx.seconds or not sched.pending:
-            break  # closes on the first tick boundary at or after --seconds
+        if a - t_open >= ctx.seconds:
+            closed_on = "clock"  # the first tick boundary at or after it
+            break
+        if not sched.pending:
+            closed_on = "empty"
+            break
         i = len(ticks)
         if ctx.trace and i == first:
             ctx.start_trace()
@@ -65,6 +106,7 @@ def run(ctx) -> Dict[str, Any]:
     if ctx.trace and traced and ctx.spans.annotate:
         window.__exit__(None, None, None)
         ctx.stop_trace()
+    queue_left = sched.queue_depth
     rate = estimators.whole_unit_rate(ticks)
     gc_seen = ctx.gc_report()
     peak = pdevice.memory_peak_bytes(ctx.chips)
@@ -81,6 +123,10 @@ def run(ctx) -> Dict[str, Any]:
     numbers = check.served_gaps(ref, m, params, sample, tokens, pad)
     limits = (cfg["rehearse"] if ctx.rehearse else cfg)["check"]["serve"]
     ok, rows = check.judge(numbers, limits)
+    left_ok, left_rows = compare(
+        {"queue_short": float(max(0, tr["queue_left_min"] - queue_left))},
+        {"queue_short": 0.0})
+    ok, rows = ok and left_ok, left_rows + rows
     control = {q: check.served_gaps(ref, m, params, sample, tokens, pad,
                                     quant=q)
                for q in ctx.control}
@@ -110,8 +156,13 @@ def run(ctx) -> Dict[str, Any]:
         "failed": failed, "values": values, "facts": facts,
         "compared": rows, "check_s": check_s, "memory_peak_bytes": peak,
         "sound": numbers, "control": control, "gc": gc_seen,
+        "says": {"window": {"closed_on": closed_on, "queue_left": queue_left}},
         "info": {"ticks": len(ticks), "tokens": rate["work"],
                  "window_s": rate["seconds"], "finished": len(done),
+                 "closed_on": closed_on, "queue_left": queue_left,
+                 "schedule": schedule_against_replay(
+                     [(len(r.prompt), r.max_new) for r in reqs], S,
+                     sv["prefill_chunk"], len(ticks)),
                  "prefill_calls": rec.prefill_calls,
                  "tick_ms_median": values["tick_ms_median"],
                  "tick_ms_max": max(tick_ms),

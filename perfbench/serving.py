@@ -107,18 +107,28 @@ def submit(sched, r: traffic_gen.Req, arrival: float) -> None:
 
 def warm_programs(eng, clock: Clock, vocab: int, chunk: int) -> None:
     """Compile (or load) the decode step and every prefill ladder size the
-    traffic can reach, by serving three throw-away requests whose prompt
-    tails land on each size."""
-    ladder = eng.prefill_ladder
+    traffic can reach, by serving throw-away requests whose prompt tails
+    land on each size — and, with a prefix cache, the copy-on-write program:
+    one more request that shares the first of them's leading tokens up to
+    the middle of a block, which a prefix hit between two random prompts
+    does inside the window at no fixed seed."""
+    def serve(reqs):
+        sched, _ = new_scheduler(eng, clock, reqs)
+        for r in reqs:
+            submit(sched, r, 0.0)
+        while sched.pending:
+            if not sched.tick():
+                raise RuntimeError("warm-up made no progress")
+
     reqs = [traffic_gen.Req(-1 - i, [1 + (7 * i + j) % (vocab - 1)
                                      for j in range(chunk + size)], 2)
-            for i, size in enumerate(ladder)]
-    sched, _ = new_scheduler(eng, clock, reqs)
-    for r in reqs:
-        submit(sched, r, 0.0)
-    while sched.pending:
-        if not sched.tick():
-            raise RuntimeError("warm-up made no progress")
+            for i, size in enumerate(eng.prefill_ladder)]
+    serve(reqs)
+    if eng.prefix is not None:
+        shared = max(1, eng.block_len // 2)
+        tail = [1 + (3 + 5 * j) % (vocab - 1) for j in range(chunk)]
+        serve([traffic_gen.Req(-1 - len(reqs),
+                               reqs[0].prompt[:shared] + tail, 2)])
     eng.drop_prefix_cache()
 
 

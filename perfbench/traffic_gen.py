@@ -4,17 +4,25 @@
 Seed-invariance: every size a run's work depends on is drawn as a **fixed
 multiset** — the quantiles of the stated distribution — so prompt tokens,
 output tokens, the context resident when the window opens, the number of
-arrivals and their gaps are the same for every seed.  The seed chooses the
-order (a shuffle inside consecutive groups, so the shape of the load over
-time stays the same too), the token ids and, elsewhere, the weights.
+arrivals and their gaps are the same for every seed.
+
+**A backlog's schedule is the mix's, not the seed's**: :func:`backlog_lengths`
+orders every wave by a fixed permutation, so each run admits the same
+lengths in the same order, runs the same chunks in the same ticks and
+decodes against the same contexts; the seed draws token ids (and, elsewhere,
+the weights) and nothing else.  :func:`replay_backlog` is that schedule
+worked out on the host, tick by tick, with no device.  The open-loop
+generator keeps a seeded order (a shuffle inside consecutive groups, so the
+shape of the load over time stays the same too).
 """
 
 from __future__ import annotations
 
 import math
+from collections import deque
 from dataclasses import dataclass
 from statistics import NormalDist
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -86,19 +94,113 @@ def _tokens(rng: np.random.Generator, n: int, vocab: int) -> List[int]:
     return rng.integers(1, vocab, size=int(n)).tolist()
 
 
-def decode_backlog(tr: Dict[str, Any], vocab: int, seed: int) -> List[Req]:
-    """``slots * (1 + queue_sets)`` requests, all due at once.  Each wave of
-    ``slots`` requests holds the same multiset of (prompt, output) pairs;
-    the seed orders the pairs inside their wave."""
-    rng = rng_for(seed, 11)
+def backlog_lengths(tr: Dict[str, Any]) -> List[Tuple[int, int]]:
+    """The backlog's ``slots * (1 + queue_sets)`` (prompt, output) lengths
+    in the order they are queued.  Each wave of ``slots`` requests holds the
+    same multiset of pairs; wave ``w`` is ordered by the fixed permutation
+    ``spread(slots, order_salt + w)``, so consecutive waves differ and no
+    seed has a say."""
     S = tr["slots"]
     wave = _pairs(tr, S, 0)
-    reqs: List[Req] = []
-    for _ in range(1 + tr["queue_sets"]):
-        for j in group_shuffle(S, 0, rng):
-            p, o = wave[j]
-            reqs.append(Req(len(reqs), _tokens(rng, p, vocab), int(o)))
-    return reqs
+    salt = tr.get("order_salt", 100)
+    return [(int(p), int(o)) for w in range(1 + tr["queue_sets"])
+            for p, o in wave[spread(S, salt + w)]]
+
+
+def decode_backlog(tr: Dict[str, Any], vocab: int, seed: int) -> List[Req]:
+    """The requests of :func:`backlog_lengths`, all due at once; the seed
+    draws their token ids."""
+    rng = rng_for(seed, 11)
+    return [Req(i, _tokens(rng, p, vocab), o)
+            for i, (p, o) in enumerate(backlog_lengths(tr))]
+
+
+class ReplayTick(NamedTuple):
+    """What one scheduler tick of a backlog does, by the replay."""
+
+    live: int      # rows of the decode step
+    calls: int     # prefill chunks started, riding ones included
+    rode: int      # of them, staged to ride the decode step (0 or 1)
+    tokens: int    # tokens generated: the live rows' and first tokens
+    admitted: int
+    finished: int
+    context: int   # KV positions the decode step reads, over its rows
+
+
+def replay_backlog(lengths: Sequence[Tuple[int, int]], slots: int, chunk: int,
+                   max_ticks: Optional[int] = None
+                   ) -> Tuple[int, List[ReplayTick]]:
+    """``(fill, ticks)``: the schedule of a backlog of ``lengths`` on a plain
+    engine of ``slots`` slots and prefill chunks of ``chunk``, replayed on
+    the host.  A tick admits the queue's head into every free slot (lowest
+    index first), gives every prefilling slot one chunk, oldest admission
+    first — the first of them rides the decode step where a decode row is
+    live, the others are calls of their own — and decodes one token for
+    every slot whose prefill was done before the step; a final chunk yields
+    the request's first token, and a slot that rode joins the rows a tick
+    later.  ``fill`` is the number of leading ticks the runner spends in
+    set-up: until ``slots`` prefills are done.  Stops when nothing is
+    pending or after ``max_ticks``.
+
+    It knows no token: a prefix hit between two prompts (the seed's) starts
+    a prefill a token or more in, and may move that slot's chunks by a tick.
+    """
+    queue = deque(lengths)
+    table: List[Optional[Dict[str, Any]]] = [None] * slots
+    seq, prefills_done, fill = 0, 0, None
+    ticks: List[ReplayTick] = []
+
+    def take_chunk(i: int) -> Tuple[int, int]:
+        """Slot ``i``'s next chunk is in: ``(tokens, finished)`` — a final
+        chunk yields the first token, which may be all the request asks."""
+        nonlocal prefills_done
+        s = table[i]
+        s["pos"] = min(s["pos"] + chunk, s["text"])
+        if s["pos"] < s["text"]:
+            return 0, 0
+        s["prefilling"], s["made"] = False, 1
+        prefills_done += 1
+        if s["made"] < s["new"]:
+            return 1, 0
+        table[i] = None
+        return 1, 1
+
+    while (queue or any(table)) and (max_ticks is None
+                                     or len(ticks) < max_ticks):
+        if fill is None and prefills_done >= slots:
+            fill = len(ticks)
+        admitted = 0
+        for i in range(slots):
+            if table[i] is None and queue:
+                p, o = queue.popleft()
+                table[i] = {"text": p, "new": o, "pos": 0, "made": 0,
+                            "prefilling": True, "seq": seq}
+                seq += 1
+                admitted += 1
+        ride = any(s is not None and not s["prefilling"] for s in table)
+        prefilling = sorted((i for i, s in enumerate(table)
+                             if s is not None and s["prefilling"]),
+                            key=lambda i: table[i]["seq"])
+        staged = prefilling[0] if ride and prefilling else None
+        done = [take_chunk(i) for i in prefilling if i != staged]
+        live = [i for i, s in enumerate(table)
+                if s is not None and not s["prefilling"]]
+        context = sum(table[i]["pos"] + 1 for i in live)
+        for i in live:
+            s = table[i]
+            s["pos"] += 1
+            s["made"] += 1
+            over = s["made"] >= s["new"]
+            done.append((1, int(over)))
+            if over:
+                table[i] = None
+        if staged is not None and live:  # after the step it rode
+            done.append(take_chunk(staged))
+        ticks.append(ReplayTick(
+            len(live), len(prefilling), int(staged is not None),
+            sum(t for t, _ in done), admitted, sum(f for _, f in done),
+            context))
+    return (len(ticks) if fill is None else fill), ticks
 
 
 def open_loop(tr: Dict[str, Any], vocab: int, seed: int,

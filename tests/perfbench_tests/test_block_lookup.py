@@ -240,8 +240,8 @@ def test_kernel_roofline_finds_a_need_of_the_data_directory(with_standin):
     full = shipped.config("starcoder2-3b")
     from perfbench.flops import flash_attention
 
-    for name, fn in (("flash_forward", flash_attention.forward),
-                     ("flash_backward", flash_attention.backward)):
+    for name, fn in (("flash_head_dim_forward", flash_attention.forward),
+                     ("flash_head_dim_backward", flash_attention.backward)):
         one = fn(1, 24, 2, 4096, 128)
         assert man.need(name)({"config": full}, 30) == \
             {k: 30 * v for k, v in one.items()}

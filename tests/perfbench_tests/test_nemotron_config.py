@@ -275,14 +275,17 @@ def test_flash_need_reads_the_models_head_dim_and_counts_a_backward_by_dq():
             for k in spec["args"]["kernels"]]
     assert hits == [[True, False, False, False], [False, True, False, False],
                     [False, False, True, True]]
-    assert all(k.get("events_per_call", 1) == 1
+    assert all(set(k) == {"pattern", "need"}  # one event a call
                for k in spec["args"]["kernels"])
 
 
-def test_flash_roofline_by_dq_events_reads_the_recorded_trace_as_the_shipped_one():
-    """On the recorded training trace (two dK/dV launches and one dQ a
-    backward call, where ``events_per_call`` 3 holds) the share counted by
-    dQ events is the shipped ``flash_roofline``'s."""
+def test_flash_roofline_by_dq_events_reads_a_stated_and_a_derived_head_size_alike():
+    """``flash_head_dim_roofline`` is the one flash roofline since PR 43
+    (``flash_roofline`` divided the backward's events by 3 where a call has
+    been 2 since PR 36): on the recorded training trace it reads
+    ``starcoder2-3b``, whose model states no ``head_dim``, as it reads the
+    same model with ``head_dim`` 128 stated — 9 matmul units a layer and
+    step over the three kernels' time."""
     import os
 
     from perfbench import trace as ptrace
@@ -292,15 +295,21 @@ def test_flash_roofline_by_dq_events_reads_the_recorded_trace_as_the_shipped_one
                                  "train_2steps_program.xplane.pb.gz"))
     peaks = json.load(open(os.path.join(HERE, "peaks.json")))["TPU v5 lite"]
     cfg = MAN.config("starcoder2-3b")
-    cfg = dict(cfg, model=dict(cfg["model"], head_dim=128))
-    facts = {"trace": t, "traced_units": 2, "manifest": MAN, "config": cfg,
-             "peaks": peaks, "values": {}}
-    got = {name: MAN.reducer("kernel_roofline").reduce(
-        facts, MAN.metric_file(name)["args"])
-        for name in ("flash_head_dim_roofline", "flash_roofline")}
-    assert got["flash_head_dim_roofline"] == pytest.approx(
-        got["flash_roofline"], rel=1e-12)
-    assert 30 < got["flash_roofline"] < 60
+    stated = dict(cfg, model=dict(cfg["model"], head_dim=128))
+    got = [MAN.reducer("kernel_roofline").reduce(
+        {"trace": t, "traced_units": 2, "manifest": MAN, "config": c,
+         "peaks": peaks, "values": {}},
+        MAN.metric_file("flash_head_dim_roofline")["args"])
+        for c in (cfg, stated)]
+    assert got[0] == got[1]
+    kernel_s = sum(e.dur for e in t.devices[0].ops
+                   if "tpu_custom_call" in e.name)
+    assert got[0] == pytest.approx(
+        100 * 2 * 30 * 9 * 2.0 * 128 * (4096 * 4097 / 2) * 24 / 197e12
+        / kernel_s, rel=1e-9)
+    assert "sc2-3b_train_1chip" in next(
+        m for m in MAN.doc["per_layer"]
+        if m["name"] == "flash_head_dim_roofline")["workloads"]
 
 
 def test_the_cell_is_the_issues():

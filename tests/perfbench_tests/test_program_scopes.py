@@ -188,8 +188,9 @@ def test_decode_counts_agree_with_the_benchmarks_recorder(served):
     assert sum(s.stats["retired"] for s in emits) <= len(rec.retired)
     man = Manifest()
     facts = {"program_trace": t, "traced_units": len(decodes)}
-    spec = man.metric_file("paged_grid_useful_pct")
-    got = man.reducer(spec["reducer"]).reduce(facts, spec["args"])
+    got = man.reducer("span_stat_ratio").reduce(
+        facts, {"span": "cmn_serve_decode", "num": "kv_blocks_resident",
+                "den": "kv_blocks_grid"})
     want = 100.0 * sum(s.stats["kv_blocks_resident"] for s in decodes) / sum(
         s.stats["kv_blocks_grid"] for s in decodes)
     assert got == pytest.approx(want) and 0 < got <= 100

@@ -419,12 +419,18 @@ def test_recorded_backlog_counts_and_every_new_metric(backlog):
     # at least these: a later PR may add a metric to the cell, and the
     # trace recorded here need not hold what that one reads
     for name in ("serve_kv_write_ms_tick", "serve_attn_ms_tick",
-                 "serve_unscoped_pct", "paged_grid_useful_pct",
+                 "serve_unscoped_pct",
                  "tick_build_ms", "tick_emit_ms", "tick_publish_ms",
                  "tick_readback_ms", "tick_idle_build_ms",
                  "tick_idle_emit_ms"):
         assert got.get(name) is not None, (name, got)
-    assert got["paged_grid_useful_pct"] == pytest.approx(
+    # the share of the tables that holds a token, from the two counts (the
+    # metric that priced it, paged_grid_useful_pct, went with PR 43: since
+    # PR 31 neither read walks the whole table)
+    assert reducer("span_stat_ratio")(
+        {"program_trace": t}, {"span": "cmn_serve_decode",
+                               "num": "kv_blocks_resident",
+                               "den": "kv_blocks_grid"}) == pytest.approx(
         100.0 * sum(s.stats["kv_blocks_resident"] for s in decodes)
         / (2 * 32 * 64))
     assert got["serve_attn_ms_tick"] > 5 * got["serve_kv_write_ms_tick"] > 0
@@ -486,27 +492,27 @@ def test_the_rooflines_find_their_kernels_by_name(train, backlog):
     call of every layer, and its operations counted twice.)"""
     man = Manifest()
     old = train[1]
-    found = _kernel_events(man, "flash_roofline", old)
+    found = _kernel_events(man, "flash_head_dim_roofline", old)
     mosaic = [e for e in old.devices[0].ops if "tpu_custom_call" in e.name]
-    assert sorted(map(id, found["flash_forward"] + found["flash_backward"])) \
-        == sorted(map(id, mosaic))
+    assert sorted(map(id, sum(found.values(), []))) == sorted(map(id, mosaic))
     assert all(e.name.startswith("%flash_fwd.")
-               for e in found["flash_forward"])
-    # 2 steps x 30 layers: the forward and its recomputation; the backward's
-    # dK/dV kernel over two chunks of query rows and its dQ kernel once
-    assert len(found["flash_forward"]) == 2 * 30 * 2
-    per_call = man.metric_file("flash_roofline")["args"]["kernels"][1][
-        "events_per_call"]
-    assert len(found["flash_backward"]) == 2 * 30 * per_call
-    assert sum(e.name.startswith("%flash_bwd_dq.")
-               for e in found["flash_backward"]) == 2 * 30
-    assert sum(e.name.startswith("%flash_bwd_dkv.")
-               for e in found["flash_backward"]) == 2 * 30 * 2
+               for e in found["flash_head_dim_forward"])
+    # 2 steps x 30 layers: the forward and its recomputation; a backward
+    # call is its ONE dQ launch, and the dK/dV launches (two a call in this
+    # trace, recorded before PR 36; one since) add their time and no need
+    assert len(found["flash_head_dim_forward"]) == 2 * 30 * 2
+    assert len(found["flash_head_dim_backward"]) == 2 * 30
+    assert all(e.name.startswith("%flash_bwd_dq.")
+               for e in found["flash_head_dim_backward"])
+    assert len(found["flash_head_dim_time_only"]) == 2 * 30 * 2
+    assert all(e.name.startswith("%flash_bwd_dkv.")
+               for e in found["flash_head_dim_time_only"])
     cfg = man.config("starcoder2-3b")
+    assert "head_dim" not in cfg["model"]  # 3072 // 24, not stated
     got = man.reducer("kernel_roofline").reduce(
         {"trace": old, "manifest": man, "config": cfg,
          "peaks": device.peaks("TPU v5 lite")},
-        man.metric_file("flash_roofline")["args"])
+        man.metric_file("flash_head_dim_roofline")["args"])
     # 9 matmul units a layer and step (2 + 2 forward, 5 backward) of
     # 2 * 128 * T(T+1)/2 * 24 heads, over 197 TFLOP/s and the kernels' time
     need = 2 * 30 * 9 * 2.0 * 128 * (4096 * 4097 / 2) * 24
@@ -521,6 +527,6 @@ def test_the_rooflines_find_their_kernels_by_name(train, backlog):
     other = ptrace.Event('%scan_chunk.3 = bf16[24,4096,128]{2,1,0} '
                          'custom-call(bf16[8] %x), '
                          'custom_call_target="tpu_custom_call"', 0.0, 1.0)
-    for metric in ("flash_roofline", "paged_roofline"):
+    for metric in ("flash_head_dim_roofline", "paged_roofline"):
         for k in man.metric_file(metric)["args"]["kernels"]:
             assert not ptrace.matching([other], k["pattern"]), k

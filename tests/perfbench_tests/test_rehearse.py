@@ -52,6 +52,42 @@ def test_rehearsal_runs_and_prints_no_device_metric(kind, cell, capsys):
                             for c in compared if "number" in c)
 
 
+@pytest.mark.parametrize("seconds,left_min,closed_on", [
+    ("0.02", 0, "clock"), ("30", 0, "empty"), ("30", 2, "empty")])
+def test_a_backlog_run_says_how_its_window_closed(seconds, left_min,
+                                                  closed_on, tmp_path,
+                                                  capsys):
+    """``info.closed_on`` / ``info.queue_left``, and again in the last line
+    (``window``): on the clock with requests still queued, or on an empty
+    queue — which the mix's ``queue_left_min`` makes a run that is not
+    correct (``queue_short``, the first number compared), since slots with
+    nothing to refill from do not run the cell's load."""
+    tr = Manifest().traffic("decode_backlog")
+    tr["rehearse"]["queue_left_min"] = left_min
+    os.makedirs(tmp_path / "traffic")
+    (tmp_path / "traffic" / "decode_backlog.json").write_text(json.dumps(tr))
+    rc = prun.main(["--workload", "gpt2-xl_serve_backlog", "--seed", "5",
+                    "--seconds", seconds, "--rehearse",
+                    "--data", str(tmp_path)])
+    out = capsys.readouterr()
+    lines = [json.loads(x) for x in out.out.splitlines() if x.startswith("{")]
+    info = next(x["info"] for x in lines if "info" in x)
+    last = lines[-1]
+    assert info["closed_on"] == closed_on == last["window"]["closed_on"]
+    assert info["queue_left"] == last["window"]["queue_left"]
+    assert (info["queue_left"] > 0) == (closed_on == "clock")
+    short = last["compared"]["queue_short"]
+    assert short == {"value": float(max(0, left_min - info["queue_left"])),
+                     "limit": 0.0}
+    assert last["correct"] is (short["value"] == 0.0) and rc == (
+        0 if last["correct"] else 1)
+    assert "perfbench: compared queue_short" in out.err
+    # every tick of the window is the replay's, whatever the clock did
+    assert info["schedule"]["off"] == 0
+    assert info["schedule"]["fill"] == info["schedule"]["fill_replay"]
+    assert info["schedule"]["ticks"] == info["ticks"]
+
+
 def test_without_a_tpu_the_command_fails_and_prints_no_result(capsys):
     cell = Manifest().doc["workloads"][0]["name"]
     rc = prun.main(["--workload", cell, "--seed", "1", "--seconds", "1",

@@ -41,6 +41,10 @@ def rehearsal(tmp_path_factory):
         cfg, model, weights.make_params(specs, 13, pdt), rehearse=True)
     clock = Clock()
     serving.warm_programs(eng, clock, m["vocab"], sv["prefill_chunk"])
+    warmed = {"cow": eng.cow_compiles, "mixed": eng.mixed_compiles,
+              "prefill": eng.prefill_compiles,
+              "cached_blocks": eng.prefix.cached_blocks,
+              "free_blocks": eng.pool.allocator.free_blocks}
     reqs = traffic_gen.decode_backlog(tr, m["vocab"], 2**31 + 42)
     sched, rec = serving.new_scheduler(eng, clock, reqs)
     for r in reqs:
@@ -80,9 +84,11 @@ def rehearsal(tmp_path_factory):
                                     "*.xplane.pb"))
     t = pt.load(path)
     assert t is not None, "no xplane_pb2 to read the trace with"
-    facts = {"program_trace": t, "traffic": traffic, "traced_units": n,
+    facts = {"program_trace": t, "traffic": tr, "traced_units": n,
              "unit_ledgers": {"serve_tick": obs.unit_ledger("serve_tick")}}
-    return {"man": man, "facts": facts, "door": door, "fill": fill}
+    return {"man": man, "facts": facts, "door": door, "fill": fill,
+            "tr": tr, "chunk": sv["prefill_chunk"], "reqs": reqs,
+            "warmed": warmed, "eng": eng}
 
 
 def test_rode_share_is_the_hand_count_over_the_window(rehearsal):
@@ -98,6 +104,70 @@ def test_rode_share_is_the_hand_count_over_the_window(rehearsal):
                          rehearsal["man"].metric_file(NAME)["args"])
     assert sum(u.counts.get("cmn_serve_decode.chunk_rows", 0) > 0
                for u in units) == rode
+
+
+def test_the_replay_is_the_schedulers_schedule_tick_for_tick(rehearsal):
+    """``traffic_gen.replay_backlog`` against the scheduler itself, over the
+    pool fill and the whole drain: per tick the chunks started, the one
+    that rode and the rows of the decode step, from the unit ledger — and
+    the calls at the engine's door, tick by tick."""
+    tr, door = rehearsal["tr"], rehearsal["door"]
+    lengths = [(len(r.prompt), r.max_new) for r in rehearsal["reqs"]]
+    assert lengths == traffic_gen.backlog_lengths(tr)
+    fill, ticks = traffic_gen.replay_backlog(lengths, tr["slots"],
+                                             rehearsal["chunk"])
+    units = rehearsal["facts"]["unit_ledgers"]["serve_tick"].units()
+    assert fill == rehearsal["fill"]
+    assert [u.ordinal for u in units] == list(range(len(ticks)))
+    assert [(u.calls.get("cmn_serve_prefill", 0),
+             u.counts.get("cmn_serve_prefill.rode", 0),
+             u.counts.get("cmn_serve_decode.live", 0),
+             u.counts.get("cmn_serve_admit.admitted", 0),
+             u.counts.get("cmn_serve_emit.retired", 0)) for u in units] == \
+        [(t.calls, t.rode, t.live, t.admitted,
+          # a request over at its first token is retired outside the emit
+          t.finished if t.live else 0) for t in ticks]
+    assert door["mixed_step"] == [i for i, t in enumerate(ticks) if t.rode]
+    assert door["prefill"] == [i for i, t in enumerate(ticks)
+                               for _ in range(t.calls - t.rode)]
+    assert sum(t.tokens for t in ticks) == sum(o for _, o in lengths)
+
+
+def test_warm_up_reaches_every_program_a_window_can_call(rehearsal):
+    """``serving.warm_programs``: the decode step, the mixed step, the
+    whole-chunk prefill and — through a request that shares half a block
+    with an earlier one — the copy-on-write program, whose first call a
+    prefix hit between two random prompts otherwise makes inside the window
+    (PERF.md: 169 ms of ``cmn_compile`` in tick 1214 of seed ...713).  It
+    leaves the trie empty and every block free, and the drive after it
+    compiles nothing."""
+    warmed, eng = rehearsal["warmed"], rehearsal["eng"]
+    assert warmed["cow"] == 1 and warmed["mixed"] == 1
+    assert warmed["prefill"] == 1 and warmed["cached_blocks"] == 0
+    assert warmed["free_blocks"] == eng.pool.allocator.num_blocks - 1
+    assert (eng.cow_compiles, eng.mixed_compiles, eng.prefill_compiles) == \
+        (1, 1, 1)
+    units = rehearsal["facts"]["unit_ledgers"]["serve_tick"].units()
+    assert sum(u.calls.get("cmn_compile", 0) for u in units) == 0
+
+
+def test_the_traced_share_is_taken_over_the_traced_ticks_alone(rehearsal):
+    """``traced_prefill_rode_share``: the same count over the traced ticks
+    only — how far the profiled stretch stands for the window."""
+    man, tr = rehearsal["man"], rehearsal["tr"]
+    spec = man.metric_file("traced_prefill_rode_share")
+    whole = man.metric_file(NAME)
+    assert spec["args"] == dict(whole["args"], over="traced")
+    got = man.reducer(spec["reducer"]).reduce(rehearsal["facts"],
+                                              spec["args"])
+    _, ticks = traffic_gen.replay_backlog(
+        [(len(r.prompt), r.max_new) for r in rehearsal["reqs"]],
+        tr["slots"], rehearsal["chunk"])
+    a = rehearsal["fill"] + tr["trace_from_tick"]
+    traced = ticks[a:a + tr["trace_ticks"]]
+    calls = sum(t.calls for t in traced)
+    assert calls, "the traced ticks of the rehearsal hold no chunk"
+    assert got == pytest.approx(sum(t.rode for t in traced) / calls)
 
 
 def test_a_ledger_without_the_count_reads_zero():

@@ -144,3 +144,75 @@ def test_every_backlog_layer_metric_reads_the_recorded_trace(recorded):
     assert got["paged_roofline"] == pytest.approx(
         100 * need / 819e9 / kernel_s, rel=1e-6)
     assert got["paged_roofline"] < 100
+
+
+MIXED = os.path.join(HERE, "fixtures",
+                     "serve_backlog_2ticks_mixed_program.xplane.pb.gz")
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """The first two ticks of the traced stretch (window ticks 1157 and
+    1158; my chip run, PR 43, seed 2147490102): a chunk riding the decode
+    step beside a call of its own, then a riding chunk alone — as
+    ``traffic_gen.replay_backlog`` says they are."""
+    return pt.load(MIXED), program_trace.load(MIXED)
+
+
+def test_paged_ms_tick_is_the_ticks_main_program_whichever_it_is(
+        recorded, mixed):
+    man = Manifest()
+    t, spans = mixed
+    [d] = t.devices.values()
+    runs = {}
+    for m in d.modules:
+        runs.setdefault(m.name.split("(")[0], []).append(m.dur)
+    # no tick of this stretch ran the plain step: a pattern naming
+    # step_impl alone (as until PR 43) reads nothing here
+    assert {k: len(v) for k, v in runs.items()} == {
+        "jit_mixed_impl": 2, "jit_prefill_impl": 1}
+
+    def read(name, trace, **args):
+        spec = man.metric_file(name)
+        return man.reducer(spec["reducer"]).reduce(
+            {"trace": trace, "traced_units": 2},
+            dict(spec["args"], **args))
+
+    assert read("paged_ms_tick", t, pattern="step_impl") == 0.0
+    assert read("paged_ms_tick", t) == pytest.approx(
+        1e3 * sum(runs["jit_mixed_impl"]) / 2)
+    assert 16.5 < read("paged_ms_tick", t) < 18
+    # ... and the older fixture's two plain steps still answer to it
+    assert read("paged_ms_tick", recorded) == pytest.approx(651, rel=0.01)
+    # prefill_dev_share stays the share of chunks that are calls of their own
+    busy, _ = pt.busy_seconds(t)
+    assert read("prefill_dev_share", t) == pytest.approx(
+        100 * runs["jit_prefill_impl"][0] / busy)
+    assert 10 < read("prefill_dev_share", t) < 20
+    # the program's counts say the same of the two ticks
+    chunks = [(s.stats["rode"], s.stats["tokens"], s.stats["final"])
+              for s in spans.named("cmn_serve_prefill")]
+    assert chunks == [(1, 10, 1), (0, 32, 0), (1, 32, 0)]
+    assert [(s.stats["live"], s.stats["chunk_rows"])
+            for s in spans.named("cmn_serve_decode")] == [(30, 10), (31, 32)]
+
+
+def test_the_mixed_fixture_is_the_replays_ticks(mixed):
+    from perfbench import traffic_gen as tg
+
+    man = Manifest()
+    tr = man.traffic("decode_backlog")
+    chunk = man.config("gpt2-xl")["serve"]["prefill_chunk"]
+    fill, ticks = tg.replay_backlog(tg.backlog_lengths(tr), tr["slots"],
+                                    chunk, max_ticks=1300)
+    spans = mixed[1]
+    got = sorted(s.stats["tick"] for s in spans.named("cmn_serve_tick"))
+    first = fill + tr["trace_from_tick"]
+    assert got == [first, first + 1]
+    want = ticks[first:first + 2]
+    assert [(t.live, t.calls, t.rode) for t in want] == [
+        (30, 2, 1), (31, 1, 1)]
+    assert [s.stats["live"] for s in spans.named("cmn_serve_decode")] == \
+        [t.live for t in want]
+    assert len(spans.named("cmn_serve_prefill")) == sum(
+        t.calls for t in want)

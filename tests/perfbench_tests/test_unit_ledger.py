@@ -100,7 +100,7 @@ def rehearsal(tmp_path_factory):
                        str(tmp_path_factory.mktemp("ledger_trace")))
     ledger = obs.unit_ledger("serve_tick")
     assert ledger is sched._units
-    facts = {"program_trace": t, "traffic": traffic,
+    facts = {"program_trace": t, "traffic": tr,
              "traced_units": tr["trace_ticks"],
              "unit_ledgers": {"serve_tick": ledger}}
     return {"man": man, "facts": facts, "ledger": ledger, "trace": t,
@@ -364,7 +364,8 @@ def test_the_reducers_over_a_hand_made_ledger(man):
               _tick(24, 0.024, calls=1)]
     facts = {"program_trace": _made_up_trace("cmn_serve_tick", "tick",
                                              [14, 15, 16]),
-             "traffic": man.traffic("decode_backlog"),
+             "traffic": dict(man.traffic("decode_backlog"),
+                             trace_from_tick=2),
              "unit_ledgers": {"serve_tick": _Hand(units)}}
     window = units[2:]
     got = {name: _reduce(man, name, facts) for name in SERVE}
