@@ -48,6 +48,7 @@ from chainermn_tpu.models.transformer import (
     dense_lm_reference,
     init_parallel_lm,
     lm_generate,
+    lm_head_logits,
     lm_loss,
     lm_loss_chunked,
     parallel_lm_specs,
@@ -89,6 +90,7 @@ __all__ = [
     "TransformerLM",
     "HybridLM",
     "lm_generate",
+    "lm_head_logits",
     "lm_beam_search",
     "lm_speculative_generate",
     "lm_loss",

@@ -1,6 +1,7 @@
-"""A decoder-only LM whose depth is a string of layer kinds — the
-Nemotron-H family's shape: every layer is ``h <- h + mixer(RMSNorm(h))`` with
-ONE mixer, its kind read from ``layer_kinds[i]``:
+"""A decoder-only LM whose depth is a string of layer kinds, one letter a
+published layer.  The Nemotron-H family's shape: every layer is
+``h <- h + mixer(RMSNorm(h))`` with ONE mixer, its kind read from
+``layer_kinds[i]``:
 
 * ``M`` — a Mamba-2 mixer: one input projection to ``[z | xBC | dt]``, a
   causal depthwise convolution and SiLU over ``xBC``, the state-space
@@ -15,28 +16,71 @@ ONE mixer, its kind read from ``layer_kinds[i]``:
   The routed experts elsewhere are left out of the sum: ``ep_of = 1`` holds
   them all.
 
+And the Falcon-H1 family's, whose layer runs TWO mixers side by side:
+
+* ``F`` — a whole Falcon-H1 block: ``h <- h + m + a`` with ``m`` the
+  Mamba-2 mixer of ``M`` and ``a`` grouped-query attention with RoPE over
+  the whole head, both of one ``RMSNorm(h)``; then a SwiGLU,
+  ``h <- h + down(silu(gate(v)) * up(v))`` of a second norm ``v``.  The
+  family's fourteen muP multipliers (``embedding_multiplier``,
+  ``ssm_in_multiplier``, the five ``ssm_multipliers`` on the input
+  projection's segments ``[z | x | B | C | dt]``, ``ssm_out_multiplier``,
+  ``attention_in_multiplier``, ``key_multiplier``,
+  ``attention_out_multiplier``, the two ``mlp_multipliers`` on the gate and
+  on the FFN's result, ``lm_head_multiplier``) are fields whose default, 1,
+  adds no operation.
+
 RMS norms, no bias anywhere but the convolution's, an untied bias-free head.
 Trains through :func:`~chainermn_tpu.models.lm_loss_chunked` like
 :class:`~chainermn_tpu.models.TransformerLM` (``return_hidden=True``, the
 head read from ``lm_head/kernel``); each block is under ``jax.checkpoint``
-when ``remat``.  Training only: no cache, no decode path.
+when ``remat``.
+
+**Decode path** (``F`` layers; the calling convention
+:class:`~chainermn_tpu.serving.DecodeEngine` uses for ``TransformerLM``):
+``cache`` holds one entry a layer — the paged ``{"kv"}`` pool attention
+writes and reads through ``block_tables``
+(:mod:`chainermn_tpu.ops.decode_attention`), and beside it the layer's
+**recurrent state by slot**, ``{"ssm": (slots, H, P, N) float32, "conv":
+(slots, K - 1, inner + 2 G N)}`` (:meth:`HybridLM.state_shapes`).  Decode
+rows (``T == 1``, ``decode_pos`` per row) are the slots in order: row ``r``
+advances slot ``r``'s state by one position
+(:func:`~chainermn_tpu.ops.ssd_scan.ssd_step`, scope ``ssm.step``), a row
+``slot_mask`` leaves out keeps it to the bit.  A prefill chunk
+(``T > 1``, one scalar ``decode_pos``, one table) and the ``chunk_rows``
+that ride a decode step are ONE sequence of slot ``state_slot``: the
+chunked scan from that slot's state to that slot's state (``ssm.scan``),
+over the first ``chunk_len`` rows — the rows past a short tail are given
+``dt = 0`` and the convolution's tail is taken at the last real position.
+A chunk that starts at position 0 starts from zeros, inside the program:
+that is what makes a used slot's next request, and an evicted request's
+recompute, right.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, NamedTuple, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from chainermn_tpu.ops.decode_attention import paged_attend, pool_write
 from chainermn_tpu.ops.flash_attention import (
     flash_attention,
     reference_attention,
     resolve_attention,
 )
-from chainermn_tpu.ops.ssd_scan import causal_depthwise_conv, ssd_scan
+from chainermn_tpu.ops.rope import apply_rope, rope_tables
+from chainermn_tpu.ops.ssd_scan import (
+    causal_depthwise_conv,
+    conv_step,
+    conv_tail,
+    ssd_scan,
+    ssd_step,
+)
+from chainermn_tpu.models.transformer import _wants_paged_kernel
 from chainermn_tpu.parallel.held_experts import (
     held_experts_ffn,
     held_range,
@@ -44,7 +88,13 @@ from chainermn_tpu.parallel.held_experts import (
     sigmoid_topk_route,
 )
 
-LAYER_KINDS = "M*E"
+LAYER_KINDS = "M*EF"
+
+
+def _times(x, m):
+    """``x * m``; a multiplier left at 1 adds no operation."""
+    return x if m == 1 else x * m
+
 
 def router_bias(layer: int, n_experts: int) -> jax.Array:
     """The ``e_bias`` buffer of layer ``layer``: a constant outside the
@@ -64,6 +114,21 @@ def rms_norm(x, scale, eps: float, groups: int = 1):
         return g.reshape(x.shape) * scale.astype(jnp.float32)
 
 
+class _Decode(NamedTuple):
+    """What a block's decode path is handed besides its cache entry (see the
+    module docstring): plain data, ``chunk_rows`` and ``paged_kernel``
+    static."""
+
+    decode_pos: Any       # scalar or (B,), as the model was given it
+    q_pos: Any            # (B, T) the position of every token
+    block_tables: Any     # (B, max_blocks)
+    slot_mask: Any        # (B,) bool or None
+    chunk_rows: int
+    state_slot: Any       # the slot the chunk's rows are a sequence of
+    chunk_len: Any        # how many of the chunk's rows hold text
+    paged_kernel: bool
+
+
 class _HybridBlock(nn.Module):
     kind: str
     layer: int
@@ -77,68 +142,188 @@ class _HybridBlock(nn.Module):
                                name=name)
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, rope=None, cache=None, dec=None):
         c = self.cfg
         scale = self.param("norm", nn.initializers.ones, (c.d_model,),
                            c.param_dtype)
         u = rms_norm(h, scale, c.norm_eps)
+        if self.kind == "F":
+            return self._falcon(h, u, rope, cache, dec)
+        if cache is not None:
+            raise NotImplementedError(
+                f"layer kind {self.kind!r} has no decode path: only 'F' "
+                "layers keep a cache")
         mixer = {"M": self._mamba, "*": self._attention, "E": self._experts}
         return h + mixer[self.kind](u).astype(h.dtype)
 
+    # ------------------------------------------------------------ F
+    def _falcon(self, h, u, rope, cache, dec):
+        """Both mixers of one norm, then the gated FFN of a second."""
+        c = self.cfg
+        m, state = self._mamba(u, cache, dec)
+        a, kv = self._attention(u, rope, cache, dec)
+        h = (h + _times(m, c.ssm_out_multiplier).astype(h.dtype)
+             + _times(a, c.attention_out_multiplier).astype(h.dtype))
+        scale = self.param("norm_ff", nn.initializers.ones, (c.d_model,),
+                           c.param_dtype)
+        x = rms_norm(h, scale, c.norm_eps).astype(c.dtype)
+        gate_m, down_m = c.mlp_multipliers
+        # gate, up, down and the residual add read as ONE layer in a device
+        # trace, as TransformerLM's FFN does
+        with jax.named_scope("ffn"):
+            g = _times(self._dense(c.d_ff, "gate")(x), gate_m)
+            y = self._dense(c.d_model, "down")(
+                jax.nn.silu(g) * self._dense(c.d_ff, "up")(x))
+            h = h + _times(y, down_m).astype(h.dtype)
+        return h if cache is None else (h, {**kv, **state})
+
     # ------------------------------------------------------------ M
-    def _mamba(self, u):
+    def _mamba(self, u, cache=None, dec=None):
+        """The mixer's output; called from an ``F`` layer, ``(output, the
+        cache entry's ``{"ssm", "conv"}`` as they are after)`` — nothing
+        where there is no cache."""
         c = self.cfg
         B, T, _ = u.shape
         H, P, G, N = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
         inner, bc = H * P, G * N
         with jax.named_scope("ssm.in_proj"):
             zxbcdt = self._dense(2 * inner + 2 * bc + H, "in_proj")(
-                u.astype(c.dtype))
+                _times(u, c.ssm_in_multiplier).astype(c.dtype))
+            if any(m != 1 for m in c.ssm_multipliers):
+                widths = (inner, inner, bc, bc, H)  # z, x, B, C, dt
+                zxbcdt = zxbcdt * jnp.concatenate([
+                    jnp.full((w,), m, c.dtype)
+                    for w, m in zip(widths, c.ssm_multipliers)])
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], -1)
-        with jax.named_scope("ssm.conv"):
-            kernel = self.param("conv_kernel", nn.initializers.normal(0.02),
-                                (c.conv_kernel, inner + 2 * bc), c.param_dtype)
-            bias = self.param("conv_bias", nn.initializers.zeros,
-                              (inner + 2 * bc,), c.param_dtype)
-            xbc = jax.nn.silu(causal_depthwise_conv(
-                xbc, kernel.astype(c.dtype), bias.astype(c.dtype)))
-            x, Bm, Cm = jnp.split(xbc, [inner, inner + bc], -1)
-        with jax.named_scope("ssm.scan"):
-            dt_bias = self.param("dt_bias", nn.initializers.zeros, (H,),
-                                 c.param_dtype)
-            A_log = self.param("A_log", nn.initializers.zeros, (H,),
-                               c.param_dtype)
-            D = self.param("D", nn.initializers.ones, (H,), c.param_dtype)
-            delta = jax.nn.softplus(dt.astype(jnp.float32)
-                                    + dt_bias.astype(jnp.float32))
-            y = ssd_scan(x.reshape(B, T, H, P), delta,
-                         -jnp.exp(A_log.astype(jnp.float32)),
-                         Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N),
-                         chunk=min(c.ssm_chunk, T), D=D)
+        kernel = self.param("conv_kernel", nn.initializers.normal(0.02),
+                            (c.conv_kernel, inner + 2 * bc), c.param_dtype)
+        bias = self.param("conv_bias", nn.initializers.zeros,
+                          (inner + 2 * bc,), c.param_dtype)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (H,),
+                             c.param_dtype)
+        A_log = self.param("A_log", nn.initializers.zeros, (H,),
+                           c.param_dtype)
+        D = self.param("D", nn.initializers.ones, (H,), c.param_dtype)
+        state = {}
+        if cache is None:
+            with jax.named_scope("ssm.conv"):
+                xbc = jax.nn.silu(causal_depthwise_conv(
+                    xbc, kernel.astype(c.dtype), bias.astype(c.dtype)))
+                x, Bm, Cm = jnp.split(xbc, [inner, inner + bc], -1)
+            with jax.named_scope("ssm.scan"):
+                delta = jax.nn.softplus(dt.astype(jnp.float32)
+                                        + dt_bias.astype(jnp.float32))
+                y = ssd_scan(x.reshape(B, T, H, P), delta,
+                             -jnp.exp(A_log.astype(jnp.float32)),
+                             Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N),
+                             chunk=min(c.ssm_chunk, T), D=D)
+        else:
+            y, state = self._recur(xbc, dt, cache, dec, kernel.astype(c.dtype),
+                                   bias.astype(c.dtype), dt_bias, A_log, D)
         with jax.named_scope("ssm.gate_out"):
             gate = self.param("gate_norm", nn.initializers.ones, (inner,),
                               c.param_dtype)
             y = y.reshape(B, T, inner) * jax.nn.silu(z.astype(jnp.float32))
             y = rms_norm(y, gate, c.norm_eps, groups=G)
-            return self._dense(c.d_model, "out_proj")(y.astype(c.dtype))
+            out = self._dense(c.d_model, "out_proj")(y.astype(c.dtype))
+        return out if self.kind == "M" else (out, state)
+
+    def _recur(self, xbc, dt, cache, dec, kernel, bias, dt_bias, A_log, D):
+        """The convolution and the recurrence against the slots' state:
+        ``(y (rows, T, H, P) float32, {"ssm", "conv"} as they are after)``.
+        The decode rows step their slots' state (``ssm.step``); the chunk's
+        rows — a prefill chunk's ``T``, or the last ``chunk_rows`` — scan
+        from slot ``state_slot``'s state to it (``ssm.scan``)."""
+        c = self.cfg
+        H, P, G, N = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
+        inner, bc = H * P, G * N
+        rows, T = xbc.shape[:2]
+        C = dec.chunk_rows if T == 1 else T
+        S = rows - C if T == 1 else 0
+        if T > 1 and rows != 1:
+            raise ValueError(
+                f"a prefill chunk is one sequence of one slot, got {rows} rows")
+        ssm, conv = cache["ssm"], cache["conv"]
+        f32 = jnp.float32
+        A = -jnp.exp(A_log.astype(f32))
+        dt = dt.astype(f32) + dt_bias.astype(f32)
+        ys = []
+        if S:
+            live = (jnp.ones((S,), bool) if dec.slot_mask is None
+                    else dec.slot_mask[:S].astype(bool))
+            with jax.named_scope("ssm.conv"):
+                out, tail = conv_step(conv, xbc[:S, 0], kernel, bias)
+                conv = jnp.where(live[:, None, None], tail, conv)
+                x, Bm, Cm = jnp.split(jax.nn.silu(out), [inner, inner + bc], -1)
+            with jax.named_scope("ssm.step"):
+                delta = jnp.where(live[:, None],
+                                  jax.nn.softplus(dt[:S, 0]), 0.0)
+                y, ssm = ssd_step(ssm, x.reshape(S, H, P), delta, A,
+                                  Bm.reshape(S, G, N), Cm.reshape(S, G, N), D)
+            ys.append(y.reshape(S, 1, H, P))
+        if C:
+            def seq(v):  # the chunk's rows as one sequence (1, C, ...)
+                return jnp.swapaxes(v[S:], 0, 1) if T == 1 else v
+
+            slot = 0 if dec.state_slot is None else dec.state_slot
+            n = C if dec.chunk_len is None else dec.chunk_len
+            fresh = dec.q_pos[S, 0] == 0
+            with jax.named_scope("ssm.conv"):
+                xc = seq(xbc)
+                tail = jnp.where(fresh, 0, jax.lax.dynamic_index_in_dim(
+                    conv, slot, 0, keepdims=True)).astype(conv.dtype)
+                out = causal_depthwise_conv(xc, kernel, bias, tail=tail)
+                conv = jax.lax.dynamic_update_slice_in_dim(
+                    conv, conv_tail(xc, tail, n).astype(conv.dtype), slot, 0)
+                x, Bm, Cm = jnp.split(jax.nn.silu(out), [inner, inner + bc], -1)
+            with jax.named_scope("ssm.scan"):
+                s0 = jnp.where(fresh, 0.0, jax.lax.dynamic_index_in_dim(
+                    ssm, slot, 0, keepdims=True))
+                delta = jnp.where((jnp.arange(C) < n)[None, :, None],
+                                  jax.nn.softplus(seq(dt)), 0.0)
+                Q = C if C <= c.ssm_chunk else c.ssm_chunk
+                y, s1 = ssd_scan(x.reshape(1, C, H, P), delta, A,
+                                 Bm.reshape(1, C, G, N), Cm.reshape(1, C, G, N),
+                                 chunk=Q, D=D, initial_state=s0,
+                                 return_state=True)
+                ssm = jax.lax.dynamic_update_slice_in_dim(
+                    ssm, s1.astype(ssm.dtype), slot, 0)
+            ys.append(jnp.swapaxes(y, 0, 1) if T == 1 else y)
+        y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=0)
+        return y, {"ssm": ssm, "conv": conv}
 
     # ------------------------------------------------------------ *
-    def _attention(self, u):
+    def _attention(self, u, rope=None, cache=None, dec=None):
+        """The mixer's output; from ``F``, ``(output, the cache entry's new
+        ``{"kv"}``)``: keys and values go into the paged pool and the read
+        is :func:`~chainermn_tpu.ops.decode_attention.paged_attend`'s."""
         c = self.cfg
         T = u.shape[1]
-        u = u.astype(c.dtype)
+        u = _times(u, c.attention_in_multiplier).astype(c.dtype)
         with jax.named_scope("attn_qkv"):
             q = self._dense((c.n_heads, c.head_dim), "q")(u)
             kv = self._dense((2, c.n_kv_heads, c.head_dim), "kv")(u)
-            k, v = kv[:, :, 0], kv[:, :, 1]
-        if resolve_attention(c.attention, T) == "flash":
+            k, v = _times(kv[:, :, 0], c.key_multiplier), kv[:, :, 1]
+            if rope is not None:
+                # before the pool's write: a cached key keeps its rotation
+                q, k = apply_rope(q, tables=rope), apply_rope(k, tables=rope)
+        pool = {}
+        if cache is not None:
+            pool = pool_write({"kv": cache["kv"]}, k, v, None,
+                              dec.block_tables, dec.q_pos, dec.slot_mask)
+            a = paged_attend(q, pool, dec.block_tables, dec.decode_pos,
+                             dec.q_pos, dec.slot_mask,
+                             kernel=dec.paged_kernel,
+                             chunk_rows=dec.chunk_rows)
+        elif resolve_attention(c.attention, T) == "flash":
             with jax.named_scope("attn.flash"):
                 a = flash_attention(q, k, v, causal=True)
         else:
             with jax.named_scope("attn.xla"):
                 a = reference_attention(q, k, v, causal=True).astype(q.dtype)
         with jax.named_scope("attn_out"):
-            return self._dense(c.d_model, "proj", axis=(-2, -1))(a)
+            out = self._dense(c.d_model, "proj", axis=(-2, -1))(a)
+        return out if self.kind == "*" else (out, pool)
 
     # ------------------------------------------------------------ E
     def _experts(self, u):
@@ -222,6 +407,22 @@ class HybridLM(nn.Module):
     d_expert: int = 256
     d_shared: int = 256
     norm_eps: float = 1e-5
+    # F Falcon-H1 block: the gated FFN's width, RoPE's base, and the
+    # family's muP multipliers (1 adds no operation)
+    d_ff: int = 1024
+    rope_theta: float = 10000.0
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Sequence[float] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    mlp_multipliers: Sequence[float] = (1.0, 1.0)
+    #: whether paged decode rows may run the Pallas kernel ("fused") or
+    #: take the gathered read ("einsum"), as ``TransformerLM``'s field
+    decode_attention: str = "einsum"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     #: each block under ``jax.checkpoint``: O(n_layers) residuals only
@@ -233,31 +434,96 @@ class HybridLM(nn.Module):
                         "moe_rows_max_over_mean": jnp.max,
                         "moe_pairs_dropped": jnp.sum}
 
-    @nn.compact
-    def __call__(self, tokens, segment_ids=None, return_hidden: bool = False):
-        if segment_ids is not None:
-            raise NotImplementedError(
-                "HybridLM trains whole rows: packed documents would need the "
-                "scan's state and the convolution reset at each boundary")
+    def _kinds(self) -> str:
         kinds = self.layer_kinds[:self.n_layers]
         if len(kinds) != self.n_layers or set(kinds) - set(LAYER_KINDS):
             raise ValueError(
                 f"layer_kinds={self.layer_kinds!r}: need {self.n_layers} "
                 f"letters of {LAYER_KINDS!r}")
+        return kinds
+
+    def state_shapes(self):
+        """What ONE slot keeps of each layer beside its paged keys and
+        values, ``{name: (shape, dtype)}`` a layer: the recurrence's state
+        in float32 (up to a context's length of steps compound in it) and
+        the convolution's last ``conv_kernel - 1`` inputs in the compute
+        dtype.  The serving pool is built from it
+        (:class:`~chainermn_tpu.serving.kv_pool.PagedKVPool`)."""
+        kinds = self._kinds()
+        if set(kinds) != {"F"}:
+            raise NotImplementedError(
+                f"layer_kinds={kinds!r}: only 'F' layers have a decode path")
+        width = (self.ssm_heads * self.ssm_head_dim
+                 + 2 * self.ssm_groups * self.ssm_state)
+        return [{"ssm": ((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+                         jnp.float32),
+                 "conv": ((self.conv_kernel - 1, width), self.dtype)}
+                for _ in kinds]
+
+    @nn.compact
+    def __call__(self, tokens, segment_ids=None, return_hidden: bool = False,
+                 cache=None, decode_pos=None, block_tables=None,
+                 slot_mask=None, chunk_rows: int = 0, state_slot=None,
+                 chunk_len=None):
+        """(B, T) int32 -> (B, T, vocab) float32 logits, or the pre-head
+        hidden states with ``return_hidden``.  With ``cache`` (the module
+        docstring's decode path; ``block_tables`` required) the result is
+        ``(that, new_cache)``."""
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "HybridLM trains whole rows: packed documents would need the "
+                "scan's state and the convolution reset at each boundary")
+        kinds = self._kinds()
+        B, T = tokens.shape
+        dec = None
+        if cache is not None:
+            if block_tables is None:
+                raise ValueError(
+                    "HybridLM's cache is the serving engine's paged pool "
+                    "with the slots' state: block_tables is required")
+            self.state_shapes()  # refuses kinds without a decode path
+            if jnp.ndim(decode_pos) == 0:
+                q_pos = jnp.broadcast_to(
+                    (decode_pos + jnp.arange(T))[None], (B, T))
+            else:
+                q_pos = decode_pos[:, None] + jnp.arange(T)[None]
+            dec = _Decode(decode_pos, q_pos, block_tables, slot_mask,
+                          chunk_rows, state_slot, chunk_len,
+                          _wants_paged_kernel(self.decode_attention))
+        elif chunk_rows:
+            raise ValueError("chunk_rows needs the paged cache")
         with jax.named_scope("embed"):
             h = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
                          param_dtype=self.param_dtype, name="embed")(tokens)
+            h = _times(h, self.embedding_multiplier)
+        rope = None
+        if "F" in kinds:  # once, shared by every layer
+            rope = rope_tables(jnp.arange(T) if dec is None else dec.q_pos,
+                               self.head_dim, float(self.rope_theta))
         fields = _Fields(tuple(
-            (f.name, getattr(self, f.name)) for f in dataclasses.fields(self)
-            if f.name not in ("parent", "name")))
-        block = nn.remat(_HybridBlock) if self.remat else _HybridBlock
+            (f.name, tuple(v) if isinstance(v, list) else v)
+            for f in dataclasses.fields(self)
+            if f.name not in ("parent", "name")
+            for v in (getattr(self, f.name),)))
+        block = (nn.remat(_HybridBlock) if self.remat and cache is None
+                 else _HybridBlock)
+        new_cache = []
         for i, kind in enumerate(kinds):
-            h = block(kind=kind, layer=i, cfg=fields, name=f"block_{i}")(h)
+            blk = block(kind=kind, layer=i, cfg=fields, name=f"block_{i}")
+            if cache is not None:
+                h, entry = blk(h, rope, cache[i], dec)
+                new_cache.append(entry)
+            elif kind == "F":
+                h = blk(h, rope)
+            else:
+                h = blk(h)
         scale = self.param("norm_f", nn.initializers.ones, (self.d_model,),
                            self.param_dtype)
         h = rms_norm(h, scale, self.norm_eps).astype(self.dtype)
-        if return_hidden:
-            return h
-        with jax.named_scope("head"):
-            return nn.Dense(self.vocab, use_bias=False, dtype=jnp.float32,
-                            param_dtype=self.param_dtype, name="lm_head")(h)
+        if not return_hidden:
+            with jax.named_scope("head"):
+                h = _times(nn.Dense(
+                    self.vocab, use_bias=False, dtype=jnp.float32,
+                    param_dtype=self.param_dtype, name="lm_head")(h),
+                    self.lm_head_multiplier)
+        return h if cache is None else (h, new_cache)

@@ -759,6 +759,21 @@ class TransformerLM(nn.Module):
         ]
 
 
+def lm_head_logits(model, params, h):
+    """float32 logits of hidden rows ``h`` (..., d_model) through the
+    model's own head, for the callers that run a model headless
+    (``return_hidden=True``) and apply the head at a few rows only — the
+    serving engine's prefill and mixed step: ``lm_head``'s kernel, its bias
+    where the model has one, and the model's ``lm_head_multiplier`` where it
+    scales its logits (:class:`~chainermn_tpu.models.HybridLM`)."""
+    head = params["lm_head"]
+    logits = h.astype(jnp.float32) @ head["kernel"].astype(jnp.float32)
+    if "bias" in head:
+        logits = logits + head["bias"].astype(jnp.float32)
+    scale = getattr(model, "lm_head_multiplier", 1)
+    return logits if scale == 1 else logits * scale
+
+
 def _check_generation_length(model: "TransformerLM", P: int,
                              n_new: int) -> int:
     """Shared decode-entry contract (``lm_generate`` and
@@ -1015,6 +1030,12 @@ def lm_loss_chunked(model: nn.Module, chunk_size: int = 4096):
     ``O(B·T·chunk_size)``).  The head params (``lm_head/kernel|bias``) are
     read from the tree, so the same initialized params serve both losses."""
     from chainermn_tpu.ops import chunked_softmax_cross_entropy
+
+    if getattr(model, "lm_head_multiplier", 1) != 1:
+        raise NotImplementedError(
+            "lm_loss_chunked streams lm_head's kernel itself and knows no "
+            "lm_head_multiplier: train this model through lm_loss"
+        )
 
     def loss_fn(params, batch):
         tokens, targets, *rest = batch

@@ -23,6 +23,14 @@ log-decays, their cumulative sums, every ``exp`` and the recurrence over
 chunk states are float32.  Plain ``jax.numpy``: the backward is autodiff's,
 meant to run under the block's ``jax.checkpoint`` (the ``(H, Q, Q)`` decay
 tiles live only while one block is differentiated).
+
+Serving carries the recurrence across calls: a prefill chunk is one
+:func:`ssd_scan` from a slot's state to a slot's state (``initial_state=`` /
+``return_state=``; a position given ``dt = 0`` decays by one and adds
+nothing, so the rows past a short tail leave the state alone to the bit),
+a decode step one position of it a row (:func:`ssd_step`), and the
+convolution's last ``K - 1`` inputs ride beside the state
+(``causal_depthwise_conv(tail=)``, :func:`conv_step`).
 """
 
 from __future__ import annotations
@@ -37,16 +45,63 @@ from chainermn_tpu.utils import pvary_to_match
 
 
 def causal_depthwise_conv(x: jax.Array, kernel: jax.Array,
-                          bias: Optional[jax.Array] = None) -> jax.Array:
+                          bias: Optional[jax.Array] = None,
+                          tail: Optional[jax.Array] = None) -> jax.Array:
     """``out[t, c] = sum_j kernel[j, c] * x[t - (K - 1) + j, c] (+ bias[c])``
     over ``x`` (B, T, C) with ``kernel`` (K, C): each channel sees its own
-    last ``K`` positions, zeros before the first.  ``K`` shifted multiplies
+    last ``K`` positions; before the first, zeros, or ``tail`` (B, K - 1, C),
+    the inputs the sequence had before ``x``.  ``K`` shifted multiplies
     (K is 4 in the published models), which XLA fuses into one pass."""
     K = kernel.shape[0]
     T = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    if tail is None:
+        padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    else:
+        padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     out = sum(padded[:, j:j + T] * kernel[j] for j in range(K))
     return out if bias is None else out + bias
+
+
+def conv_tail(x: jax.Array, tail: jax.Array, length) -> jax.Array:
+    """The ``K - 1`` inputs that precede position ``length`` of the
+    sequence ``tail ++ x``'s part ``x`` (B, T, C): what the next call's
+    ``tail`` is after ``length`` (traced, ``<= T``) real positions of a
+    chunk.  ``length = 0`` hands ``tail`` back."""
+    return lax.dynamic_slice_in_dim(
+        jnp.concatenate([tail.astype(x.dtype), x], axis=1), length,
+        tail.shape[1], axis=1)
+
+
+def conv_step(tail: jax.Array, x: jax.Array, kernel: jax.Array,
+              bias: Optional[jax.Array] = None):
+    """One position of :func:`causal_depthwise_conv` a row: ``tail``
+    (B, K - 1, C) the row's last inputs, ``x`` (B, C) the new one.  Returns
+    ``(out (B, C), new tail)``."""
+    window = jnp.concatenate([tail.astype(x.dtype), x[:, None]], axis=1)
+    out = jnp.sum(window * kernel, axis=1)
+    return (out if bias is None else out + bias), window[:, 1:]
+
+
+def ssd_step(state: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+             B: jax.Array, C: jax.Array, D: Optional[jax.Array] = None):
+    """One position of the recurrence a row, in float32: ``state``
+    (batch, H, P, N), ``x`` (batch, H, P), ``dt`` (batch, H), ``A`` (H,),
+    ``B`` and ``C`` (batch, G, N).  Returns ``(y (batch, H, P) float32,
+    new state)``.  A row whose ``dt`` is 0 keeps its state to the bit."""
+    Bsz, H, P, N = state.shape
+    G = B.shape[1]
+    f32 = jnp.float32
+    s = state.astype(f32).reshape(Bsz, G, H // G, P, N)
+    dtf = dt.astype(f32).reshape(Bsz, G, H // G)
+    xf = x.astype(f32).reshape(Bsz, G, H // G, P)
+    decay = jnp.exp(dtf * A.astype(f32).reshape(G, H // G))
+    s = (s * decay[..., None, None]
+         + (xf * dtf[..., None])[..., None]
+         * B.astype(f32)[:, :, None, None, :])
+    y = jnp.sum(s * C.astype(f32)[:, :, None, None, :], axis=-1)
+    if D is not None:
+        y = y + xf * D.astype(f32).reshape(G, H // G)[..., None]
+    return y.reshape(Bsz, H, P), s.reshape(Bsz, H, P, N)
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,

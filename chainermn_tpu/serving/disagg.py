@@ -698,6 +698,17 @@ def drain_all(sched: Scheduler, transport: MigrationTransport,
 
 
 # ---------------------------------------------------------------- roles
+def _refuse_state(sched: Scheduler, role: str) -> None:
+    """The disaggregated roles hand finished prefills over as KV blocks; a
+    model with state by slot would arrive without its recurrent state."""
+    if getattr(sched.engine, "stateful", False):
+        raise NotImplementedError(
+            f"{role} with a model that keeps state by slot "
+            f"({type(sched.engine.model).__name__}): a migration frame "
+            "ships KV blocks, and the slots' recurrent state is in none"
+        )
+
+
 class PrefillRole:
     """Drives a :class:`~chainermn_tpu.serving.Scheduler` in
     prefill-only mode: admission + the chunked-prefill ladder, then
@@ -715,6 +726,7 @@ class PrefillRole:
                  decode_ranks: Sequence[int], guard=None):
         if not decode_ranks:
             raise ValueError("prefill role needs >= 1 decode rank")
+        _refuse_state(sched, "PrefillRole")
         self.sched = sched
         self.transport = transport
         self.decode_ranks = list(decode_ranks)
@@ -804,6 +816,7 @@ class DecodeRole:
     def __init__(self, sched: Scheduler, transport: MigrationTransport,
                  prefill_ranks: Sequence[int], guard=None,
                  peer_ranks: Sequence[int] = ()):
+        _refuse_state(sched, "DecodeRole")
         self.sched = sched
         self.transport = transport
         self.prefill_ranks = list(prefill_ranks)

@@ -94,7 +94,17 @@ class DecodeEngine:
       model: a :class:`~chainermn_tpu.models.TransformerLM`.  Works with
         either ``decode_attention`` setting — "fused" runs the paged Pallas
         kernel in the hot loop, "einsum" the gathered read (the
-        reference path).
+        reference path).  Or a model with **state by slot** — one that
+        has ``state_shapes()``, :class:`~chainermn_tpu.models.HybridLM`
+        of ``F`` layers: each layer's entry of ``pools`` then holds the
+        slots' recurrent state beside its ``"kv"`` blocks, the three
+        programs are told which slot a chunk belongs to and how many of
+        its rows hold text (``state_slot=``, ``chunk_len=``), and a chunk
+        that starts at position 0 starts from zeros inside the program.
+        A block holds no state, so everything that moves or shares blocks
+        is refused for such a model, at construction or at the call:
+        ``prefix_cache=True``, ``draft_model`` / ``spec_k``, ``mesh=``,
+        :meth:`cow_copy`, :meth:`read_block` / :meth:`write_block`.
       params: the model's parameter pytree.
       capacity: decode slots per step (the fixed batch dimension).
       num_blocks: physical blocks in the pool (block 0 stays reserved).
@@ -151,6 +161,8 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        from chainermn_tpu.models.transformer import lm_head_logits
+
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         if top_k < 0:
@@ -181,6 +193,24 @@ class DecodeEngine:
                 "mesh and device are mutually exclusive — a sharded "
                 "engine's placement IS its mesh"
             )
+        #: the model keeps state by slot beside the paged pool
+        self.stateful = getattr(model, "state_shapes", None) is not None
+        if self.stateful:
+            for given, what, why in (
+                (prefix_cache, "prefix_cache=True",
+                 "a cached block has no state to go with it: the state "
+                 "after a shared prefix would have to be kept a trie node"),
+                (draft_model is not None, "speculative decoding",
+                 "a rejected draft cannot be rolled back by not advancing "
+                 "the position: the state has already taken it"),
+                (mesh is not None, "mesh=",
+                 "the slots' state has no sharding rule yet"),
+            ):
+                if given:
+                    raise NotImplementedError(
+                        f"{what} with a model that keeps state by slot "
+                        f"({type(model).__name__}): {why}"
+                    )
         self.mesh = mesh
         self.device = device
         placement = None
@@ -220,10 +250,15 @@ class DecodeEngine:
         self.draft_params = draft_params
         self.capacity = capacity
         self.pool = PagedKVPool(model, num_blocks, block_len,
-                                placement=placement)
+                                placement=placement, slots=capacity)
         self.block_len = block_len
         self.spec_k = spec_k
         self.draft_model = draft_model
+        if max_blocks_per_slot is None and not hasattr(model, "max_len"):
+            raise ValueError(
+                f"{type(model).__name__} states no max_len: say how wide a "
+                "slot's block table is (max_blocks_per_slot)"
+            )
         self.max_blocks = (
             max_blocks_per_slot
             if max_blocks_per_slot is not None
@@ -319,6 +354,16 @@ class DecodeEngine:
         # and as much again in every persistent-cache entry (chip
         # compiler, PR 21) — and a sharded engine's programs would hold
         # the weights unsharded.
+        def of_chunk(last_idx, rows, *slot):
+            """What a model with state is told of a chunk besides: whose
+            it is, and how many of its rows hold text (a final chunk ends
+            at ``last_idx``, any other is whole).  Nothing, for a model
+            without (which is handed no ``slot``)."""
+            if not self.stateful:
+                return {}
+            return {"state_slot": slot[0],
+                    "chunk_len": jnp.where(last_idx >= 0, last_idx + 1, rows)}
+
         def step_impl(params, pools, tokens, pos, tables, active, rng,
                       temp):
             logits, new_pools = model.apply(
@@ -353,17 +398,13 @@ class DecodeEngine:
                 {"params": params}, tokens[:, None], cache=pools,
                 decode_pos=pos, block_tables=row_tables, slot_mask=active,
                 chunk_rows=C, return_hidden=True,
+                **of_chunk(last_idx, C, slot),
             )
             take = jnp.concatenate(
                 [jnp.arange(S), S + jnp.maximum(last_idx, 0)[None]]
             )
             with jax.named_scope("head"):
-                head = params["lm_head"]
-                logits = (
-                    h[take, 0].astype(jnp.float32)
-                    @ head["kernel"].astype(jnp.float32)
-                    + head["bias"].astype(jnp.float32)
-                )
+                logits = lm_head_logits(model, params, h[take, 0])
             with jax.named_scope("sample"):
                 nxt = jax.vmap(pick)(
                     logits,
@@ -382,10 +423,12 @@ class DecodeEngine:
         # speculative engine's prefill ALSO runs the draft model over the
         # chunk (headless) so the draft cache tracks the target's.
         def prefill_impl(params, draft_params, pools, dpools, tokens, p0,
-                         table, last_idx, rng, temp):
+                         table, last_idx, rng, temp, *slot):
+            # (``slot``: a stateful engine's one more argument)
             h, new_pools = model.apply(
                 {"params": params}, tokens, cache=pools, decode_pos=p0,
                 block_tables=table, return_hidden=True,
+                **of_chunk(last_idx, tokens.shape[1], *slot),
             )
             if draft_model is not None:
                 _, dpools = draft_model.apply(
@@ -399,12 +442,7 @@ class DecodeEngine:
             # fp32 head application as models.lm_loss_chunked.
             with jax.named_scope("head"):
                 hx = jax.lax.dynamic_slice_in_dim(h, li, 1, axis=1)
-                head = params["lm_head"]
-                logits = (
-                    hx[0].astype(jnp.float32)
-                    @ head["kernel"].astype(jnp.float32)
-                    + head["bias"].astype(jnp.float32)
-                )
+                logits = lm_head_logits(model, params, hx[0])
             with jax.named_scope("sample"):
                 nxt = pick(logits[0], rng, p0 + li, temp)
             return new_pools, dpools, nxt
@@ -640,6 +678,7 @@ class DecodeEngine:
                 np.int32(last_idx),
                 self.rng[slot],
                 np.float32(self.temp[slot]),
+                *((np.int32(slot),) if self.stateful else ()),
             )
         if last_idx < 0:
             return None
@@ -771,10 +810,24 @@ class DecodeEngine:
             return np.asarray(toks), np.asarray(n_accept)
 
     # ----------------------------------------------------- prefix sharing
+    def _refuse_with_state(self, what: str) -> None:
+        """A block of a model with state by slot is half of what a position
+        needs: the other half is the slot's recurrent state *at that
+        position*, which nothing keeps."""
+        if self.stateful:
+            raise NotImplementedError(
+                f"{what} with a model that keeps state by slot "
+                f"({type(self.model).__name__}): a block's keys and values "
+                "are no use without the recurrent state at its last "
+                "position, and no pool keeps that — such a request moves "
+                "as a recompute entry (its text), not as blocks"
+            )
+
     def cow_copy(self, src: int, dst: int) -> None:
         """Copy physical block ``src`` onto ``dst`` across every layer of
         every pool (target + draft) — the device half of copy-on-write.
         Pure block-table/refcount surgery stays with the caller."""
+        self._refuse_with_state("cow_copy (sharing a block)")
         self.pools, self.draft_pools = self._cow(
             self.pools, self.draft_pools, np.int32(src), np.int32(dst)
         )
@@ -795,6 +848,7 @@ class DecodeEngine:
         plane.  Pure read: the pools stay live for the next step."""
         import jax
 
+        self._refuse_with_state("read_block (KV-block migration)")
         t, d = self._gather(
             self.pools, self.draft_pools, np.int32(block)
         )
@@ -807,6 +861,7 @@ class DecodeEngine:
         as the source's :meth:`read_block` bytes (same dtypes, same
         layout).  A plain engine refuses draft data and vice versa —
         migration requires role-homogeneous engine geometry."""
+        self._refuse_with_state("write_block (KV-block migration)")
         if (data.get("draft") is not None) != (self.draft_model is not None):
             raise ValueError(
                 "migration payload draft pools do not match this engine "
@@ -903,6 +958,10 @@ class DecodeEngine:
             "decode_compiles": self.decode_compiles,
             "prefill_compiles": self.prefill_compiles,
         }
+        if self.stateful:
+            # beside the blocks' budget: what the slots' state holds,
+            # whatever the contexts are
+            out["state_bytes"] = self.pool.state_bytes
         if self.prefix is not None:
             out["prefix_cached_blocks"] = self.prefix.cached_blocks
         if self.spec_k:

@@ -176,7 +176,18 @@ class PagedKVPool:
 
     Built from the model's own geometry so the pool entries are exactly
     what :meth:`TransformerLM.__call__`'s paged decode branch hands
-    ``pool_write``.
+    ``pool_write``: ``n_kv_heads`` rows of the model's ``head_dim`` where
+    it states one, of ``d_model // n_heads`` where it does not.
+
+    A model that keeps **state by slot** beside its keys and values — a
+    recurrence's, which no block holds: it is one array a slot however long
+    the context — says so through ``state_shapes()`` (``{name: (shape,
+    dtype)}`` a layer, :meth:`~chainermn_tpu.models.HybridLM.state_shapes`),
+    and each layer's entry then carries ``{name: (slots, *shape)}`` beside
+    its ``"kv"``: one tree, donated and returned by the engine's programs
+    together.  ``state_bytes`` is what that costs, apart from the blocks'
+    budget; a model without ``state_shapes`` gets the entries it always
+    had.
     ``kv_dtype=jnp.int8`` models get int8 pools with fp32 scale planes —
     the same symmetric-absmax convention as the contiguous cache, at half
     the bf16 pool bytes.
@@ -192,7 +203,7 @@ class PagedKVPool:
     """
 
     def __init__(self, model, num_blocks: int, block_len: int,
-                 placement=None):
+                 placement=None, slots: int = 0):
         import jax.numpy as jnp
 
         from chainermn_tpu.ops.decode_attention import pool_shapes
@@ -200,8 +211,9 @@ class PagedKVPool:
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1, got {block_len}")
         kvh = model.n_kv_heads or model.n_heads
-        dh = model.d_model // model.n_heads
-        kvd = model.kv_dtype if model.kv_dtype is not None else model.dtype
+        dh = getattr(model, "head_dim", None) or model.d_model // model.n_heads
+        kvd = getattr(model, "kv_dtype", None)
+        kvd = kvd if kvd is not None else model.dtype
         shape, scale_shape = pool_shapes(num_blocks, block_len, kvh, dh)
         self.block_len = block_len
         self.num_blocks = num_blocks
@@ -223,6 +235,22 @@ class PagedKVPool:
                 {"kv": jnp.zeros(shape, kvd)} for _ in range(model.n_layers)
             ]
             per_layer = math.prod(shape[1:]) * jnp.dtype(kvd).itemsize
+        #: HBM bytes of the slots' state across all layers (0: the model
+        #: keeps none).
+        self.state_bytes = 0
+        state_shapes = getattr(model, "state_shapes", None)
+        if state_shapes is not None:
+            if slots < 1:
+                raise ValueError(
+                    "a model with state by slot needs the pool told how "
+                    f"many slots there are, got slots={slots}"
+                )
+            for layer, shapes in zip(self.pools, state_shapes()):
+                for name, (shp, dt) in shapes.items():
+                    layer[name] = jnp.zeros((slots,) + tuple(shp), dt)
+                    self.state_bytes += (
+                        slots * math.prod(shp) * jnp.dtype(dt).itemsize
+                    )
         if placement is not None:
             self.pools = [
                 {n: placement(arr) for n, arr in layer.items()}

@@ -132,8 +132,9 @@ from chainermn_tpu.serving.kv_pool import PoolExhausted, blocks_for
 #: The counts of the tick's phases that the unit ledger sums a tick: plain
 #: integers that are at hand where the span opens or closes.
 _LEDGER_COUNTS = {
-    "cmn_serve_prefill": ("tokens", "padded", "final", "ctx_blocks", "rode"),
-    "cmn_serve_decode": ("live", "chunk_rows"),
+    "cmn_serve_prefill": ("tokens", "padded", "final", "ctx_blocks", "rode",
+                          "state_reset"),
+    "cmn_serve_decode": ("live", "chunk_rows", "state_rows"),
     "cmn_serve_emit": ("tokens", "retired"),
     "cmn_serve_admit": ("admitted",),
 }
@@ -651,7 +652,7 @@ class Scheduler:
                 f"{blocks_for(probe_end, eng.block_len)} blocks, pool has "
                 f"{eng.pool.num_blocks - 1} allocatable"
             )
-        if eng.model.pos_enc == "learned" and \
+        if getattr(eng.model, "pos_enc", None) == "learned" and \
                 max(probe_end, worst_end) > eng.model.max_len:
             raise ValueError(
                 f"request {req.id}: worst padded prefill end {worst_end} "
@@ -1115,7 +1116,7 @@ class Scheduler:
         submit() validated the unmatched geometry."""
         eng = self.engine
         cap = eng.max_blocks * eng.block_len
-        if eng.model.pos_enc == "learned":
+        if getattr(eng.model, "pos_enc", None) == "learned":
             cap = min(cap, eng.model.max_len)
         while matched > 0 and self._padded_end(matched, text_len) > cap:
             matched -= 1
@@ -1342,6 +1343,10 @@ class Scheduler:
                        ctx_blocks=context_blocks(
                            (end if ride else p0 + size) - 1,
                            eng.block_len, eng.max_blocks),
+                       # a model with state by slot: this chunk starts
+                       # its slot's state from zeros
+                       **({"state_reset": int(p0 == 0)}
+                          if getattr(eng, "stateful", False) else {}),
                        ) as span:
             chunk = np.zeros((size,), np.int32)
             chunk[: end - p0] = slot.text[p0:end]
@@ -1477,6 +1482,10 @@ class Scheduler:
                 ),
                 kv_blocks_grid=S * self.engine.max_blocks,
                 table_width=self.engine.max_blocks,
+                # a model with state by slot: the slots whose state this
+                # step updates, the riding chunk's among them
+                **({"state_rows": len(live) + (staged is not None)}
+                   if getattr(self.engine, "stateful", False) else {}),
             )
             mixed = self._unsynced_prefill or staged is not None
             self._iterations += 1

@@ -193,15 +193,16 @@ _XL_NB = 2049
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("heads, kv_heads, dh, t", [
-    (25, 25, 64, 1), (24, 2, 128, 1), (25, 25, 64, 4),
-], ids=["dh64", "dh128", "dh64_verify_t4"])
+    (25, 25, 64, 1), (24, 2, 128, 1), (25, 25, 64, 4), (20, 4, 128, 1),
+], ids=["dh64", "dh128", "dh64_verify_t4", "dh128_group5"])
 def test_mosaic_accepts_the_kv_panel(heads, kv_heads, dh, t, kv_dtype, chip):
     """A pool block's whole ``(block_len, KH * 2 * Dh)`` row — every KV
     head's ``[k | v]`` — is what the kernel DMAs (by hand, from the pool
     left in HBM, into its double buffer) and slices at the heads' lane
     groups: Mosaic takes it at both head widths the configurations have,
     as lane-dense arithmetic over the row (25 heads of 64, decode and a
-    verify chunk) and as a loop of matmuls over KV heads (24 / 2 of 128),
+    verify chunk) and as a loop of matmuls over KV heads (24 / 2 of 128;
+    Falcon-H1's 20 / 4 of 128, a group of 5 query rows a head, unpadded),
     int8 (the slot's ``(max_blocks, KH, 2, block_len)`` scale panels a
     per-slot block) included — one ``tpu_custom_call`` named
     ``paged_decode``."""
@@ -274,6 +275,80 @@ def test_pool_write_and_kernel_share_one_layout(tokens, per_slot, launches,
     at_rest = [a for a in entry.split(", ") if shape in a]
     assert len(at_rest) == 1 and at_rest[0].startswith(
         "bf16" + shape + "{2,1,0"), at_rest
+
+
+# ------------------------------------------- a layer with state by slot
+def _falcon_layer_step(chunk_rows):
+    """One Falcon-H1-wide ``F`` layer over the backlog cell's pools, as the
+    engine's decode step (and, with ``chunk_rows``, its mixed step) calls
+    it: 64 slots, 4,097 blocks of 16, a table 64 wide, the pools — keys
+    and values by block, recurrent state and convolution tail by slot —
+    donated."""
+    from chainermn_tpu.models import HybridLM
+    from chainermn_tpu.serving.kv_pool import PagedKVPool
+
+    slots = 64
+    model = HybridLM(
+        vocab=256, n_layers=1, d_model=5120, layer_kinds="F", n_heads=20,
+        n_kv_heads=4, head_dim=128, ssm_heads=32, ssm_head_dim=128,
+        ssm_groups=2, ssm_state=256, ssm_chunk=128, conv_kernel=4,
+        d_ff=21504, rope_theta=1e11, embedding_multiplier=5.65,
+        ssm_in_multiplier=0.25, ssm_multipliers=(0.35, 0.25, 0.18, 0.5, 0.35),
+        ssm_out_multiplier=0.088, key_multiplier=0.011,
+        attention_out_multiplier=0.0375, mlp_multipliers=(0.18, 0.011),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        decode_attention="fused")
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"])
+    pools = jax.eval_shape(
+        lambda: PagedKVPool(model, 4097, BL, slots=slots).pools)
+    rows = slots + chunk_rows
+
+    def step(params, pools, tokens, pos, tables, active, slot, n):
+        kw = dict(state_slot=slot, chunk_len=n) if chunk_rows else {}
+        return model.apply(
+            {"params": params}, tokens, cache=pools, decode_pos=pos,
+            block_tables=tables, slot_mask=active, return_hidden=True,
+            chunk_rows=chunk_rows, **kw)
+
+    args = (params, pools, jax.ShapeDtypeStruct((rows, 1), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows, MB), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    return jax.jit(step, donate_argnums=(1,)), args, pools[0]
+
+
+@pytest.mark.parametrize("chunk_rows", [0, 64], ids=["decode", "mixed_c64"])
+def test_the_slots_state_is_stepped_in_place(chunk_rows, chip):
+    """The guard of the state pool's layout, as the one above is the block
+    pool's: at Falcon-H1's widths the decode rows' recurrence is ONE fusion
+    that reads the 268 MB ``f32[64,32,128,256]`` state of a layer once and
+    writes it once (``y`` comes out of the same pass), the donated state
+    and pool are updated in place — no ``copy`` of either's shape, no
+    temporary as large as the state — and the decode rows' attention is the
+    paged kernel at a group of 5 (one ``tpu_custom_call``), with a chunk
+    aboard too."""
+    fn, args, pools = _falcon_layer_step(chunk_rows)
+    compiled = fn.lower(*_on(chip, args)).compile()
+    text = compiled.as_text()
+    _assert_mosaic_took(text, 1, ["paged_decode"], chunk_rows)
+    state, pool = pools["ssm"], pools["kv"]
+    for arr in (state, pool):
+        shape = "[%s]" % ",".join(str(d) for d in arr.shape)
+        copies = [c for c in re.findall(r"= (\S+) copy\(", text)
+                  if shape in c]
+        assert not copies, copies
+    state_bytes = state.size * state.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < state_bytes, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= state_bytes + pool.size * 2
+    # the step: one fusion holds every state-shaped operation under it
+    shape = r"f32\[64,32,128,256\]"
+    fusions = re.findall(r"(%\S+) = \([^\n]*" + shape
+                         + r"[^\n]*\) fusion\([^\n]*ssm\.step", text)
+    assert len(fusions) == 1, fusions
 
 
 # ------------------------------------------------- the hybrid cell's layers
