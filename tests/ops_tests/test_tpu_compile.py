@@ -31,6 +31,7 @@ from jax.experimental.compilation_cache import (  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 import chainermn_tpu.ops.grouped_matmul  # noqa: E402,F401  (steered below)
+import chainermn_tpu.ops.ssd_scan  # noqa: E402,F401  (steered below)
 
 from chainermn_tpu.ops import (  # noqa: E402
     flash_attention,
@@ -59,7 +60,8 @@ def chip():
     # sys.modules for the modules.
     mods = [sys.modules["chainermn_tpu.ops.flash_attention"],
             sys.modules["chainermn_tpu.ops.decode_attention"],
-            sys.modules["chainermn_tpu.ops.grouped_matmul"]]
+            sys.modules["chainermn_tpu.ops.grouped_matmul"],
+            sys.modules["chainermn_tpu.ops.ssd_scan"]]
     saved = [m._use_interpret for m in mods]
     for m in mods:
         m._use_interpret = lambda: False
@@ -364,8 +366,8 @@ def _hybrid_attention():
 
 
 def _hybrid_scan():
-    """An ``M`` layer's chunked scan, forward and autodiff's backward: 64
-    heads of 64, 8 groups of 128 state columns, chunks of 128, T = 8,192."""
+    """An ``M`` layer's chunked scan, forward and backward: 64 heads of 64,
+    8 groups of 128 state columns, chunks of 128, T = 8,192."""
     from chainermn_tpu.ops.ssd_scan import ssd_scan
 
     T, Hm, P, G, N = 8192, 64, 64, 8, 128
@@ -412,14 +414,83 @@ def test_hybrid_attention_backward_walks_two_kv_chunks(chip):
 
 
 def test_hybrid_scan_compiles_with_its_backward(chip):
-    """No kernel of ours: XLA's own dots and fusions, and a working set (the
-    float32 ``(64, 64, 128, 128)`` decay tiles and what autodiff keeps of
-    them) that stays under 3 GB — what a rematerialised block may take of
-    the 16 GB beside 10 GB of parameters and gradients."""
+    """The training call at the cell's shape (T 8,192, 64 heads of 64, 8
+    groups, state 128, chunks of 128) is the scan's own kernels, and Mosaic
+    takes each launch: ONE ``ssd_fwd`` (the forward rule's) and ONE
+    ``ssd_bwd``, which works the chunk states out again into 16 MB of VMEM
+    under a raised limit.  No ``(heads, chunks, 128, 128)`` decay tiles and
+    no chunk states are left in HBM: the temporaries are ``dy``, ``y`` and
+    the relaid 2 MB running sums, a third of a GB where the ``jax.numpy``
+    body took one."""
     fn, shapes = _hybrid_scan()
     compiled = jax.jit(fn).lower(*_on(chip, shapes)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    text = compiled.as_text()
+    calls = re.findall(r"%(\S+) = [^\n]*tpu_custom_call", text)
+    assert sorted(c.split(".")[0] for c in calls) == ["ssd_bwd", "ssd_fwd"], \
+        calls
+    assert not re.search(r"f32\[[0-9,]*128,128,128\]", text)  # decay tiles
+    assert not re.search(r"f32\[1,64,8,\d+,\d+,128\]", text)  # chunk states
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
+
+
+def _serving_chunk(fn):
+    """Falcon-H1's prefill chunk as ``HybridLM._recur`` calls the scan: one
+    sequence of 64 positions, 32 heads of 128, 2 groups, state 256, ONE
+    chunk, from a slot's state to a slot's state."""
+    shapes = [((1, 64, 32, 128), jnp.bfloat16), ((1, 64, 32), jnp.float32),
+              ((32,), jnp.float32), ((1, 64, 2, 256), jnp.bfloat16),
+              ((1, 64, 2, 256), jnp.bfloat16), ((32,), jnp.float32),
+              ((1, 32, 128, 256), jnp.float32)]
+
+    def chunk(x, dt, A, B, C, D, s0):
+        return fn(x, dt, A, B, C, chunk=64, D=D, initial_state=s0,
+                  return_state=True)
+
+    return chunk, shapes
+
+
+def test_serving_chunk_lowers_to_the_jnp_body_alone(chip):
+    """The second served model's call takes no kernel on the chip either
+    (``_kernels_take``: a state comes in and goes out, one chunk): what
+    ``ssd_scan`` lowers to for the TPU is the ``jax.numpy`` body's own text,
+    character for character — the body the parent ran, unedited."""
+    mod = sys.modules["chainermn_tpu.ops.ssd_scan"]
+    texts = []
+    for fn in (mod.ssd_scan, mod._ssd_scan_xla):
+        chunk, shapes = _serving_chunk(fn)
+        texts.append(jax.jit(chunk).lower(*_on(chip, shapes)).as_text())
+    assert texts[0] == texts[1]
+    assert "tpu_custom_call" not in texts[0]
+
+
+def test_two_mamba_layers_share_one_lowering_of_the_kernels(chip):
+    """A two-``M``-layer training step (remat on, shapes that tile: 2 heads
+    of 64, state 128, two chunks of 128).  Lowered, the module holds the
+    kernels THREE times whatever the depth — the forward, the forward rule
+    under differentiation and the backward, inside functions the layers
+    call (``_ssd_scan_kernels`` is one ``jax.jit``) — and compiled, XLA
+    launches 2 x (forward + its recompute) ``ssd_fwd`` and 2 ``ssd_bwd``:
+    a change that adds a call site a layer shows here, without the chip."""
+    from chainermn_tpu.models import HybridLM
+
+    model = HybridLM(
+        vocab=256, n_layers=2, d_model=128, layer_kinds="MM", n_heads=2,
+        n_kv_heads=2, head_dim=64, ssm_heads=2, ssm_head_dim=64,
+        ssm_groups=1, ssm_state=128, ssm_chunk=128, conv_kernel=4,
+        remat=True, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 256), jnp.int32))["params"])
+
+    def loss(params, ids):
+        return model.apply({"params": params}, ids).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss)).lower(*_on(chip, (params, ids)))
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    calls = re.findall(r"%(\S+) = [^\n]*tpu_custom_call",
+                       lowered.compile().as_text())
+    names = sorted(c.split(".")[0] for c in calls)
+    assert names == ["ssd_bwd"] * 2 + ["ssd_fwd"] * 4, calls
 
 
 def test_hybrid_expert_layer_is_grouped_matmuls_and_gathers(chip):
