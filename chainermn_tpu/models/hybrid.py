@@ -30,13 +30,29 @@ And the Falcon-H1 family's, whose layer runs TWO mixers side by side:
   on the FFN's result, ``lm_head_multiplier``) are fields whose default, 1,
   adds no operation.
 
-RMS norms, no bias anywhere but the convolution's, an untied bias-free head.
+And the Command A+ family's, whose layer runs attention and its experts side
+by side:
+
+* ``W`` / ``G`` — one **parallel block**, ``h <- h + a + ffn`` with both
+  halves of one LayerNorm ``u`` (``norm="layer"``): grouped-query attention
+  — ``W`` over the last ``window`` positions with RoPE in interleaved pairs
+  (``rope_interleaved``), ``G`` over the whole context with no positional
+  encoding — and a mixture of experts in ``E``'s shape with a **gated**
+  activation: sigmoid router over all ``experts_held * ep_of`` experts with
+  no bias, the held range through ``held_experts_ffn`` with gate and up as
+  one ``(held, D, 2 F)`` product, and ``n_shared`` shared experts as one
+  fused SwiGLU of width ``d_shared`` whose result is divided by
+  ``n_shared`` (their average).  ``tie_embeddings`` reads the head from the
+  embedding.
+
+RMS norms (a LayerNorm where ``norm`` says so), no bias anywhere but the
+convolution's, an untied bias-free head unless tied.
 Trains through :func:`~chainermn_tpu.models.lm_loss_chunked` like
 :class:`~chainermn_tpu.models.TransformerLM` (``return_hidden=True``, the
 head read from ``lm_head/kernel``); each block is under ``jax.checkpoint``
 when ``remat``.
 
-**Decode path** (``F`` layers; the calling convention
+**Decode path** (``F``, ``W`` and ``G`` layers; the calling convention
 :class:`~chainermn_tpu.serving.DecodeEngine` uses for ``TransformerLM``):
 ``cache`` holds one entry a layer — the paged ``{"kv"}`` pool attention
 writes and reads through ``block_tables``
@@ -54,7 +70,14 @@ over the first ``chunk_len`` rows — the rows past a short tail are given
 ``dt = 0`` and the convolution's tail is taken at the last real position.
 A chunk that starts at position 0 starts from zeros, inside the program:
 that is what makes a used slot's next request, and an evicted request's
-recompute, right.
+recompute, right.  A ``G`` layer's entry is the paged ``{"kv"}`` alone; a
+``W`` layer keeps no blocks of the pool but a **ring by slot**, ``{"ring":
+(slots, R, block_len, KH * 2 * Dh)}`` (:meth:`HybridLM.ring_shapes`;
+:func:`~chainermn_tpu.ops.decode_attention.ring_attend`): O(window) a slot
+whatever the context, its table worked out inside the program from the
+positions, masked by absolute position and so never zeroed.  Decode rows and
+a riding chunk's rows go through ONE call of the expert layer; rows
+``slot_mask`` leaves out are routed to no expert.
 """
 
 from __future__ import annotations
@@ -66,7 +89,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from chainermn_tpu.ops.decode_attention import paged_attend, pool_write
+from chainermn_tpu.ops.decode_attention import (
+    paged_attend,
+    pool_shapes,
+    pool_write,
+    ring_attend,
+    ring_blocks,
+    ring_write,
+)
 from chainermn_tpu.ops.flash_attention import (
     flash_attention,
     reference_attention,
@@ -86,9 +116,12 @@ from chainermn_tpu.parallel.held_experts import (
     held_range,
     relu2,
     sigmoid_topk_route,
+    swiglu,
 )
 
-LAYER_KINDS = "M*EF"
+LAYER_KINDS = "M*EFWG"
+#: the kinds with a decode path
+CACHED_KINDS = "FWG"
 
 
 def _times(x, m):
@@ -112,6 +145,15 @@ def rms_norm(x, scale, eps: float, groups: int = 1):
         g = x.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
         g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
         return g.reshape(x.shape) * scale.astype(jnp.float32)
+
+
+def layer_norm(x, scale, eps: float):
+    """float32 LayerNorm over the last axis: a scale and no bias."""
+    with jax.named_scope("layer_norm"):
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, -1, keepdims=True)
+        x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
+        return x * scale.astype(jnp.float32)
 
 
 class _Decode(NamedTuple):
@@ -146,13 +188,16 @@ class _HybridBlock(nn.Module):
         c = self.cfg
         scale = self.param("norm", nn.initializers.ones, (c.d_model,),
                            c.param_dtype)
-        u = rms_norm(h, scale, c.norm_eps)
+        u = (layer_norm if c.norm == "layer" else rms_norm)(
+            h, scale, c.norm_eps)
         if self.kind == "F":
             return self._falcon(h, u, rope, cache, dec)
+        if self.kind in "WG":
+            return self._parallel(h, u, rope, cache, dec)
         if cache is not None:
             raise NotImplementedError(
-                f"layer kind {self.kind!r} has no decode path: only 'F' "
-                "layers keep a cache")
+                f"layer kind {self.kind!r} has no decode path: only "
+                f"{CACHED_KINDS!r} layers keep a cache")
         mixer = {"M": self._mamba, "*": self._attention, "E": self._experts}
         return h + mixer[self.kind](u).astype(h.dtype)
 
@@ -304,11 +349,28 @@ class _HybridBlock(nn.Module):
             q = self._dense((c.n_heads, c.head_dim), "q")(u)
             kv = self._dense((2, c.n_kv_heads, c.head_dim), "kv")(u)
             k, v = _times(kv[:, :, 0], c.key_multiplier), kv[:, :, 1]
-            if rope is not None:
+            if rope is not None and c.rope_interleaved:
+                q, k = (apply_rope(x, tables=rope, interleaved=True)
+                        for x in (q, k))
+            elif rope is not None:
                 # before the pool's write: a cached key keeps its rotation
                 q, k = apply_rope(q, tables=rope), apply_rope(k, tables=rope)
         pool = {}
-        if cache is not None:
+        window = c.window if self.kind == "W" else 0
+        if cache is not None and self.kind == "W":
+            # rows are the slots in order; the chunk's rows are one slot's
+            B = u.shape[0]
+            S = B - dec.chunk_rows if T == 1 else 0
+            slots = jnp.concatenate([
+                jnp.arange(S, dtype=jnp.int32),
+                jnp.full((B - S,), 0 if dec.state_slot is None
+                         else dec.state_slot, jnp.int32)])
+            pool = ring_write({"ring": cache["ring"]}, k, v, slots,
+                              dec.q_pos, dec.slot_mask)
+            a = ring_attend(q, pool, slots, dec.q_pos, dec.slot_mask,
+                            window=window, kernel=dec.paged_kernel,
+                            chunk_rows=dec.chunk_rows)
+        elif cache is not None:
             pool = pool_write({"kv": cache["kv"]}, k, v, None,
                               dec.block_tables, dec.q_pos, dec.slot_mask)
             a = paged_attend(q, pool, dec.block_tables, dec.decode_pos,
@@ -317,13 +379,73 @@ class _HybridBlock(nn.Module):
                              chunk_rows=dec.chunk_rows)
         elif resolve_attention(c.attention, T) == "flash":
             with jax.named_scope("attn.flash"):
-                a = flash_attention(q, k, v, causal=True)
+                a = flash_attention(q, k, v, causal=True,
+                                    **({"window": window} if window else {}))
         else:
             with jax.named_scope("attn.xla"):
-                a = reference_attention(q, k, v, causal=True).astype(q.dtype)
+                a = reference_attention(
+                    q, k, v, causal=True,
+                    **({"window": window} if window else {})).astype(q.dtype)
         with jax.named_scope("attn_out"):
             out = self._dense(c.d_model, "proj", axis=(-2, -1))(a)
         return out if self.kind == "*" else (out, pool)
+
+    # ------------------------------------------------------------ W, G
+    def _parallel(self, h, u, rope, cache, dec):
+        """Attention and the gated experts side by side, of one norm."""
+        a, kv = self._attention(u, rope if self.kind == "W" else None,
+                                cache, dec)
+        rows = None
+        if dec is not None and dec.slot_mask is not None:
+            rows = jnp.broadcast_to(dec.slot_mask.astype(bool)[:, None],
+                                    u.shape[:2])
+        f = self._gated_experts(u, rows)
+        h = h + a.astype(h.dtype) + f.astype(h.dtype)
+        return h if cache is None else (h, kv)
+
+    def _gated_experts(self, u, rows=None):
+        """``E``'s layer with gated experts and no router bias; ``rows``
+        (B, T) bool: the rows that hold text — the others are routed to no
+        expert (nothing of them is gathered, multiplied or counted)."""
+        c = self.cfg
+        B, T, D = u.shape
+        N, n_all, F = B * T, c.experts_held * c.ep_of, c.d_expert
+        lo, _ = held_range(c.ep_index, c.ep_of, c.experts_held)
+        flat = u.reshape(N, D)
+        x = flat.astype(c.dtype)
+        init = nn.initializers.normal(0.02)
+        with jax.named_scope("moe.route"):
+            w_gate = self.param("router", init, (D, n_all), c.param_dtype)
+            experts, weights = sigmoid_topk_route(
+                flat, w_gate, jnp.zeros((n_all,), jnp.float32),
+                c.experts_per_tok, scale=c.routed_scale)
+            if rows is not None:
+                experts = jnp.where(rows.reshape(N, 1), experts, -1)
+        w_up = self.param("experts_gate_up", init,
+                          (c.experts_held, D, 2 * F), c.param_dtype)
+        w_down = self.param("experts_down", init,
+                            (c.experts_held, F, D), c.param_dtype)
+        # A tile of twice the rows a held expert draws on average, and the
+        # buffer that holds every pair: no conditional between two buffers
+        # (on the chip a fence no prefetch crosses), and at a decode step's
+        # few rows the every-pair buffer is small.
+        mean = N * c.experts_per_tok // n_all
+        routed, counters = held_experts_ffn(
+            x, experts, weights, w_up, w_down, lo=lo,
+            tile=min(128, max(16, 16 * -(-2 * mean // 16))),
+            row_bound=None, activation=swiglu)
+        held = lo + jnp.arange(c.experts_held)
+        counters["moe_experts_touched"] = jnp.sum(jnp.any(
+            experts.reshape(-1, 1) == held[None], axis=0)).astype(jnp.float32)
+        counters["moe_layers"] = jnp.ones((), jnp.float32)
+        for name, value in counters.items():
+            self.sow("intermediates", name, value)
+        with jax.named_scope("moe.shared"):
+            y = self._dense(D, "shared_down")(
+                swiglu(self._dense(2 * c.d_shared, "shared_gate_up")(x)))
+            y = y.astype(jnp.float32) / c.n_shared
+        with jax.named_scope("moe.combine"):
+            return (routed + y).reshape(B, T, D)
 
     # ------------------------------------------------------------ E
     def _experts(self, u):
@@ -407,6 +529,14 @@ class HybridLM(nn.Module):
     d_expert: int = 256
     d_shared: int = 256
     norm_eps: float = 1e-5
+    # W / G parallel block: the window of a ``W`` layer, RoPE in interleaved
+    # pairs, the shared experts averaged, a LayerNorm, a tied head — each
+    # default adds no operation to a model without such a layer
+    window: int = 0
+    rope_interleaved: bool = False
+    n_shared: int = 1
+    norm: str = "rms"
+    tie_embeddings: bool = False
     # F Falcon-H1 block: the gated FFN's width, RoPE's base, and the
     # family's muP multipliers (1 adds no operation)
     d_ff: int = 1024
@@ -434,6 +564,17 @@ class HybridLM(nn.Module):
                         "moe_rows_max_over_mean": jnp.max,
                         "moe_pairs_dropped": jnp.sum}
 
+    @property
+    def serve_counters(self):
+        """What a served step of a model with ``W`` / ``G`` layers hands
+        back beside its tokens, each summed over the layers (whole numbers:
+        pairs, experts that drew a row, and the layers counted); nothing
+        for any other model."""
+        if not set(self.layer_kinds[:self.n_layers]) & set("WG"):
+            return ()
+        return ("moe_pairs_held", "moe_experts_touched", "moe_pairs_dropped",
+                "moe_layers")
+
     def _kinds(self) -> str:
         kinds = self.layer_kinds[:self.n_layers]
         if len(kinds) != self.n_layers or set(kinds) - set(LAYER_KINDS):
@@ -450,15 +591,32 @@ class HybridLM(nn.Module):
         dtype.  The serving pool is built from it
         (:class:`~chainermn_tpu.serving.kv_pool.PagedKVPool`)."""
         kinds = self._kinds()
-        if set(kinds) != {"F"}:
+        if set(kinds) - set(CACHED_KINDS):
             raise NotImplementedError(
-                f"layer_kinds={kinds!r}: only 'F' layers have a decode path")
+                f"layer_kinds={kinds!r}: only {CACHED_KINDS!r} layers have "
+                "a decode path")
         width = (self.ssm_heads * self.ssm_head_dim
                  + 2 * self.ssm_groups * self.ssm_state)
-        return [{"ssm": ((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+        state = {"ssm": ((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
                          jnp.float32),
                  "conv": ((self.conv_kernel - 1, width), self.dtype)}
-                for _ in kinds]
+        return [state if kind == "F" else {} for kind in kinds]
+
+    def ring_shapes(self, slots: int, block_len: int, prefill_chunk: int):
+        """What each layer keeps INSTEAD of blocks of the paged pool, a
+        layer: ``None`` (it pages its whole context), or — a ``W`` layer —
+        the ``(shape, dtype)`` of its ring by slot, ``(slots, R, block_len,
+        KH * 2 * Dh)`` in the pool row's own format with ``R`` from
+        :func:`~chainermn_tpu.ops.decode_attention.ring_blocks`: the window
+        and one chunk, whatever the contexts are."""
+        kinds = self._kinds()
+        if "W" in kinds and self.window < 1:
+            raise ValueError("a 'W' layer needs window >= 1")
+        R = ring_blocks(self.window, prefill_chunk, block_len) \
+            if "W" in kinds else 0
+        row = pool_shapes(1, block_len, self.n_kv_heads, self.head_dim)[0]
+        return [((slots, R) + row[1:], self.dtype) if kind == "W" else None
+                for kind in kinds]
 
     @nn.compact
     def __call__(self, tokens, segment_ids=None, return_hidden: bool = False,
@@ -497,7 +655,7 @@ class HybridLM(nn.Module):
                          param_dtype=self.param_dtype, name="embed")(tokens)
             h = _times(h, self.embedding_multiplier)
         rope = None
-        if "F" in kinds:  # once, shared by every layer
+        if "F" in kinds or "W" in kinds:  # once, shared by every layer
             rope = rope_tables(jnp.arange(T) if dec is None else dec.q_pos,
                                self.head_dim, float(self.rope_theta))
         fields = _Fields(tuple(
@@ -513,14 +671,21 @@ class HybridLM(nn.Module):
             if cache is not None:
                 h, entry = blk(h, rope, cache[i], dec)
                 new_cache.append(entry)
-            elif kind == "F":
+            elif kind in CACHED_KINDS:
                 h = blk(h, rope)
             else:
                 h = blk(h)
         scale = self.param("norm_f", nn.initializers.ones, (self.d_model,),
                            self.param_dtype)
-        h = rms_norm(h, scale, self.norm_eps).astype(self.dtype)
-        if not return_hidden:
+        h = (layer_norm if self.norm == "layer" else rms_norm)(
+            h, scale, self.norm_eps).astype(self.dtype)
+        if not return_hidden and self.tie_embeddings:
+            with jax.named_scope("head"):
+                table = self.variables["params"]["embed"]["embedding"]
+                h = _times(h.astype(jnp.float32)
+                           @ table.astype(jnp.float32).T,
+                           self.lm_head_multiplier)
+        elif not return_hidden:
             with jax.named_scope("head"):
                 h = _times(nn.Dense(
                     self.vocab, use_bias=False, dtype=jnp.float32,

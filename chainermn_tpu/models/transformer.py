@@ -765,7 +765,14 @@ def lm_head_logits(model, params, h):
     (``return_hidden=True``) and apply the head at a few rows only — the
     serving engine's prefill and mixed step: ``lm_head``'s kernel, its bias
     where the model has one, and the model's ``lm_head_multiplier`` where it
-    scales its logits (:class:`~chainermn_tpu.models.HybridLM`)."""
+    scales its logits (:class:`~chainermn_tpu.models.HybridLM`) — or, for a
+    model whose head is tied (``tie_embeddings``), the embedding read
+    transposed."""
+    if getattr(model, "tie_embeddings", False):
+        table = params["embed"]["embedding"]
+        logits = h.astype(jnp.float32) @ table.astype(jnp.float32).T
+        scale = getattr(model, "lm_head_multiplier", 1)
+        return logits if scale == 1 else logits * scale
     head = params["lm_head"]
     logits = h.astype(jnp.float32) @ head["kernel"].astype(jnp.float32)
     if "bias" in head:

@@ -140,6 +140,9 @@ def kv_pool_sample(engine, live_slots: Sequence[Tuple[int, int]] = ()
         # that keeps one (a fixed cost a slot, whatever the contexts; 0 for
         # a model that keeps none)
         "state_bytes": getattr(engine.pool, "state_bytes", 0),
+        # and the window layers' rings: O(window) a slot, whatever the
+        # contexts (0 for a model whose layers all page their whole context)
+        "ring_bytes": getattr(engine.pool, "ring_bytes", 0),
         "fragmentation": (
             1.0 - live_written / live_capacity if live_capacity else 0.0
         ),
@@ -218,7 +221,7 @@ class MemoryMonitor:
             self._g = {k: noop for k in (
                 "in_use", "peak", "limit",
                 "kv_used", "kv_free", "kv_cached", "kv_occ", "kv_frag",
-                "kv_bytes", "kv_leaked", "kv_state",
+                "kv_bytes", "kv_leaked", "kv_state", "kv_ring",
             )}
         else:
             reg = registry if registry is not None else _metrics.registry()
@@ -234,6 +237,7 @@ class MemoryMonitor:
                 "kv_bytes": reg.gauge("mem.kv.bytes_in_use"),
                 "kv_leaked": reg.gauge("mem.kv.leaked_blocks"),
                 "kv_state": reg.gauge("mem.kv.state_bytes"),
+                "kv_ring": reg.gauge("mem.kv.ring_bytes"),
             }
         global _latest_monitor
         _latest_monitor = weakref.ref(self)
@@ -258,6 +262,7 @@ class MemoryMonitor:
             self._g["kv_frag"].set(kv["fragmentation"])
             self._g["kv_bytes"].set(kv["bytes_in_use"])
             self._g["kv_state"].set(kv.get("state_bytes", 0))
+            self._g["kv_ring"].set(kv.get("ring_bytes", 0))
             self.last_kv = kv
         s = {"t_mono": time.perf_counter(), "device": dev, "kv": kv}
         with self._lock:

@@ -303,8 +303,8 @@ def _paged_row_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, o_ref,
     over_queries(last)
 
 
-def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, *rest,
-                       scale, block_len, quant, n_q, group):
+def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, *rest,
+                       scale, block_len, quant, n_q, group, windowed=False):
     """One slot, a static loop over a block's KV heads: per head the
     ``(n_q * G, Dh) x (Dh, block_len)`` score and ``(n_q * G, block_len) x
     (block_len, Dh)`` value matmuls on the head's ``[k | v]`` lane group of
@@ -316,7 +316,15 @@ def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, *rest,
     ``r // group``): offset ``t`` attends positions ``< valid + t`` —
     per-position causality inside a speculative verify chunk, reducing to
     the classic decode bound at ``n_q == 1``.
+
+    ``windowed``: two more prefetched scalars a slot — the lowest position
+    it may attend (``lo_ref``: a window layer's ``q - window + 1``) and the
+    position the table's first entry starts at (``first_ref``: a ring hands
+    its resident blocks oldest first, not from position 0).
     """
+    if windowed:
+        lo_ref, first_ref, *rest = rest
+    q_ref, kv_hbm, *rest = rest
     if quant:
         sc_ref, *rest = rest
     o_ref, kv_buf, sem, m_scr, l_scr, acc = rest
@@ -332,10 +340,18 @@ def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, *rest,
     bound = len_ref[pl.program_id(0)] + jax.lax.broadcasted_iota(
         jnp.int32, (R, block_len), 0) // group
 
+    if windowed:
+        lowest, first = (ref[pl.program_id(0)] for ref in (lo_ref, first_ref))
+
     def fold(i, b):
-        mask = i * block_len + jax.lax.broadcasted_iota(
+        pos = i * block_len + jax.lax.broadcasted_iota(
             jnp.int32, (R, block_len), 1
-        ) < bound
+        )
+        if windowed:
+            pos = pos + first
+            mask = (pos < bound) & (pos >= lowest)
+        else:
+            mask = pos < bound
         for h in range(KH):
             q = q_ref[0, h].astype(jnp.float32) * scale   # (R, Dh)
             kv = kv_buf[b, :, h * 2 * Dh:(h + 1) * 2 * Dh] \
@@ -377,6 +393,8 @@ def paged_decode_attention(
     block_tables: jax.Array,
     valid_len: jax.Array,
     kv_scale: Optional[jax.Array] = None,
+    lowest: Optional[jax.Array] = None,
+    first_pos: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Single-position attention against a block-pooled (paged) KV cache.
 
@@ -425,6 +443,14 @@ def paged_decode_attention(
         pool is int8: row 0 of a head's pair is the per-position key
         scale, row 1 the value scale (symmetric absmax: a stored value
         times its position's scale is the float it stood for).
+      lowest, first_pos: ``(S,)`` int32, together or not at all — the
+        **window** form (:func:`ring_attend`; ``T == 1``): slot ``s``
+        attends positions ``lowest[s] <= j < valid_len[s]``, and entry
+        ``i`` of its table holds positions ``first_pos[s] + i * block_len
+        ...`` (a multiple of ``block_len``; 0 for an idle slot): the table
+        lists the slot's resident blocks oldest first and the kernel walks
+        those and no others.  The launch is then named
+        ``paged_decode_window``.
 
     Returns ``(S, H, Dh)`` or ``(S, T, H, Dh)`` (matching ``q``) in
     ``q``'s dtype.
@@ -456,16 +482,28 @@ def paged_decode_attention(
     scale = 1.0 / math.sqrt(Dh)
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(valid_len, jnp.int32).reshape(S)
+    windowed = lowest is not None
+    if windowed != (first_pos is not None) or (windowed and T != 1):
+        raise ValueError(
+            "the window form takes lowest and first_pos together, for "
+            "single-position queries"
+        )
     # Blocks some query offset of the slot may attend: the kernel's trip
     # count.  A table entry past it is never read.
-    nblk = jnp.minimum((lens + (T - 1) + (BL - 1)) // BL, MB)
+    if windowed:
+        bounds = [jnp.asarray(x, jnp.int32).reshape(S)
+                  for x in (lowest, first_pos)]
+        nblk = jnp.minimum((lens - bounds[1] + (BL - 1)) // BL, MB)
+    else:
+        bounds = []
+        nblk = jnp.minimum((lens + (T - 1) + (BL - 1)) // BL, MB)
 
     def slot(s, *prefetched):
         return (s, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     q4 = q.reshape(S, T, KH, G, Dh)
-    by_row = G == 1 and not quant
+    by_row = G == 1 and not quant and not windowed
     if by_row:
         # Query offset t's heads along the row's own lanes: scaled, zeros
         # under the value lanes.
@@ -490,7 +528,7 @@ def paged_decode_attention(
         qg = q4.transpose(0, 2, 1, 3, 4).reshape(S, KH, R, Dh)
         kernel = functools.partial(
             _paged_head_kernel, scale=scale, block_len=BL, quant=quant,
-            n_q=T, group=G,
+            n_q=T, group=G, **({"windowed": True} if windowed else {}),
         )
         operands = [qg, kv_pool]
         in_specs = [pl.BlockSpec((1, KH, R, Dh), slot), hbm]
@@ -511,7 +549,7 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(bounds),
             grid=(S,),
             in_specs=in_specs,
             out_specs=out_spec,
@@ -525,8 +563,8 @@ def paged_decode_attention(
             dimension_semantics=("parallel",)
         ),
         interpret=_use_interpret(),
-        name="paged_decode",
-    )(tbl, lens, nblk, *operands)
+        name="paged_decode_window" if windowed else "paged_decode",
+    )(tbl, lens, nblk, *bounds, *operands)
     if by_row:
         out = out.reshape(S, T, KH, 2, Dh)[:, :, :, 1]
     else:
@@ -692,6 +730,65 @@ def _attend_width(W, window, q, pool, scale, block_tables, q_pos):
     return a.reshape(B, T, H, Dh).astype(q.dtype)
 
 
+#: float32 scores :func:`pool_context_attend` may hold for all heads at
+#: once; a chunk whose scores are more goes a KV head at a time
+#: (:func:`_attend_by_head`).  32 rows of 25 heads over 1,024 positions
+#: are 3 MB; 256 rows of 128 heads over 14,336 positions are 1.9 GB.
+_SCORES_AT_ONCE = 64 * 2**20
+
+
+def _attend_by_head(W, window, q, pool, scale, block_tables, q_pos,
+                    first_pos=None):
+    """:func:`_attend_width`'s result with the scores of ONE KV head's
+    group alive at a time (``lax.map`` over the KV heads, in order): the
+    float32 ``(G, T, L)`` scores of a head instead of ``(KH, G, T, L)`` —
+    what a 128-head chunk of 256 rows over a long context has the room
+    for.  A head's ``[k | v]`` lane group is sliced into its halves (whole
+    vregs at a head width that is a multiple of 128) rather than contracted
+    as stored.  Float pools only.
+
+    ``first_pos`` ``(B,)``: the position row ``b``'s first table entry
+    starts at (a ring's table lists its resident blocks oldest first);
+    ``None``: 0, the table's own order."""
+    assert scale is None, "an int8 pool's chunks go all heads at once"
+    B, T, H, Dh = q.shape
+    BL = pool.shape[1]
+    KH = pool.shape[2] // (2 * Dh)
+    G = H // KH
+    L = W * BL
+    g = jnp.moveaxis(
+        pool[block_tables[:, :W]].reshape(B, L, KH, 2 * Dh), 2, 0)
+    qg = jnp.moveaxis(q.reshape(B, T, KH, G, Dh), 2, 0)
+    k_pos = jnp.arange(L)[None]
+    if first_pos is not None:
+        k_pos = k_pos + first_pos[:, None]
+    visible = k_pos[:, None, :] <= q_pos[:, :, None]          # (B, T, L)
+    if window:
+        visible &= k_pos[:, None, :] > q_pos[:, :, None] - window
+
+    def head(operands):
+        qh, gh = operands                     # (B, T, G, Dh), (B, L, 2 Dh)
+        s = jnp.einsum("btgd,bld->bgtl", qh, gh[..., :Dh],
+                       preferred_element_type=jnp.float32) / math.sqrt(Dh)
+        p = jax.nn.softmax(jnp.where(visible[:, None], s, -1e30), axis=-1)
+        return jnp.einsum("bgtl,bld->btgd", p, gh[..., Dh:],
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    a = jax.lax.map(head, (qg, g))                      # (KH, B, T, G, Dh)
+    return jnp.moveaxis(a, 0, 2).reshape(B, T, H, Dh)
+
+
+def _attend_at(W, window, q, cache):
+    """The body :func:`pool_context_attend` runs at width ``W``: all heads
+    at once where their scores fit (:data:`_SCORES_AT_ONCE`), a KV head at
+    a time where they do not."""
+    B, T, H, _ = q.shape
+    whole = 4 * B * H * T * W * cache["kv"].shape[1] <= _SCORES_AT_ONCE
+    return functools.partial(
+        _attend_width if whole or "kv_scale" in cache else _attend_by_head,
+        W, window)
+
+
 # a model's layers call it alike: its branches traced once, not once a layer
 @functools.partial(jax.jit, static_argnames="window")
 def pool_context_attend(q, cache, block_tables, q_pos, window=0):
@@ -713,7 +810,7 @@ def pool_context_attend(q, cache, block_tables, q_pos, window=0):
         rung = _context_rung(jnp.max(q_pos), cache["kv"].shape[1], widths)
         return jax.lax.switch(
             rung,
-            [functools.partial(_attend_width, w, window) for w in widths],
+            [_attend_at(w, window, q, cache) for w in widths],
             q, cache["kv"], cache.get("kv_scale"), block_tables, q_pos,
         )
 
@@ -796,3 +893,104 @@ def paged_attend(q, cache, block_tables, decode_pos, q_pos, slot_mask=None,
         else:
             a = paged_decode_attention(*args)
         return a[:, None] if T == 1 else a
+
+
+# ---------------------------------------------------------------------------
+# A window layer's ring by slot
+# ---------------------------------------------------------------------------
+#
+# A layer that attends the last ``window`` positions only need not page its
+# whole context: it keeps, for every slot, a RING of ``R`` blocks in the pool
+# row's own format — ``{"ring": (slots, R, block_len, KH * 2 * Dh)}`` —
+# where logical block ``b`` of the slot's sequence lives at ``b mod R``.  No
+# allocator, no table on the host, nothing to free: the table is worked out
+# inside the program from the positions, and masking is by absolute position
+# (``q - window < j <= q``), so whatever an older turn of the ring — or the
+# slot's previous request — left behind is never attended and a sequence
+# that starts again at position 0 needs no zeroing.
+
+
+def ring_blocks(window: int, chunk: int, block_len: int) -> int:
+    """Blocks a slot's ring holds, ``R``: the window and one chunk of
+    ``chunk`` positions written ahead of its own oldest query, so that a
+    chunk's newest write never lands on a key its oldest query still sees —
+    ``ceil((window + chunk) / block_len)``, and one more where chunks need
+    not start on a block (``chunk`` no multiple of ``block_len``)."""
+    if window < 1:
+        raise ValueError(f"a ring holds a window of >= 1 positions: {window}")
+    return -(-(window + chunk) // block_len) + bool(chunk % block_len)
+
+
+def _ring_table(slots, first_block, R: int):
+    """``(B, R)`` physical blocks of the flattened ring ``(slots * R, ...)``:
+    row ``b``'s logical blocks ``first_block[b] ...``, oldest first."""
+    return slots[:, None] * R + (first_block[:, None] + jnp.arange(R)) % R
+
+
+def ring_write(cache, k, v, slots, q_pos, slot_mask=None):
+    """:func:`pool_write` for a ring: row ``b``'s ``(T, KH, Dh)`` keys and
+    values go to slot ``slots[b]``'s ring at ``(q_pos // block_len) mod R``.
+    Rows ``slot_mask`` leaves out write nothing (their index lies outside
+    the array, and a scatter drops such an update)."""
+    ring = cache["ring"]
+    n_slots, R, BL, L = ring.shape
+    B, T = q_pos.shape
+    with jax.named_scope("kv_write"):
+        row = jnp.concatenate([k, v], axis=-1).reshape(B, T, L) \
+            .astype(ring.dtype)
+        pb = slots[:, None] * R + (q_pos // BL) % R
+        if slot_mask is not None:
+            pb = jnp.where(slot_mask.astype(bool)[:, None], pb, n_slots * R)
+        new = ring.reshape(n_slots * R, BL, L).at[pb, q_pos % BL].set(
+            row, mode="drop")
+    return {"ring": new.reshape(ring.shape)}
+
+
+def ring_attend(q, cache, slots, q_pos, slot_mask=None, *, window: int,
+                kernel: bool, chunk_rows: int = 0):
+    """A query chunk's attention over its slots' rings (:func:`ring_write`'s
+    result), query ``q_pos`` seeing ``q_pos - window < j <= q_pos`` — scope
+    ``attn.window``.  ``q`` ``(B, T, H, Dh)``, ``slots`` ``(B,)`` whose
+    sequence each row is.
+
+    Single-token rows (``T == 1``) run the Pallas kernel over the slot's
+    resident blocks and no others, oldest first, where ``kernel`` allows and
+    the head width tiles (:func:`paged_kernel_takes`;
+    ``paged_decode_window``), and the gathered read of the ``R`` blocks
+    otherwise.  A prefill chunk (``T > 1``, one row) and the last
+    ``chunk_rows`` single-token rows (one slot's chunk riding a decode
+    step) are one sequence: the gathered read of that slot's ``R`` blocks, a
+    KV head at a time — one width wherever the chunk sits, so no
+    conditional."""
+    ring = cache["ring"]
+    n_slots, R, BL, L = ring.shape
+    pool = ring.reshape(n_slots * R, BL, L)
+    B, T, _, Dh = q.shape
+
+    def gathered(q, slots, q_pos):
+        first = jnp.maximum(jnp.min(q_pos, axis=1) - window + 1, 0) // BL
+        return _attend_by_head(R, window, q, pool, None,
+                               _ring_table(slots, first, R), q_pos,
+                               first_pos=first * BL)
+
+    with jax.named_scope("attn.window"):
+        if T > 1:
+            return gathered(q, slots, q_pos)
+        S = B - chunk_rows
+        if kernel and paged_kernel_takes(Dh):
+            pos = q_pos[:S, 0]
+            live = (jnp.ones((S,), bool) if slot_mask is None
+                    else slot_mask[:S].astype(bool))
+            lowest = jnp.where(live, jnp.maximum(pos - window + 1, 0), 0)
+            a = paged_decode_attention(
+                q[:S, 0], pool, _ring_table(slots[:S], lowest // BL, R),
+                jnp.where(live, pos + 1, 0), None,
+                lowest=lowest, first_pos=lowest // BL * BL,
+            )[:, None]
+        else:
+            a = gathered(q[:S], slots[:S], q_pos[:S])
+        if chunk_rows:
+            c = gathered(jnp.swapaxes(q[S:], 0, 1), slots[S:S + 1],
+                         q_pos[S:].T)
+            a = jnp.concatenate([a, jnp.swapaxes(c, 0, 1)], axis=0)
+        return a

@@ -36,9 +36,12 @@ def rope_tables(positions: jnp.ndarray, head_dim: int,
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray = None,
-               theta: float = 10000.0, tables=None) -> jnp.ndarray:
+               theta: float = 10000.0, tables=None,
+               interleaved: bool = False) -> jnp.ndarray:
     """Rotate ``x`` (..., T, H, D) by its ``positions`` ((T,) or (..., T)
-    int) — NeoX half-split convention: feature pairs are ``(i, i + D/2)``.
+    int) — NeoX half-split convention: feature pairs are ``(i, i + D/2)``;
+    ``interleaved`` pairs neighbours ``(2i, 2i + 1)`` instead (GPT-J's
+    convention, ``rope_gptj``), pair ``i`` turning by the same angle.
     Pass ``tables`` (from :func:`rope_tables`) to reuse precomputed
     cos/sin across layers.
 
@@ -51,6 +54,11 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray = None,
     if tables is None:
         tables = rope_tables(positions, D, theta)
     cos, sin = tables
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     out = jnp.concatenate(

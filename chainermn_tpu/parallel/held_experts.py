@@ -21,7 +21,9 @@ over a buffer that holds every pair (``lax.cond``: the exact fallback).
 Routing is the sigmoid / bias-corrected top-k of the DeepSeek-V3 family:
 scores ``s = sigmoid(u . W_g)`` in float32, the ``k`` largest of ``s +
 e_bias`` chosen, weights ``s`` of the chosen over their sum times ``scale``;
-experts are ``relu(.)**2`` MLPs, not gated.
+experts are ``relu(.)**2`` MLPs, not gated — or, with
+``activation=swiglu``, gated ones whose gate and up projections are one
+``(held, D, 2 F)`` product.
 """
 
 from __future__ import annotations
@@ -49,6 +51,13 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def swiglu(h):
+    """``silu(gate) * up`` of a fused ``[gate | up]`` product (..., 2 F):
+    the gated expert's activation, which halves the width."""
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
 def sigmoid_topk_route(u, w_gate, e_bias, k: int, *, scale: float = 1.0):
     """``(experts, weights)`` of shape (N, k) for tokens ``u`` (N, D): the
     router runs in float32 at the highest matmul precision over every
@@ -64,6 +73,7 @@ def sigmoid_topk_route(u, w_gate, e_bias, k: int, *, scale: float = 1.0):
 
 def held_experts_ffn(x, experts, weights, w_up, w_down, *, lo: int,
                      tile: int = 128, row_bound: Optional[int] = None,
+                     activation=relu2,
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' part of the routed sum for tokens ``x`` (N, D).
 
@@ -71,7 +81,8 @@ def held_experts_ffn(x, experts, weights, w_up, w_down, *, lo: int,
     layer's experts; ``w_up`` (held, D, F) and ``w_down`` (held, F, D) are
     the experts ``lo .. lo + held - 1``.  Returns ``(y, counters)`` with
     ``y`` (N, D) float32: ``sum over chosen held e of weight * down_e .
-    relu2(up_e . x)``.  ``tile`` rows of the buffer belong to one expert (a
+    activation(up_e . x)`` — :func:`relu2`, or :func:`swiglu` over a
+    ``w_up`` that is ``[gate | up]`` (held, D, 2 F).  ``tile`` rows of the buffer belong to one expert (a
     multiple of 8); ``row_bound`` sizes the buffer of the usual case (all
     ``N * k`` pairs when ``None``).
     """
@@ -104,7 +115,7 @@ def held_experts_ffn(x, experts, weights, w_up, w_down, *, lo: int,
         with jax.named_scope("moe.experts"):
             h = grouped_matmul(rows, w_up.astype(x.dtype), tile_group,
                                n_active, tile)
-            out = grouped_matmul(relu2(h), w_down.astype(x.dtype), tile_group,
+            out = grouped_matmul(activation(h), w_down.astype(x.dtype), tile_group,
                                  n_active, tile)
         with jax.named_scope("moe.combine"):
             gate = jnp.where(valid, flat_w[pair], 0)

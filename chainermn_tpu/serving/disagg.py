@@ -701,6 +701,12 @@ def drain_all(sched: Scheduler, transport: MigrationTransport,
 def _refuse_state(sched: Scheduler, role: str) -> None:
     """The disaggregated roles hand finished prefills over as KV blocks; a
     model with state by slot would arrive without its recurrent state."""
+    if getattr(sched.engine, "ringed", False):
+        raise NotImplementedError(
+            f"{role} with a model that keeps a ring by slot "
+            f"({type(sched.engine.model).__name__}): a migration frame "
+            "ships KV blocks, and the window layers' rings are in none"
+        )
     if getattr(sched.engine, "stateful", False):
         raise NotImplementedError(
             f"{role} with a model that keeps state by slot "

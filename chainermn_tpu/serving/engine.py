@@ -105,6 +105,20 @@ class DecodeEngine:
         is refused for such a model, at construction or at the call:
         ``prefix_cache=True``, ``draft_model`` / ``spec_k``, ``mesh=``,
         :meth:`cow_copy`, :meth:`read_block` / :meth:`write_block`.
+        Or a model with **window layers** — one whose ``ring_shapes()``
+        names a ring for some layer, ``HybridLM`` of ``W`` / ``G`` layers:
+        two kinds of cache in one engine.  A layer that attends its whole
+        context pages it as ever (the allocator's blocks, the slots'
+        tables); a window layer's entry of ``pools`` is a ring by slot,
+        ``{"ring": (capacity, R, block_len, KH * 2 * Dh)}``, O(window) a
+        slot whatever ``max_blocks_per_slot`` is, with no allocator, no
+        table and nothing to free — the program works its table out from
+        the positions.  The scheduler's accounting is the paged layers'
+        alone.  A ring is kept by slot too, so the same is refused, each
+        with the ring's reason.  Such a model's expert layers count their
+        routing, and the counts leave the step with the sampled tokens in
+        the one readback (``cmn_engine_readback(moe_pairs_held=,
+        moe_experts_touched=, moe_pairs_dropped=, moe_layers=)``).
       params: the model's parameter pytree.
       capacity: decode slots per step (the fixed batch dimension).
       num_blocks: physical blocks in the pool (block 0 stays reserved).
@@ -195,20 +209,38 @@ class DecodeEngine:
             )
         #: the model keeps state by slot beside the paged pool
         self.stateful = getattr(model, "state_shapes", None) is not None
-        if self.stateful:
-            for given, what, why in (
-                (prefix_cache, "prefix_cache=True",
-                 "a cached block has no state to go with it: the state "
-                 "after a shared prefix would have to be kept a trie node"),
-                (draft_model is not None, "speculative decoding",
-                 "a rejected draft cannot be rolled back by not advancing "
-                 "the position: the state has already taken it"),
-                (mesh is not None, "mesh=",
-                 "the slots' state has no sharding rule yet"),
-            ):
+        ring_shapes = getattr(model, "ring_shapes", None)
+        #: some layer keeps a ring by slot and no blocks of the pool
+        self.ringed = ring_shapes is not None and any(
+            r is not None
+            for r in ring_shapes(capacity, block_len, prefill_chunk))
+        # What is kept by slot — a ring, a recurrent state — no block holds:
+        # whatever shares or moves blocks is refused, each with its reason.
+        if self.ringed:
+            kept, whys = "a ring by slot", (
+                "a cached block is a full layer's alone: the window layers' "
+                "keys for the shared prefix live in the ring of the slot "
+                "that wrote them, and a ring is not shared",
+                "a verify chunk writes ahead of what is accepted, and a "
+                "ring's newest write takes the place of its oldest key: a "
+                "rejected tail cannot be rolled back by not advancing the "
+                "position",
+                "the slots' rings have no sharding rule yet")
+        else:
+            kept, whys = "state by slot", (
+                "a cached block has no state to go with it: the state "
+                "after a shared prefix would have to be kept a trie node",
+                "a rejected draft cannot be rolled back by not advancing "
+                "the position: the state has already taken it",
+                "the slots' state has no sharding rule yet")
+        if self.ringed or self.stateful:
+            for given, what, why in zip(
+                    (prefix_cache, draft_model is not None, mesh is not None),
+                    ("prefix_cache=True", "speculative decoding", "mesh="),
+                    whys):
                 if given:
                     raise NotImplementedError(
-                        f"{what} with a model that keeps state by slot "
+                        f"{what} with a model that keeps {kept} "
                         f"({type(model).__name__}): {why}"
                     )
         self.mesh = mesh
@@ -250,7 +282,8 @@ class DecodeEngine:
         self.draft_params = draft_params
         self.capacity = capacity
         self.pool = PagedKVPool(model, num_blocks, block_len,
-                                placement=placement, slots=capacity)
+                                placement=placement, slots=capacity,
+                                prefill_chunk=prefill_chunk)
         self.block_len = block_len
         self.spec_k = spec_k
         self.draft_model = draft_model
@@ -364,15 +397,36 @@ class DecodeEngine:
             return {"state_slot": slot[0],
                     "chunk_len": jnp.where(last_idx >= 0, last_idx + 1, rows)}
 
+        #: what the model's expert layers count a step (``serve_counters``:
+        #: names, each summed over the layers); they ride behind the
+        #: sampled tokens, so the one readback brings both
+        self.counters = tuple(getattr(model, "serve_counters", ()))
+
+        def apply(params, *args, **kwargs):
+            """``model.apply`` and, for a model that counts its routing,
+            the counts as int32 (one a name, summed over the layers)."""
+            if not self.counters:
+                return model.apply({"params": params}, *args, **kwargs), None
+            out, sown = model.apply({"params": params}, *args, **kwargs,
+                                    mutable=["intermediates"])
+            layers = sown["intermediates"].values()
+            return out, jnp.stack([
+                sum(jnp.sum(jnp.stack(layer[name])) for layer in layers
+                    if name in layer)
+                for name in self.counters]).astype(jnp.int32)
+
+        def with_counts(nxt, counts):
+            return nxt if counts is None else jnp.concatenate([nxt, counts])
+
         def step_impl(params, pools, tokens, pos, tables, active, rng,
                       temp):
-            logits, new_pools = model.apply(
-                {"params": params}, tokens[:, None], cache=pools,
+            (logits, new_pools), counts = apply(
+                params, tokens[:, None], cache=pools,
                 decode_pos=pos, block_tables=tables, slot_mask=active,
             )
             with jax.named_scope("sample"):
                 nxt = jax.vmap(pick)(logits[:, 0], rng, pos, temp)
-            return new_pools, nxt
+            return new_pools, with_counts(nxt, counts)
 
         # A prefill chunk RIDING the decode step: the capacity decode rows
         # and one slot's chunk — ``prefill_chunk`` more single-token rows
@@ -394,8 +448,8 @@ class DecodeEngine:
                 tables[:S],
                 jnp.broadcast_to(tables[S:], (C, tables.shape[1])),
             ])
-            h, new_pools = model.apply(
-                {"params": params}, tokens[:, None], cache=pools,
+            (h, new_pools), counts = apply(
+                params, tokens[:, None], cache=pools,
                 decode_pos=pos, block_tables=row_tables, slot_mask=active,
                 chunk_rows=C, return_hidden=True,
                 **of_chunk(last_idx, C, slot),
@@ -412,7 +466,7 @@ class DecodeEngine:
                     pos[take],
                     jnp.concatenate([temp, temp[slot][None]]),
                 )
-            return new_pools, nxt
+            return new_pools, with_counts(nxt, counts)
 
         # Prefill stays a SINGLE-ROW program (one slot's chunk per call):
         # a fixed-capacity variant would pay the full ``capacity x chunk``
@@ -716,9 +770,20 @@ class DecodeEngine:
         ctrl = self._upload(tokens, pos, tables, active)
         with _annotate("cmn_engine_dispatch", program="decode_step"):
             self.pools, nxt = self._step(self.params, self.pools, *ctrl)
-        # The wait for the device: everything dispatched drains here.
-        with _annotate("cmn_engine_readback"):
-            return np.asarray(nxt)
+        return self._readback(nxt)
+
+    def _readback(self, nxt) -> np.ndarray:
+        """The wait for the device — everything dispatched drains here —
+        and the step's tokens; the routing counts that rode behind them
+        (``counters``) become the span's counts."""
+        with _annotate("cmn_engine_readback") as span:
+            out = np.asarray(nxt)
+            n = len(self.counters)
+            if n:
+                span.set_metadata(**{
+                    k: int(v) for k, v in zip(self.counters, out[-n:])})
+                out = out[:-n]
+        return out
 
     def mixed_step(self, tokens: np.ndarray, pos: np.ndarray,
                    tables: np.ndarray, active: np.ndarray, slot: int,
@@ -777,8 +842,7 @@ class DecodeEngine:
         )
         with _annotate("cmn_engine_dispatch", program=program, chunk=1):
             self.pools, nxt = self._mixed(self.params, self.pools, *ctrl)
-        with _annotate("cmn_engine_readback"):
-            out = np.asarray(nxt)
+        out = self._readback(nxt)
         return out[:-1], (int(out[-1]) if last_idx >= 0 else None)
 
     def spec_step(self, tokens: np.ndarray, pos: np.ndarray,
@@ -814,6 +878,14 @@ class DecodeEngine:
         """A block of a model with state by slot is half of what a position
         needs: the other half is the slot's recurrent state *at that
         position*, which nothing keeps."""
+        if self.ringed:
+            raise NotImplementedError(
+                f"{what} with a model that keeps a ring by slot "
+                f"({type(self.model).__name__}): a block holds the full "
+                "layers' keys and values alone — the window layers' are in "
+                "the slot's ring, which no block id names — such a request "
+                "moves as a recompute entry (its text), not as blocks"
+            )
         if self.stateful:
             raise NotImplementedError(
                 f"{what} with a model that keeps state by slot "
@@ -938,6 +1010,14 @@ class DecodeEngine:
         <= 1; 0 on a speculative engine, which has none)."""
         return int(self._mixed._cache_size()) if self._mixed else 0
 
+    def ring_resident(self, positions) -> int:
+        """Blocks of ONE window layer's rings that a decode step reads for
+        slots writing ``positions``: each slot's blocks from the one that
+        holds its oldest visible key to the one it writes — what the kernel
+        walks, a count the host has at hand."""
+        BL, w = self.block_len, self.model.window
+        return sum(p // BL - max(p - w + 1, 0) // BL + 1 for p in positions)
+
     def free_blocks(self) -> int:
         return self.pool.allocator.free_blocks
 
@@ -962,6 +1042,9 @@ class DecodeEngine:
             # beside the blocks' budget: what the slots' state holds,
             # whatever the contexts are
             out["state_bytes"] = self.pool.state_bytes
+        if self.ringed:
+            # and the window layers' rings, O(window) a slot
+            out["ring_bytes"] = self.pool.ring_bytes
         if self.prefix is not None:
             out["prefix_cached_blocks"] = self.prefix.cached_blocks
         if self.spec_k:

@@ -187,7 +187,13 @@ class PagedKVPool:
     its ``"kv"``: one tree, donated and returned by the engine's programs
     together.  ``state_bytes`` is what that costs, apart from the blocks'
     budget; a model without ``state_shapes`` gets the entries it always
-    had.
+    had.  A layer that attends a window only keeps no blocks of the pool
+    at all: the model's ``ring_shapes(slots, block_len, prefill_chunk)``
+    names, a layer, ``None`` or the ``(shape, dtype)`` of its **ring by
+    slot** (:func:`~chainermn_tpu.ops.decode_attention.ring_attend`), and
+    that layer's entry is ``{"ring": ...}`` in place of ``{"kv": ...}`` —
+    ``ring_bytes``, O(window) a slot whatever the contexts; the allocator's
+    blocks (``bytes_per_block``) are the other layers' alone.
     ``kv_dtype=jnp.int8`` models get int8 pools with fp32 scale planes —
     the same symmetric-absmax convention as the contiguous cache, at half
     the bf16 pool bytes.
@@ -203,7 +209,7 @@ class PagedKVPool:
     """
 
     def __init__(self, model, num_blocks: int, block_len: int,
-                 placement=None, slots: int = 0):
+                 placement=None, slots: int = 0, prefill_chunk: int = 0):
         import jax.numpy as jnp
 
         from chainermn_tpu.ops.decode_attention import pool_shapes
@@ -218,6 +224,18 @@ class PagedKVPool:
         self.block_len = block_len
         self.num_blocks = num_blocks
         self.allocator = BlockAllocator(num_blocks)
+        #: HBM bytes of the window layers' rings (0: every layer pages).
+        self.ring_bytes = 0
+        ring_shapes = getattr(model, "ring_shapes", None)
+        rings = [None] * model.n_layers
+        if ring_shapes is not None:
+            rings = ring_shapes(slots, block_len, prefill_chunk)
+        if any(r is not None for r in rings) and (
+                slots < 1 or jnp.dtype(kvd) == jnp.int8):
+            raise ValueError(
+                "a model with window layers keeps a float ring by slot: "
+                f"got slots={slots}, kv_dtype={kvd}"
+            )
         if jnp.dtype(kvd) == jnp.int8:
             self.pools: List[Dict] = [
                 {"kv": jnp.zeros(shape, jnp.int8),
@@ -231,10 +249,17 @@ class PagedKVPool:
                 raise ValueError(
                     f"kv_dtype must be a float dtype or jnp.int8, got {kvd}"
                 )
+            # a ring layer never holds a block of the pool: its entry is
+            # built alone (a pool built first and dropped would not fit
+            # beside the weights)
             self.pools = [
-                {"kv": jnp.zeros(shape, kvd)} for _ in range(model.n_layers)
+                {"kv": jnp.zeros(shape, kvd)} if ring is None
+                else {"ring": jnp.zeros(*ring)} for ring in rings
             ]
             per_layer = math.prod(shape[1:]) * jnp.dtype(kvd).itemsize
+            self.ring_bytes = sum(
+                math.prod(r[0]) * jnp.dtype(r[1]).itemsize
+                for r in rings if r is not None)
         #: HBM bytes of the slots' state across all layers (0: the model
         #: keeps none).
         self.state_bytes = 0
@@ -260,4 +285,5 @@ class PagedKVPool:
         #: from geometry, NOT the arrays: the engine donates the pool
         #: buffers to its jitted step, so these initial arrays are deleted
         #: after the first iteration.
-        self.bytes_per_block = int(per_layer * model.n_layers)
+        self.bytes_per_block = int(
+            per_layer * sum(r is None for r in rings))

@@ -134,7 +134,11 @@ from chainermn_tpu.serving.kv_pool import PoolExhausted, blocks_for
 _LEDGER_COUNTS = {
     "cmn_serve_prefill": ("tokens", "padded", "final", "ctx_blocks", "rode",
                           "state_reset"),
-    "cmn_serve_decode": ("live", "chunk_rows", "state_rows"),
+    "cmn_serve_decode": ("live", "chunk_rows", "state_rows",
+                         "ring_blocks_resident"),
+    # a model that counts its routing: what rode behind the step's tokens
+    "cmn_engine_readback": ("moe_pairs_held", "moe_experts_touched",
+                            "moe_pairs_dropped", "moe_layers"),
     "cmn_serve_emit": ("tokens", "retired"),
     "cmn_serve_admit": ("admitted",),
 }
@@ -1486,6 +1490,11 @@ class Scheduler:
                 # step updates, the riding chunk's among them
                 **({"state_rows": len(live) + (staged is not None)}
                    if getattr(self.engine, "stateful", False) else {}),
+                # a model with window layers: the blocks of ONE such
+                # layer's rings that this step's decode rows read
+                **({"ring_blocks_resident": self.engine.ring_resident(
+                    [s.pos for s in live])}
+                   if getattr(self.engine, "ringed", False) else {}),
             )
             mixed = self._unsynced_prefill or staged is not None
             self._iterations += 1

@@ -353,6 +353,103 @@ def test_the_slots_state_is_stepped_in_place(chunk_rows, chip):
     assert len(fusions) == 1, fusions
 
 
+# ------------------------------------------ a window layer's ring by slot
+def test_mosaic_accepts_the_window_form_at_blocks_of_128(chip):
+    """The paged kernel's window form at the fifth configuration's shapes:
+    128 query / 8 KV heads of 128 (a group of 16), a ring of 32 slots x 34
+    blocks of 128 positions flattened to ``(1088, 128, 2048)`` — a 512 KB
+    block a DMA, four of them in VMEM — five prefetched scalars a slot: one
+    ``tpu_custom_call`` named ``paged_decode_window``."""
+    slots, R, bl = 32, 34, 128
+    args = [((slots, 128, 128), jnp.bfloat16),
+            ((slots * R, bl, 8 * 2 * 128), jnp.bfloat16),
+            ((slots, R), jnp.int32), ((slots,), jnp.int32)]
+    bounds = [((slots,), jnp.int32)] * 2
+
+    def fn(q, pool, tbl, valid, lowest, first_pos):
+        return paged_decode_attention(q, pool, tbl, valid, None,
+                                      lowest=lowest, first_pos=first_pos)
+
+    text = jax.jit(fn).lower(*_on(chip, args + bounds)).compile().as_text()
+    _assert_mosaic_took(text, 1, ["paged_decode_window"], "window")
+
+
+def _parallel_layers_step(chunk_rows, prefill=False):
+    """A ``W`` and a ``G`` layer at the fifth configuration's widths over
+    its pools — 32 slots, a ring of 34 blocks of 128 a slot, 3,585 blocks of
+    the paged pool, a table 112 wide, chunks of 256 — as the engine's
+    decode step (with ``chunk_rows``, its mixed step; ``prefill``: a chunk
+    of its own) calls them, the pools donated."""
+    from chainermn_tpu.models import HybridLM
+    from chainermn_tpu.serving.kv_pool import PagedKVPool
+
+    slots, chunk, bl, width = 32, 256, 128, 112
+    model = HybridLM(
+        vocab=256, n_layers=2, d_model=4096, layer_kinds="WG", n_heads=128,
+        n_kv_heads=8, head_dim=128, window=4096, rope_theta=50000.0,
+        rope_interleaved=True, norm="layer", tie_embeddings=True,
+        experts_held=16, ep_of=8, experts_per_tok=8, d_expert=4096,
+        n_shared=4, d_shared=16384, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, decode_attention="fused")
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"])
+    pools = jax.eval_shape(lambda: PagedKVPool(
+        model, 3585, bl, slots=slots, prefill_chunk=chunk).pools)
+    rows = 1 if prefill else slots + chunk_rows
+
+    def step(params, pools, tokens, pos, tables, active, slot, n):
+        kw = dict(state_slot=slot, chunk_len=n) \
+            if chunk_rows or prefill else {}
+        return model.apply(
+            {"params": params}, tokens, cache=pools,
+            decode_pos=pos[0] if prefill else pos, block_tables=tables,
+            slot_mask=None if prefill else active, return_hidden=True,
+            chunk_rows=chunk_rows, **kw)
+
+    args = (params, pools,
+            jax.ShapeDtypeStruct((rows, chunk if prefill else 1), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows, width), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    return jax.jit(step, donate_argnums=(1,)), args, pools
+
+
+@pytest.mark.parametrize("chunk_rows, prefill, kernels", [
+    (0, False, ["grouped_matmul", "paged_decode", "paged_decode_window"]),
+    (256, False, ["grouped_matmul", "paged_decode", "paged_decode_window"]),
+    (0, True, ["grouped_matmul"]),
+], ids=["decode", "mixed_c256", "prefill_c256"])
+def test_two_kinds_of_cache_are_updated_in_place(chunk_rows, prefill,
+                                                 kernels, chip):
+    """The guard of the ring's layout, as the two above are the block
+    pool's and the state's: the ring ``bf16[32,34,128,2048]`` and the pool
+    ``bf16[3585,128,2048]`` are written (a scatter that drops what a masked
+    row would write) and read (the kernels over the flattened ring and the
+    pool; a chunk's gathers) with no ``copy`` of either's shape, flattened
+    or not, and temporaries far under a ring — in particular no float32
+    scores of 128 heads x 256 rows over the context at once (1.9 GB); the
+    decode rows of BOTH kinds of layer run a Mosaic kernel, the expert
+    layer's two products are ``grouped_matmul`` launches, and a chunk of its
+    own launches no paged kernel."""
+    fn, args, pools = _parallel_layers_step(chunk_rows, prefill)
+    compiled = fn.lower(*_on(chip, args)).compile()
+    text = compiled.as_text()
+    _assert_mosaic_took(text, 4 + (0 if prefill else 2), kernels, chunk_rows)
+    ring, pool = pools[0]["ring"], pools[1]["kv"]
+    for shape in (ring.shape, (ring.shape[0] * ring.shape[1],) + ring.shape[2:],
+                  pool.shape):
+        shape = "[%s]" % ",".join(str(d) for d in shape)
+        copies = [c for c in re.findall(r"= (\S+) copy\(", text)
+                  if shape in c]
+        assert not copies, copies
+    ring_bytes = ring.size * 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < ring_bytes, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= ring_bytes + pool.size * 2
+
+
 # ------------------------------------------------- the hybrid cell's layers
 def _hybrid_attention():
     """A ``*`` layer's attention at the cell's shape: 32 query / 2 KV heads
