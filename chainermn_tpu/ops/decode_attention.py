@@ -45,7 +45,13 @@ head — because 25 matmuls of ``(1 x 64) . (64 x 16)`` a block leave the
 MXU idle behind its own latency; grouped queries (and the int8 pool,
 whose scale panels are per head) as a static loop of MXU matmuls over
 the KV heads, where ``G * T`` query rows a head make the matmul worth
-its push.  A 4-D query ``(S, T, H, Dh)`` is the **multi-query verify
+its push.  That second body's loop step is a **tile of 128 positions**
+where a block holds fewer (:func:`blocks_a_step`: eight consecutive table
+entries at ``block_len`` 16, their rows DMAed into consecutive slices of
+one buffer): a table lists a slot's blocks in logical order, so the tile
+is contiguous in everything the fold computes, and a head's two products
+cost the MXU's round trip once for 128 positions instead of once a block
+(PR 48).  A 4-D query ``(S, T, H, Dh)`` is the **multi-query verify
 mode** (the serving engine's speculative decode): query offset ``t``
 attends positions ``< valid_len + t`` — per-position causality inside
 the verify chunk, one kernel launch for all ``k + 1`` positions
@@ -194,42 +200,130 @@ def _group_sums(x, width):
     ], axis=1)
 
 
-#: rows of the pool in VMEM at once in :func:`paged_decode_attention`: one
-#: in use and three in flight.  On a v5e (my chip runs, PR 28) two buffers
-#: left every block waiting on its row — 0.40 us a block at 102 KB rows and
-#: at 16 KB rows alike, so the DMA's latency and not its bytes — three
-#: 0.29, four 0.27 (the arithmetic's own time at GPT-2 XL's row), six and
-#: eight no better.
+#: buffers of the pool's rows in VMEM at once in
+#: :func:`paged_decode_attention`, each one loop step's blocks (a block, or
+#: a tile of :func:`blocks_a_step`): one in use and three in flight.  On a
+#: v5e, a block a step (my chip runs, PR 28): two buffers left every block
+#: waiting on its row — 0.40 us a block at 102 KB rows and at 16 KB rows
+#: alike, so the DMA's latency and not its bytes — three 0.29, four 0.27
+#: (the arithmetic's own time at GPT-2 XL's row), six and eight no better.
+#: A tile of eight 32 KB rows a step (Falcon-H1's 64 slots of 36 blocks,
+#: one launch, the fold still one head after another; my chip runs, PR 48):
+#: two buffers 297 us, three 287, four 282, six 275 — a tile in flight
+#: already hides the rows' latency, and four buffers are 1 MB of VMEM.  As
+#: the fold ships (three passes over the heads) four read 218 us: 0.49 us a
+#: tile and 0.94 us a slot, where the parent's 0.62 us a block came to 4.97
+#: a tile.
 _ROWS_IN_VMEM = 4
 
+#: positions the per-head body folds a loop step where a block holds fewer
+#: (:func:`blocks_a_step`): a ``(rows, 128)`` float32 score tile is whole
+#: vregs, and a product's cost is the MXU's round trip, not its columns.
+_TILE = 128
 
-def _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold):
-    """This slot's resident blocks, in table order: ``fold(i, b)`` runs on
-    block ``i`` once its row sits in ``kv_buf[b]``, while the rows of the
-    blocks after it are in flight into the other buffers.  The loop's trip
-    count is the slot's own block count: a table entry past it costs
-    nothing.  ``init()`` runs behind the first rows' DMAs."""
+
+def _takes_rows(group: int, quant: bool, windowed: bool) -> bool:
+    """Whether a call runs :func:`_paged_row_kernel`: one query head a KV
+    head, a float pool, the whole context."""
+    return group == 1 and not quant and not windowed
+
+
+def blocks_a_step(block_len: int, dtype, group: int,
+                  windowed: bool = False) -> int:
+    """Consecutive table entries one loop step of the kernel folds, from
+    the call's static shapes alone (the scheduler's ``kv_steps=`` counts
+    with it what the kernel walks).
+
+    The per-head body (:func:`_paged_head_kernel`) folds a tile of
+    ``128 // block_len`` blocks where ``block_len`` divides 128, is under
+    it, and is whole packed sublane tiles of the pool's dtype (16 rows of
+    bfloat16, 8 of float32: a block's rows then land aligned in their
+    slice of the step's buffer); at any other length one block.  One block
+    too for an int8 pool — its scale panels ride ``(max_blocks, KH, 2,
+    block_len)`` a slot and a tile's would have to be laid along lanes
+    inside the kernel; no cell runs one — and for the row body
+    (:func:`_paged_row_kernel`: ``group == 1`` on a float pool, not
+    ``windowed``), whose arithmetic is along the row's lanes.
+    """
+    dtype = jnp.dtype(dtype)
+    quant = dtype == jnp.int8
+    if (_takes_rows(group, quant, windowed) or quant or block_len >= _TILE
+            or _TILE % block_len or block_len % (32 // dtype.itemsize)):
+        return 1
+    return _TILE // block_len
+
+
+def _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold,
+                 blocks=1):
+    """This slot's resident blocks, in table order, ``blocks`` a loop step:
+    ``fold(j, b)`` runs on step ``j`` once its blocks' rows sit in
+    consecutive slices of ``kv_buf[b]``, while the rows of the steps after
+    it are in flight into the other buffers.  The loop's trip count is the
+    slot's own and so is every copy: a table entry past its block count
+    costs neither a DMA nor an iteration, and the slices of a slot's last
+    step that no block of it fills are zeroed (whatever an earlier step
+    left there would reach the value product, and ``0 * NaN`` is ``NaN``).
+    ``init()`` runs behind the first rows' DMAs."""
     s_idx = pl.program_id(0)
     n = nblk_ref[s_idx]
     n_buf = kv_buf.shape[0]
+    block_len = kv_buf.shape[1] // blocks
+    steps = n if blocks == 1 else (n + (blocks - 1)) // blocks
 
-    def row(i):
-        b = i % n_buf
+    def entry(j, c):
+        """The table entry of step ``j``'s block ``c``."""
+        return j if blocks == 1 else j * blocks + c
+
+    def row(j, c):
+        b = j % n_buf
+        dst = kv_buf.at[b] if blocks == 1 else \
+            kv_buf.at[b, pl.ds(c * block_len, block_len)]
         return pltpu.make_async_copy(
-            kv_hbm.at[tbl_ref[s_idx, i]], kv_buf.at[b], sem.at[b])
+            kv_hbm.at[tbl_ref[s_idx, entry(j, c)]], dst, sem.at[b])
 
-    for i in range(n_buf - 1):
-        pl.when(i < n)(row(i).start)
+    def start(j):
+        """Step ``j``'s blocks on their way, those the slot holds (``j``
+        traced: no entry of the table is named at trace time)."""
+        for c in range(blocks):
+            pl.when(entry(j, c) < n)(lambda c=c: row(j, c).start())
+
+    for j in range(n_buf - 1):
+        if blocks == 1:
+            pl.when(j < n)(row(j, 0).start)
+        else:
+            start(jnp.int32(j))
     init()
 
-    def body(i, carry):
-        ahead = i + (n_buf - 1)
-        pl.when(ahead < n)(lambda: row(ahead).start())
-        row(i).wait()
-        fold(i, i % n_buf)
+    def body(j, carry):
+        ahead = j + (n_buf - 1)
+        if blocks == 1:
+            pl.when(ahead < n)(lambda: row(ahead, 0).start())
+            row(j, 0).wait()
+        else:
+            b = j % n_buf
+            pl.when(ahead < steps)(lambda: start(ahead))
+            whole = entry(j, blocks - 1) < n
+
+            @pl.when(whole)
+            def _():
+                for c in range(blocks):
+                    row(j, c).wait()
+
+            @pl.when(jnp.logical_not(whole))
+            def _():  # the slot's last step: the blocks it has, zeros after
+                row(j, 0).wait()
+                for c in range(1, blocks):
+                    pl.when(entry(j, c) < n)(lambda c=c: row(j, c).wait())
+
+                    @pl.when(entry(j, c) >= n)
+                    def _():
+                        kv_buf[b, pl.ds(c * block_len, block_len)] = \
+                            jnp.zeros((block_len, kv_buf.shape[2]),
+                                      kv_buf.dtype)
+        fold(j, j % n_buf)
         return carry
 
-    jax.lax.fori_loop(0, n, body, None)
+    jax.lax.fori_loop(0, steps, body, None)
 
 
 def _paged_row_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, o_ref,
@@ -304,13 +398,27 @@ def _paged_row_kernel(tbl_ref, len_ref, nblk_ref, q_ref, kv_hbm, o_ref,
 
 
 def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, *rest,
-                       scale, block_len, quant, n_q, group, windowed=False):
-    """One slot, a static loop over a block's KV heads: per head the
-    ``(n_q * G, Dh) x (Dh, block_len)`` score and ``(n_q * G, block_len) x
-    (block_len, Dh)`` value matmuls on the head's ``[k | v]`` lane group of
-    the row, accumulated through the online-softmax recurrence into that
-    head's VMEM scratch; the end normalizes and writes the ``(KH, n_q * G,
-    Dh)`` output.
+                       scale, block_len, quant, n_q, group, blocks=1,
+                       windowed=False):
+    """One slot, a loop step a **tile** of ``blocks`` consecutive blocks
+    (:func:`blocks_a_step`: 128 positions where a block holds fewer) and in
+    it static loops over the KV heads: per head ONE ``(n_q * G, Dh) x (Dh,
+    blocks * block_len)`` score and ONE ``(n_q * G, blocks * block_len) x
+    (blocks * block_len, Dh)`` value matmul on the head's ``[k | v]`` lane
+    group of the tile's rows — the heads' score products first, then their
+    softmax steps, then their value products — accumulated through the
+    online-softmax recurrence into that head's VMEM scratch; the end
+    normalizes and writes the ``(KH, n_q * G, Dh)`` output.  A table lists a slot's blocks in
+    logical order, so a tile's positions are consecutive in everything the
+    fold computes.
+
+    Both products are float32 by float32 — queries scaled once a slot,
+    keys and values cast as they leave the buffer, the probabilities as
+    they are — and the running max, normalizer and accumulator float32: a
+    block a step (``blocks == 1``) is, a head, the arithmetic it was before
+    a step held a tile, to the bit.  (The score product on the stored bfloat16
+    with the scale on the scores read no faster on the chip, and 1.5%
+    slower at ``block_len`` 128: my chip runs, PR 48.)
 
     ``n_q`` query positions ride as extra rows (row ``r`` is query offset
     ``r // group``): offset ``t`` attends positions ``< valid + t`` —
@@ -329,6 +437,7 @@ def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, *rest,
         sc_ref, *rest = rest
     o_ref, kv_buf, sem, m_scr, l_scr, acc = rest
     KH, R, Dh = q_ref.shape[1:]
+    width = blocks * block_len
 
     def init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
@@ -337,34 +446,48 @@ def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, *rest,
 
     # Row r is query offset r // group; it may attend one position more
     # than the row before it (the verify chunk's causality).
-    bound = len_ref[pl.program_id(0)] + jax.lax.broadcasted_iota(
-        jnp.int32, (R, block_len), 0) // group
+    bound = len_ref[pl.program_id(0)]
+    if n_q > 1:
+        bound = bound + jax.lax.broadcasted_iota(
+            jnp.int32, (R, width), 0) // group
 
     if windowed:
         lowest, first = (ref[pl.program_id(0)] for ref in (lo_ref, first_ref))
 
-    def fold(i, b):
-        pos = i * block_len + jax.lax.broadcasted_iota(
-            jnp.int32, (R, block_len), 1
-        )
+    # The slot's queries, cast and scaled once: (R, Dh) a KV head.
+    qs = [q_ref[0, h].astype(jnp.float32) * scale for h in range(KH)]
+
+    def fold(j, b):
+        pos = j * width + jax.lax.broadcasted_iota(jnp.int32, (R, width), 1)
         if windowed:
             pos = pos + first
             mask = (pos < bound) & (pos >= lowest)
         else:
             mask = pos < bound
-        for h in range(KH):
-            q = q_ref[0, h].astype(jnp.float32) * scale   # (R, Dh)
-            kv = kv_buf[b, :, h * 2 * Dh:(h + 1) * 2 * Dh] \
-                .astype(jnp.float32)                      # (BL, 2·Dh)
-            k, v = kv[:, :Dh], kv[:, Dh:]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+        # Three passes over the KV heads — every head's score product, then
+        # every head's softmax step, then every head's value product — and
+        # not one head after another.  A head's arithmetic is the same to
+        # the bit; the compiler's MXU assigner then lays the products out
+        # so that they do not wait on each other (on a v5e, my chip runs,
+        # PR 48: 1.21 -> 0.71 us a 512 KB block at 8 KV heads of 16 rows,
+        # the DMA's own time; 282 -> 219 us a launch at Falcon-H1's).
+        def rows(h, half):  # head h's keys (0) or values (1): (width, Dh)
+            lanes = (2 * h + half) * Dh
+            return kv_buf[b, :, lanes:lanes + Dh].astype(jnp.float32)
+
+        scores = [
+            jax.lax.dot_general(
+                qs[h], rows(h, 0), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # (R, BL)
+            )  # (R, width)
+            for h in range(KH)
+        ]
+        probs, alphas = [], []
+        for h, s in enumerate(scores):
             if quant:
                 # Per-position k scale commutes out of the Dh contraction;
                 # v scale folds into the probability operand below.
-                s = s * sc_ref[0, i, h, 0:1, :]
+                s = s * sc_ref[0, j, h, 0:1, :]
             s = jnp.where(mask, s, NEG_INF)
             m_prev = m_scr[h, :, 0]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -374,15 +497,18 @@ def _paged_head_kernel(tbl_ref, len_ref, nblk_ref, *rest,
             # = 1 per position.
             p = jnp.exp(s - m_new[:, None]) * mask.astype(jnp.float32)
             l_scr[h, :, 0] = alpha * l_scr[h, :, 0] + jnp.sum(p, axis=1)
+            m_scr[h, :, 0] = m_new
             if quant:
-                p = p * sc_ref[0, i, h, 1:2, :]
-            acc[h] = alpha[:, None] * acc[h] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p = p * sc_ref[0, j, h, 1:2, :]
+            probs.append(p)
+            alphas.append(alpha)
+        for h in range(KH):
+            acc[h] = alphas[h][:, None] * acc[h] + jax.lax.dot_general(
+                probs[h], rows(h, 1), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_scr[h, :, 0] = m_new
 
-    _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold)
+    _walk_blocks(tbl_ref, nblk_ref, kv_hbm, kv_buf, sem, init, fold, blocks)
     o_ref[0] = (acc[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
@@ -405,17 +531,19 @@ def paged_decode_attention(
     scalar-prefetched: the pool stays in HBM and the step loops over the
     slot's own blocks (:func:`_walk_blocks`), DMAing the whole row
     ``pool[block_tables[s, i]]`` — every KV head of the block in one
-    contiguous read — ``_ROWS_IN_VMEM - 1`` blocks ahead of the one it
+    contiguous read — ``_ROWS_IN_VMEM - 1`` loop steps ahead of the one it
     folds through the online-softmax recurrence.  No contiguous per-slot
     cache copy is ever materialized, VMEM bounds no context length, and a
     table entry past the slot's last resident block costs neither a DMA
-    nor a loop iteration.
+    nor a loop iteration — also where a loop step folds several blocks: a
+    slot's last step copies the blocks it has and no other.
 
     How the heads of a row are handled follows from the static shapes
     alone: one query head a KV head (``G == 1``, float pool) as lane-dense
     arithmetic over the whole row (:func:`_paged_row_kernel`); grouped
     queries, and the int8 pool with its per-head scale panel, as a static
-    loop of MXU matmuls over the KV heads (:func:`_paged_head_kernel`).
+    loop of MXU matmuls over the KV heads (:func:`_paged_head_kernel`), a
+    loop step :func:`blocks_a_step` consecutive blocks of the table.
 
     Args:
       q: ``(S, H, Dh)`` — each slot's current query position — or
@@ -503,7 +631,8 @@ def paged_decode_attention(
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     q4 = q.reshape(S, T, KH, G, Dh)
-    by_row = G == 1 and not quant and not windowed
+    by_row = _takes_rows(G, quant, windowed)
+    C = blocks_a_step(BL, kv_pool.dtype, G, windowed)
     if by_row:
         # Query offset t's heads along the row's own lanes: scaled, zeros
         # under the value lanes.
@@ -528,7 +657,8 @@ def paged_decode_attention(
         qg = q4.transpose(0, 2, 1, 3, 4).reshape(S, KH, R, Dh)
         kernel = functools.partial(
             _paged_head_kernel, scale=scale, block_len=BL, quant=quant,
-            n_q=T, group=G, **({"windowed": True} if windowed else {}),
+            n_q=T, group=G, blocks=C,
+            **({"windowed": True} if windowed else {}),
         )
         operands = [qg, kv_pool]
         in_specs = [pl.BlockSpec((1, KH, R, Dh), slot), hbm]
@@ -554,7 +684,7 @@ def paged_decode_attention(
             in_specs=in_specs,
             out_specs=out_spec,
             scratch_shapes=[
-                pltpu.VMEM((_ROWS_IN_VMEM, BL, L), kv_pool.dtype),
+                pltpu.VMEM((_ROWS_IN_VMEM, C * BL, L), kv_pool.dtype),
                 pltpu.SemaphoreType.DMA((_ROWS_IN_VMEM,)),
             ] + scratch,
         ),

@@ -83,7 +83,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from chainermn_tpu.observability.tracing import annotate as _annotate
-from chainermn_tpu.serving.kv_pool import PagedKVPool
+from chainermn_tpu.serving.kv_pool import PagedKVPool, blocks_for
 from chainermn_tpu.serving.prefix_cache import PrefixCache
 
 
@@ -1009,6 +1009,15 @@ class DecodeEngine:
         """Variants of the decode step a prefill chunk rides (must stay
         <= 1; 0 on a speculative engine, which has none)."""
         return int(self._mixed._cache_size()) if self._mixed else 0
+
+    def kv_steps(self, positions) -> int:
+        """Loop steps ONE layer's paged kernel takes for slots writing
+        ``positions``: a slot's resident blocks, ``blocks_a_step`` of them
+        a step (:func:`~chainermn_tpu.ops.decode_attention.blocks_a_step`
+        at the pool's geometry) and its last step what is left."""
+        C = self.pool.blocks_a_step
+        return sum(-(-blocks_for(p + 1, self.block_len) // C)
+                   for p in positions)
 
     def ring_resident(self, positions) -> int:
         """Blocks of ONE window layer's rings that a decode step reads for

@@ -212,7 +212,10 @@ class PagedKVPool:
                  placement=None, slots: int = 0, prefill_chunk: int = 0):
         import jax.numpy as jnp
 
-        from chainermn_tpu.ops.decode_attention import pool_shapes
+        from chainermn_tpu.ops.decode_attention import (
+            blocks_a_step,
+            pool_shapes,
+        )
 
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1, got {block_len}")
@@ -222,6 +225,10 @@ class PagedKVPool:
         kvd = kvd if kvd is not None else model.dtype
         shape, scale_shape = pool_shapes(num_blocks, block_len, kvh, dh)
         self.block_len = block_len
+        #: table entries the paged kernel folds a loop step at this pool's
+        #: geometry (what the scheduler's ``kv_steps=`` counts with)
+        self.blocks_a_step = blocks_a_step(block_len, kvd,
+                                           model.n_heads // kvh)
         self.num_blocks = num_blocks
         self.allocator = BlockAllocator(num_blocks)
         #: HBM bytes of the window layers' rings (0: every layer pages).

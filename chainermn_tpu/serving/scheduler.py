@@ -1484,6 +1484,10 @@ class Scheduler:
                     blocks_for(s.pos + 1, self.engine.block_len)
                     for s in live
                 ),
+                # the loop steps one layer's kernel takes over them: a
+                # step folds blocks_a_step of a slot's blocks
+                kv_steps=lambda: self.engine.kv_steps(
+                    [s.pos for s in live]),
                 kv_blocks_grid=S * self.engine.max_blocks,
                 table_width=self.engine.max_blocks,
                 # a model with state by slot: the slots whose state this

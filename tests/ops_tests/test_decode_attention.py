@@ -207,6 +207,46 @@ def test_paged_pool_shape_is_checked():
                    (jnp.ones((2, 1, 2)),) * 2, tbl, pos)
 
 
+def _pool_case(rng, kind, KH, NB, BL, Dh):
+    """A random pool whose LAST block is poison — NaN keys and values, in
+    an int8 pool NaN scales: ``(kp, vp, scale, kf, vf)``, the stored
+    per-head arrays, the fused scale plane (or ``None``) and the floats the
+    stored values stand for."""
+    if kind == "int8":
+        kp = rng.randint(-127, 128, size=(KH, NB, BL, Dh)).astype(np.int8)
+        vp = rng.randint(-127, 128, size=(KH, NB, BL, Dh)).astype(np.int8)
+        ks = (rng.rand(KH, NB, BL) * 0.02 + 0.001).astype(np.float32)
+        vs = (rng.rand(KH, NB, BL) * 0.02 + 0.001).astype(np.float32)
+        ks[:, NB - 1] = vs[:, NB - 1] = np.nan
+        return (kp, vp, _fuse_scale(ks, vs),
+                kp * ks[..., None], vp * vs[..., None])
+    kp = jnp.asarray(rng.randn(KH, NB, BL, Dh), jnp.bfloat16)
+    vp = jnp.asarray(rng.randn(KH, NB, BL, Dh), jnp.bfloat16)
+    kp = kp.at[:, NB - 1].set(jnp.nan)
+    vp = vp.at[:, NB - 1].set(jnp.nan)
+    return kp, vp, None, np.asarray(kp, np.float32), np.asarray(vp, np.float32)
+
+
+def _assert_kernel_is_the_oracle(q, pool, tbl, valid):
+    """The kernel on ``pool`` (:func:`_pool_case`) for queries ``(S, T, H,
+    Dh)``: finite — no poisoned or stale row reached it — the float32
+    oracle's for every live slot, and zeros at offset 0 of an idle one."""
+    from chainermn_tpu.ops import paged_decode_attention
+
+    kp, vp, scale, kf, vf = pool
+    T = q.shape[1]
+    out = paged_decode_attention(
+        q if T > 1 else q[:, 0], _fuse(kp, vp), jnp.asarray(tbl),
+        jnp.asarray(valid), scale)
+    out = np.asarray(out).reshape(q.shape)
+    assert np.isfinite(out).all()
+    ref = _paged_oracle(q, kf, vf, tbl, valid)
+    live = valid > 0
+    tol = dict(atol=1e-4, rtol=1e-4) if scale is not None else dict(atol=2e-5)
+    np.testing.assert_allclose(out[live], ref[live], **tol)
+    assert (out[~live, 0] == 0).all()  # idle: offset 0 fully masked
+
+
 #: slot lengths of one call (block_len 16, a table 4 wide), by name
 _BL, _MB = 16, 4
 _LENGTHS = {
@@ -223,14 +263,18 @@ _LENGTHS = {
 @pytest.mark.parametrize("kind", ["bf16", "int8"])
 @pytest.mark.parametrize("T", [1, 4], ids=["decode", "verify_t4"])
 @pytest.mark.parametrize("lengths", sorted(_LENGTHS))
-@pytest.mark.parametrize("H, KH, Dh", [(25, 25, 64), (24, 2, 128), (4, 2, 64)],
-                         ids=["xl_mha", "sc2_gqa", "gqa_small"])
+@pytest.mark.parametrize("H, KH, Dh", [(25, 25, 64), (24, 2, 128), (4, 2, 64),
+                                       (20, 4, 128)],
+                         ids=["xl_mha", "sc2_gqa", "gqa_small", "falcon_gqa"])
 def test_paged_whole_row_matches_oracle(H, KH, Dh, lengths, T, kind):
     """One grid step serves every head of a block: the kernel against the
     float32 oracle at the serving geometries' head shapes (GPT-2 XL's 25
-    heads of 64 handled along the row's lanes, StarCoder2's 24 / 2 of 128
-    and a small GQA as a loop over KV heads), at the slot lengths where a
-    block fills, for decode and a verify chunk, float and int8 pools.
+    heads of 64 handled along the row's lanes, StarCoder2's 24 / 2 of 128,
+    a small GQA and Falcon-H1's 20 / 4 of 128 as a loop over KV heads), at
+    the slot lengths where a block fills, for decode and a verify chunk,
+    float and int8 pools.  (A table 4 wide at ``block_len`` 16: where the
+    per-head body folds a tile of 8 blocks, every slot here is one partial
+    tile; ``test_paged_tiles_match_oracle`` below has the whole ones.)
 
     Table entries past what a slot can attend point at a block of NaN (in
     an int8 pool, at NaN scales): a step past the slot's last resident
@@ -247,35 +291,93 @@ def test_paged_whole_row_matches_oracle(H, KH, Dh, lengths, T, kind):
     rng = np.random.RandomState(len(lengths) * 100 + T)
     NB = S * _MB + 2
     q = jnp.asarray(rng.randn(S, T, H, Dh), jnp.float32)
-    if kind == "int8":
-        kp = rng.randint(-127, 128, size=(KH, NB, _BL, Dh)).astype(np.int8)
-        vp = rng.randint(-127, 128, size=(KH, NB, _BL, Dh)).astype(np.int8)
-        ks = (rng.rand(KH, NB, _BL) * 0.02 + 0.001).astype(np.float32)
-        vs = (rng.rand(KH, NB, _BL) * 0.02 + 0.001).astype(np.float32)
-        ks[:, NB - 1] = vs[:, NB - 1] = np.nan
-        scale = _fuse_scale(ks, vs)
-        kf, vf = kp * ks[..., None], vp * vs[..., None]
-    else:
-        kp = jnp.asarray(rng.randn(KH, NB, _BL, Dh), jnp.bfloat16)
-        vp = jnp.asarray(rng.randn(KH, NB, _BL, Dh), jnp.bfloat16)
-        kp = kp.at[:, NB - 1].set(jnp.nan)
-        vp = vp.at[:, NB - 1].set(jnp.nan)
-        scale = None
-        kf, vf = np.asarray(kp, np.float32), np.asarray(vp, np.float32)
+    pool = _pool_case(rng, kind, KH, NB, _BL, Dh)
     tbl = np.full((S, _MB), NB - 1, np.int32)  # the poisoned block
     for s in range(S):
         n = -(-(int(valid[s]) + T - 1) // _BL)
         tbl[s, :n] = 1 + s * _MB + np.arange(n)
-    out = paged_decode_attention(
-        q if T > 1 else q[:, 0], _fuse(kp, vp), jnp.asarray(tbl),
-        jnp.asarray(valid), scale)
-    out = np.asarray(out).reshape(S, T, H, Dh)
+    _assert_kernel_is_the_oracle(q, pool, tbl, valid)
+
+
+#: blocks a slot holds, in the table's order of slots (block_len 16, so a
+#: tile is C = 8 blocks): the longest first, so that the slot after it —
+#: one whole tile, then a tile of ONE block — finds the buffers full of the
+#: longer context's finite rows
+_C = 128 // _BL
+_TILE_COUNTS = [3 * _C + 5, _C + 1, 0, 1, _C - 1, _C, 2 * _C]
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("T", [1, 4], ids=["decode", "verify_t4"])
+def test_paged_tiles_match_oracle(T, kind):
+    """Falcon-H1's head shape over a table 32 wide at ``block_len`` 16: the
+    per-head body folds eight table entries a loop step
+    (:func:`blocks_a_step`; one, for the int8 pool), and a slot's block
+    count is any number — none, one, a tile less one, a tile, a tile and
+    one, two tiles, three and five.  Every entry past a slot's count names
+    the block of NaN, so a copy of one poisons the output; the slices of a
+    slot's last tile that no block fills hold what an earlier step left
+    (in the interpreter NaN at first, then the longest slot's rows), and a
+    row of them in the value product would be ``0 * NaN``: the output is
+    finite and the oracle's."""
+    from chainermn_tpu.ops.decode_attention import blocks_a_step
+
+    H, KH, Dh, MB = 20, 4, 128, 32
+    assert blocks_a_step(_BL, jnp.bfloat16, H // KH) == _C
+    assert blocks_a_step(_BL, jnp.int8, H // KH) == 1
+    S = len(_TILE_COUNTS)
+    # a slot's last block part full, and as many blocks as the list says
+    valid = np.asarray([max(n * _BL - (T - 1) - s % 5, 0)
+                        for s, n in enumerate(_TILE_COUNTS)], np.int32)
+    rng = np.random.RandomState(7 + T)
+    NB = sum(_TILE_COUNTS) + 3
+    q = jnp.asarray(rng.randn(S, T, H, Dh), jnp.float32)
+    pool = _pool_case(rng, kind, KH, NB, _BL, Dh)
+    tbl = np.full((S, MB), NB - 1, np.int32)  # the poisoned block
+    free = iter(rng.permutation(np.arange(1, NB - 1)))
+    for s in range(S):
+        n = -(-(int(valid[s]) + T - 1) // _BL)
+        assert n == max(_TILE_COUNTS[s], int(T > 1))
+        tbl[s, :n] = [next(free) for _ in range(n)]
+    _assert_kernel_is_the_oracle(q, pool, tbl, valid)
+
+
+@pytest.mark.parametrize("H, KH", [(20, 4), (4, 4)], ids=["group5", "mha"])
+def test_paged_window_form_walks_tiles(H, KH):
+    """The window form at ``block_len`` 16: a ring's table rotated oldest
+    first, its first entry at a position that is a multiple of
+    ``block_len`` and not of the tile's 128 — a tile's positions are
+    ``first_pos + j * 128 ...`` — the lower bound inside the first block,
+    entries past a slot's newest block poisoned."""
+    from chainermn_tpu.ops import paged_decode_attention
+
+    Dh, R, window = 128, 16, 200
+    valid = np.asarray([500, 77, 0, 1000], np.int32)
+    lowest = np.maximum(valid - window, 0).astype(np.int32)
+    first = lowest // _BL * _BL
+    assert {int(f) % 128 for f in first} == {0, 32}
+    S = len(valid)
+    NB = S * R + 2
+    rng = np.random.RandomState(H)
+    q = jnp.asarray(rng.randn(S, H, Dh), jnp.float32)
+    kp, vp, _, kf, vf = _pool_case(rng, "bf16", KH, NB, _BL, Dh)
+    tbl = np.full((S, R), NB - 1, np.int32)
+    ref = np.zeros((S, H, Dh), np.float32)
+    for s in range(S):
+        n = -(-(int(valid[s]) - int(first[s])) // _BL)
+        tbl[s, :n] = 1 + s * R + (np.arange(n) + 3 * s) % R  # a ring's turn
+        k, v = (x[:, tbl[s, :n]].reshape(KH, n * _BL, Dh) for x in (kf, vf))
+        seen = slice(int(lowest[s] - first[s]), int(valid[s] - first[s]))
+        for h in range(H if valid[s] else 0):
+            sc = np.asarray(q)[s, h] @ k[h * KH // H, seen].T / np.sqrt(Dh)
+            p = np.exp(sc - sc.max())
+            ref[s, h] = p / p.sum() @ v[h * KH // H, seen]
+    out = np.asarray(paged_decode_attention(
+        q, _fuse(kp, vp), jnp.asarray(tbl), jnp.asarray(valid), None,
+        lowest=jnp.asarray(lowest), first_pos=jnp.asarray(first)))
     assert np.isfinite(out).all()
-    ref = _paged_oracle(q, kf, vf, tbl, valid)
-    live = valid > 0
-    tol = dict(atol=1e-4, rtol=1e-4) if kind == "int8" else dict(atol=2e-5)
-    np.testing.assert_allclose(out[live], ref[live], **tol)
-    assert (out[~live, 0] == 0).all()  # idle: offset 0 fully masked
+    np.testing.assert_allclose(out, ref, atol=2e-5)
+    assert (out[2] == 0).all()  # the idle slot
 
 
 # ------------------------------------------------- sharded (shard_map)

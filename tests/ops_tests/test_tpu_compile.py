@@ -214,6 +214,50 @@ def test_mosaic_accepts_the_kv_panel(heads, kv_heads, dh, t, kv_dtype, chip):
     _assert_mosaic_took(text, 1, ["paged_decode"], (dh, t, kv_dtype))
 
 
+@pytest.mark.parametrize("block_len, dtype, group, blocks", [
+    (16, jnp.bfloat16, 5, 8), (32, jnp.bfloat16, 5, 4),
+    (128, jnp.bfloat16, 16, 1), (256, jnp.bfloat16, 16, 1),
+    (48, jnp.bfloat16, 5, 1), (8, jnp.bfloat16, 5, 1),
+    (8, jnp.float32, 5, 16), (16, jnp.int8, 5, 1), (32, jnp.int8, 1, 1),
+    (16, jnp.bfloat16, 1, 1),
+], ids=["bl16", "bl32", "bl128", "bl256", "bl48", "bl8_half_a_tile",
+        "bl8_f32", "int8", "int8_mha", "row_body"])
+def test_blocks_a_step_follow_the_pool(block_len, dtype, group, blocks):
+    """The tile the per-head body folds a loop step is worked out from the
+    pool's block length and dtype and the group alone: 128 positions where
+    a block divides them, is shorter, and is whole packed sublane tiles;
+    one block for an int8 pool, and for the row body (one query head a KV
+    head on a float pool) — whose window form is the per-head body's."""
+    from chainermn_tpu.ops.decode_attention import blocks_a_step
+
+    assert blocks_a_step(block_len, dtype, group) == blocks
+    if group == 1 and dtype != jnp.int8:
+        assert blocks_a_step(block_len, dtype, group, windowed=True) == 8
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+def test_mosaic_accepts_a_tile_of_eight_blocks(windowed, chip):
+    """Falcon-H1's decode call — 64 slots, 20 / 4 heads of 128, a table 64
+    wide over ``bf16[4097, 16, 1024]`` — folds eight blocks a loop step: the
+    eight rows land in aligned 16-row slices of ONE ``(128, 1024)`` buffer,
+    four such buffers in VMEM, and the launch is still ONE
+    ``tpu_custom_call`` named ``paged_decode`` (``paged_decode_window`` with
+    the two bounds, a ring's blocks of 16)."""
+    slots, mb = 64, 64
+    args = [((slots, 20, 128), jnp.bfloat16),
+            ((4097, 16, 4 * 2 * 128), jnp.bfloat16),
+            ((slots, mb), jnp.int32), ((slots,), jnp.int32)]
+    args += [((slots,), jnp.int32)] * 2 * windowed
+
+    def fn(q, pool, tbl, valid, *bounds):
+        kw = dict(zip(("lowest", "first_pos"), bounds))
+        return paged_decode_attention(q, pool, tbl, valid, None, **kw)
+
+    text = jax.jit(fn).lower(*_on(chip, args)).compile().as_text()
+    name = "paged_decode_window" if windowed else "paged_decode"
+    _assert_mosaic_took(text, 1, [name], name)
+
+
 def _xl_layer_step(tokens_per_slot, per_slot_pos, chunk_rows=0):
     """One GPT-2 XL-wide decoder layer over the backlog cell's pool, as the
     engine's programs call it: the pools donated, (decode, verify) every

@@ -444,3 +444,77 @@ def test_serving_drain_zero_leak_baseline(obs_run):
     assert reg.snapshot()["mem.kv.leaked_blocks"]["value"] == 0
     # The post-gc resample reflects the empty pool.
     assert sched.memory.last_kv["used_blocks"] == 0
+
+
+@pytest.mark.parametrize("kv_heads, a_step", [(2, 8), (4, 1)],
+                         ids=["gqa", "mha"])
+def test_decode_span_counts_the_kernels_loop_steps(make_model, kv_heads,
+                                                   a_step, monkeypatch):
+    """``cmn_serve_decode.kv_steps`` beside ``kv_blocks_resident``: the
+    loop steps ONE layer's paged kernel takes this tick — a step folds
+    ``blocks_a_step`` of a slot's resident blocks (eight at ``block_len``
+    16 for grouped queries: Σ ceil(blocks / 8) over the live slots; one for
+    the row body a ``G == 1`` model takes, where the two counts are
+    equal).  Both are callables, evaluated only where a span is recorded:
+    the stand-in below records every one."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chainermn_tpu.ops.decode_attention import blocks_a_step
+    from chainermn_tpu.serving import scheduler as sched_mod
+
+    BL = 16
+    model = make_model(max_len=320, n_kv_heads=kv_heads)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 12), jnp.int32))["params"]
+    eng = DecodeEngine(model, params, capacity=4, num_blocks=64,
+                       block_len=BL, prefill_chunk=32)
+    assert eng.pool.blocks_a_step == a_step == blocks_a_step(
+        BL, model.dtype, model.n_heads // kv_heads)
+    seen, real = [], sched_mod._annotate
+
+    class Recorded:
+        def __init__(self, span):
+            self.span = span
+
+        def __enter__(self):
+            self.span.__enter__()
+            return self
+
+        def __exit__(self, *exc):
+            return self.span.__exit__(*exc)
+
+        def __getattr__(self, name):
+            return getattr(self.span, name)
+
+        def set_metadata(self, **counts):
+            seen.append(({k: v() if callable(v) else v
+                          for k, v in counts.items()},
+                         [s.pos for s in sched._slots
+                          if s is not None and not s.prefilling]))
+            self.span.set_metadata(**counts)
+
+    def annotate(name, **kw):
+        span = real(name, **kw)
+        return Recorded(span) if name == "cmn_serve_decode" else span
+
+    monkeypatch.setattr(sched_mod, "_annotate", annotate)
+    rng = np.random.RandomState(3)
+    sched = Scheduler(eng)
+    sched.run([
+        Request(id=i, prompt=rng.randint(1, 128, size=n).tolist(),
+                max_new_tokens=m)
+        for i, (n, m) in enumerate(((5, 40), (130, 30), (140, 12), (270, 6)))
+    ])
+    assert max(len(pos) for _, pos in seen) >= 3
+    for st, pos in seen:
+        blocks = [-(-(p + 1) // BL) for p in pos]
+        assert st["live"] == len(pos)
+        assert st["kv_blocks_resident"] == sum(blocks)
+        assert st["kv_steps"] == sum(-(-b // a_step) for b in blocks)
+    # a context of 271 positions is 17 blocks: three steps of eight
+    assert max(st["kv_blocks_resident"] - st["kv_steps"]
+               for st, _ in seen) >= (17 - 3 if a_step == 8 else 0)
+    assert eng.kv_steps([4, 15, 16, 127, 128]) == (
+        1 + 1 + 1 + 1 + 2 if a_step == 8 else 1 + 1 + 2 + 8 + 9)
